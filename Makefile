@@ -175,11 +175,15 @@ package:
 $(BUILD):
 	mkdir -p $(BUILD)
 
+# link to a private name, then rename: `queue backend=auto` builds on
+# first use, and two processes starting on a clean clone must never
+# load each other's half-written library
 $(LIB): csrc/nns_util.cc csrc/nns_ring.cc csrc/nns_custom.h | $(BUILD)
-	$(CXX) $(CXXFLAGS) -shared -o $@ csrc/nns_util.cc csrc/nns_ring.cc
+	$(CXX) $(CXXFLAGS) -shared -o $@.$$$$.tmp csrc/nns_util.cc csrc/nns_ring.cc \
+		&& mv -f $@.$$$$.tmp $@
 
 $(BUILD)/custom_%.so: csrc/custom_%.cc csrc/nns_custom.h | $(BUILD)
-	$(CXX) $(CXXFLAGS) -shared -o $@ $<
+	$(CXX) $(CXXFLAGS) -shared -o $@.$$$$.tmp $< && mv -f $@.$$$$.tmp $@
 
 test: native
 	python -m pytest tests/ -q

@@ -160,24 +160,35 @@ def main(argv=None) -> int:
     from . import parse_launch
     pipe = parse_launch(args.pipeline)
     tracer = pipe.enable_tracing() if args.trace else None
+    failed = []
     try:
         pipe.start()
-        ok = pipe.wait_eos(args.timeout)
-        if not ok:
-            print("timeout waiting for EOS", file=sys.stderr)
+        if not pipe.wait_eos(args.timeout):
+            failed.append("timeout waiting for EOS")
     except KeyboardInterrupt:
         print("interrupted", file=sys.stderr)
     finally:
         pipe.stop()
-    err = [m for m in pipe.bus.drain() if m.kind == "error"]
-    for m in err:
-        print(f"ERROR: {m.data.get('element')}: {m.data.get('error')}",
-              file=sys.stderr)
+    for m in pipe.bus.drain():
+        if m.kind == "error":
+            failed.append(f"ERROR: {m.data.get('element')}: "
+                          f"{m.data.get('error')}")
+    stats = pipe.stats()
+    # a dropped frame keeps the pipeline alive (the reference's
+    # contract), but a run that lost frames to invoke errors did not
+    # succeed: say so and exit non-zero
+    for name, snap in stats.items():
+        if snap.get("invoke_errors"):
+            failed.append(f"{name}: {snap['invoke_errors']} invoke "
+                          f"error(s), {snap.get('frames_dropped', 0)} "
+                          f"frame(s) dropped")
+    for line in failed:
+        print(line, file=sys.stderr)
     if args.stats:
-        print(json.dumps(pipe.stats(), indent=2, default=str))
+        print(json.dumps(stats, indent=2, default=str))
     if tracer is not None:
         print(json.dumps(tracer.report(pipe), indent=2, default=str))
-    return 1 if err else 0
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":

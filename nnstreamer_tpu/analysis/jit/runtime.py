@@ -3,8 +3,8 @@
 The static passes predict WHERE compilation may happen (the jit-site
 map, bucketed by CompileCache ``kind``); the runtime half observes what
 actually happened — per-element ``jit_hits`` / ``jit_misses`` /
-``jit_prewarmed`` / ``jit_recompiles`` counters plus (where the jax
-build exposes it) ``jax.monitoring`` compile events — and
+``jit_prewarmed`` / ``jit_recompiles`` counters plus
+``jax.monitoring`` compile events — and
 ``check_against_static`` closes the contract:
 
 * steady-state recompiles == 0 — a warmed process serving the same
@@ -46,14 +46,11 @@ def steady_recompiles(snapshot: Dict[str, Dict[str, int]]) -> int:
 
 
 class CompileEventMonitor:
-    """Counts jax.monitoring compile events process-wide. Best-effort:
-    older jax builds without the monitoring hooks degrade to a counter
-    that stays at zero (``available`` says which you got), and jax only
-    offers clear-all, so ``install()`` is one-way — ``reset()`` rebases
-    the count instead of unregistering."""
+    """Counts jax.monitoring compile events process-wide. jax only
+    offers clear-all for listeners, so ``install()`` is one-way —
+    ``reset()`` rebases the count instead of unregistering."""
 
     def __init__(self) -> None:
-        self.available = False
         self._count = 0
         self._base = 0
         self.events: Dict[str, int] = {}
@@ -64,15 +61,10 @@ class CompileEventMonitor:
             self.events[event] = self.events.get(event, 0) + 1
 
     def install(self) -> "CompileEventMonitor":
-        try:
-            from jax import monitoring
-            monitoring.register_event_listener(self._on_event)
-            if hasattr(monitoring, "register_event_duration_secs_listener"):
-                monitoring.register_event_duration_secs_listener(
-                    lambda event, duration, **kw: self._on_event(event))
-            self.available = True
-        except Exception:
-            self.available = False
+        from jax import monitoring
+        monitoring.register_event_listener(self._on_event)
+        monitoring.register_event_duration_secs_listener(
+            lambda event, duration, **kw: self._on_event(event))
         return self
 
     def reset(self) -> None:

@@ -584,8 +584,8 @@ class WireConfigRule(Rule):
 class FusionBreakRule(Rule):
     """A single non-fusible element sandwiched between two device-fusible
     neighbors splits what would otherwise be one FusedSegment into two
-    (or none) — every split re-crosses the host/device boundary, which on
-    a remote-attached TPU costs a full RTT per frame."""
+    (or none) — every split re-crosses the host/device boundary: one
+    more D2H, host hop and H2D per frame."""
 
     id = "fusion-break"
     severity = Severity.WARNING

@@ -1,9 +1,9 @@
 """K-frame in-flight invoke window: dispatcher/completer split.
 
-The synchronous chain path pays RTT + H2D + invoke + D2H serially per
-frame, so a remote-attached chip caps the pipeline at ~1/RTT fps no
-matter how fast the model runs. JAX dispatch is already asynchronous —
-the fix is to stop blocking the chain thread on completion:
+The synchronous chain path pays H2D + invoke + D2H serially per frame,
+and the chip idles while the host stages the next one. JAX dispatch is
+already asynchronous — the window stops blocking the chain thread on
+completion, so those legs overlap across frames:
 
   * the **dispatcher** (the element's chain thread) acquires a slot in
     the per-link :class:`~..tensors.transfer.InFlightWindow` (blocking

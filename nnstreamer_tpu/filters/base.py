@@ -172,6 +172,18 @@ class FilterFramework:
         return False
 
     # async generative path -----------------------------------------------
+    # set by the owner of an async backend (the element installs its
+    # invoke-error accounting): a failure AFTER invoke_async returned —
+    # generation thread, scheduler loop, admission — has no caller left
+    # to raise into, and a stream that silently never yields a token is
+    # the one outcome nobody can see
+    on_async_error: Optional[Callable[[BaseException], None]] = None
+
+    def _report_async_error(self, exc: BaseException) -> None:
+        """Hand one lost stream's failure to :attr:`on_async_error`."""
+        if self.on_async_error is not None:
+            self.on_async_error(exc)
+
     def set_async_dispatcher(
             self, dispatch: Callable[..., None]) -> None:
         """Element installs a callback; an async backend calls it once per

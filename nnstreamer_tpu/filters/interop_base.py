@@ -13,6 +13,7 @@ import numpy as np
 
 from ..tensors.info import TensorsInfo
 from ..utils.log import logger
+from ..utils.xla_cache import ensure_compile_cache
 from .base import FilterEvent, FilterFramework, FilterProperties
 from .jax_backend import _device_for
 
@@ -34,6 +35,7 @@ class ImportedModelFilter(FilterFramework):
 
     # -- lifecycle --------------------------------------------------------
     def open(self, props: FilterProperties) -> None:
+        ensure_compile_cache()
         self._props = props
         self._device = _device_for(props.accelerators)
         if not props.model_files:

@@ -39,6 +39,7 @@ import numpy as np
 
 from ..tensors.info import TensorsInfo
 from ..utils.log import logger
+from ..utils.xla_cache import ensure_compile_cache
 from .base import (Accelerator, FilterEvent, FilterFramework,
                    FilterProperties,
                    parse_custom_properties as _parse_custom)
@@ -46,15 +47,30 @@ from .registry import register_filter
 
 
 def _device_for(accelerators: Sequence[Accelerator]):
+    """First usable entry of the ``accelerator`` preference list (the
+    reference's ``true:tpu.cpu`` grammar): a NAMED platform must exist —
+    JAX itself falls back to CPU with a warning when the TPU runtime
+    does not come up, and a filter that asked for the TPU must not then
+    serve from the host as if nothing happened. ``default`` (and an
+    empty property) is whatever JAX's default backend is. Every
+    non-mesh filter lands on device 0 of its platform, also on a
+    four-chip host; spreading filters over chips is ``custom=mesh:``."""
     import jax
+    missing = []
     for acc in accelerators:
-        if acc in (Accelerator.CPU, Accelerator.NONE):
-            # accelerator=false / cpu is an explicit opt-out of the TPU
-            try:
-                return jax.devices("cpu")[0]
-            except RuntimeError:
-                continue
-        return jax.devices()[0]
+        if acc == Accelerator.DEFAULT:
+            return jax.devices()[0]
+        # accelerator=false / cpu is an explicit opt-out of the TPU
+        platform = "cpu" if acc == Accelerator.NONE else acc.value
+        try:
+            return jax.devices(platform)[0]
+        except RuntimeError:
+            missing.append(platform)
+    if missing:
+        raise RuntimeError(
+            f"accelerator {'.'.join(missing)} requested but JAX has no "
+            f"such backend here (default backend: "
+            f"{jax.default_backend()})")
     return jax.devices()[0]
 
 
@@ -98,6 +114,7 @@ class JaxFilter(FilterFramework):
     # -- lifecycle --------------------------------------------------------
     def open(self, props: FilterProperties) -> None:
         import jax
+        ensure_compile_cache()  # before _load_model: zoo init compiles
         self._props = props
         opts = _parse_custom(props.custom_properties)
         model = props.model_files[0] if props.model_files else ""
@@ -133,7 +150,6 @@ class JaxFilter(FilterFramework):
         cc = compile_cache.active()
         if cc is None or self._apply is None:
             return
-        cc.enable_xla_cache()
         import jax
         warmed = 0
         for sig, donate in cc.signatures("jax", self._cache_key):
@@ -149,11 +165,13 @@ class JaxFilter(FilterFramework):
                 out = self._executable(sig, donate)(self._params, *xs)
                 jax.block_until_ready(out)
                 warmed += 1
-            except Exception as exc:
+            except (TypeError, ValueError) as exc:
                 # a stale signature (model shape change across versions)
-                # only costs its own replay, never the open
-                logger.info("jax filter: cached signature %s skipped: %s",
-                            sig, exc)
+                # fails at TRACE time and only costs its own replay; a
+                # device-side failure (XlaRuntimeError) is not stale
+                # data and fails the open
+                logger.warning("jax filter: cached signature %s no "
+                               "longer traces, skipped: %s", sig, exc)
         if warmed:
             logger.info("jax filter: prewarmed %d signature(s) for %s",
                         warmed, self._cache_key)

@@ -46,6 +46,7 @@ import numpy as np
 from ..tensors.info import TensorsInfo
 from ..utils.atomic import Counters
 from ..utils.log import logger
+from ..utils.xla_cache import ensure_compile_cache
 from .base import (FilterFramework, FilterProperties,
                    parse_custom_properties as _parse_custom)
 from .registry import register_alias, register_filter
@@ -374,6 +375,7 @@ class LlmFilter(FilterFramework):
 
         from ..models import transformer as tfm
 
+        ensure_compile_cache()  # before init_params compiles
         model = props.model_files[0] if props.model_files else ""
         if model.startswith("zoo://"):
             parsed = urllib.parse.urlparse(model)
@@ -731,8 +733,9 @@ class LlmFilter(FilterFramework):
             try:
                 self._generate(
                     prompt, lambda tok: self._dispatch([tok], ctx))
-            except Exception:  # noqa: BLE001
+            except Exception as exc:  # noqa: BLE001 — thread boundary
                 logger.exception("llm generation failed")
+                self._report_async_error(exc)
 
         t = threading.Thread(target=run, name="llm-generate", daemon=True)
         self._threads.append(t)
@@ -894,8 +897,18 @@ class LlmFilter(FilterFramework):
         prompts — continuous batching, not static batching."""
         try:
             self._sched_body()
-        except Exception:  # noqa: BLE001 — daemon thread: log, don't die silent
+        except Exception as exc:  # noqa: BLE001 — thread boundary
             logger.exception("llm scheduler failed; in-flight streams lost")
+            with self._cond:
+                lost = sum(s is not None for s in self._streams or ())
+                self._streams = None
+            if self._backend is not None:
+                # the next prompt starts a fresh loop over the SAME
+                # pool: the lost streams' blocks must not stay taken
+                for slot in range(self._n_parallel):
+                    self._backend.free(slot)
+            for _ in range(max(1, lost)):
+                self._report_async_error(exc)
 
     def _finish_span(self, s: Dict[str, Any]) -> None:
         """A stream just finished: close its llm-decode span so the
@@ -959,8 +972,9 @@ class LlmFilter(FilterFramework):
                     # blocks as they finish
                     requeue.append(entry)
                     continue
-                except Exception:  # noqa: BLE001 — drop THIS prompt only
+                except Exception as exc:  # noqa: BLE001 — drop THIS prompt only
                     logger.exception("llm: prompt rejected at admission")
+                    self._report_async_error(exc)
                     continue
                 tctx = kv.get("ctx") if kv is not None else _ctx_of(ctx)
                 if tctx is not None and kv is None:
@@ -993,11 +1007,12 @@ class LlmFilter(FilterFramework):
                         # blocks: the head request exceeds the whole
                         # pool — drop it loudly instead of deadlocking
                         head = requeue.pop(0)
-                        logger.error(
-                            "llm: stream of %d tokens needs more KV "
-                            "blocks than pool_blocks=%d holds; dropped",
-                            int(np.asarray(head[0]).size),
-                            self._pool_mgr.n_blocks)
+                        msg = (f"llm: stream of "
+                               f"{int(np.asarray(head[0]).size)} tokens "
+                               f"needs more KV blocks than pool_blocks="
+                               f"{self._pool_mgr.n_blocks} holds; dropped")
+                        logger.error(msg)
+                        self._report_async_error(_PoolFull(msg))
                     self._pending[:0] = requeue
             active_np = np.array([s is not None for s in streams])
             if not active_np.any():
@@ -1073,8 +1088,8 @@ class LlmFilter(FilterFramework):
         k = min(self._chunk, max(emits_left))
         if temperature > 0:
             # one cached filler key for idle slots: a fresh eager
-            # PRNGKey per slot per round would cost an RPC each on a
-            # remote-attached chip, eroding the chunking win
+            # PRNGKey per slot per round is a dispatch each, eroding
+            # the chunking win
             if not hasattr(self, "_idle_key"):
                 self._idle_key = jax.random.PRNGKey(0)
             keys = jnp.stack([s["key"] if s else self._idle_key

@@ -1,38 +1,30 @@
-"""framework=simlink — deterministic slow-link queueing model.
+"""framework=simlink — deterministic latency-plus-service queueing model.
 
-A filter backend that behaves, timing-wise, like a model served over a
-remote-attached chip: every frame pays a link round trip (``rtt``) plus
-a serial on-chip service time (``svc``). The compute itself is a
-trivial deterministic affine map (``y = 2x + 1`` in the input dtype),
-so sync and overlapped runs are byte-comparable.
+A test fake, never a measurement: a filter backend whose every frame
+pays a fixed overlappable latency (``rtt``) plus a serial service time
+(``svc``). The compute itself is a trivial deterministic affine map
+(``y = 2x + 1`` in the input dtype), so sync and overlapped runs are
+byte-comparable.
 
-It exists for the bench's ``async_overlap`` row and the overlap tests:
-with it the queueing math is exact —
+It exists for the overlap tests: with it the queueing math is exact —
 
-  * synchronous invoke:   fps ≈ 1 / (rtt + svc)      (≈ 1/RTT collapse)
+  * synchronous invoke:   fps ≈ 1 / (rtt + svc)
   * K-frame window:       fps ≈ min(K / rtt, 1 / svc)
 
-because :meth:`dispatch` returns immediately (the frame is "on the
-link") and :meth:`complete` waits out THIS frame's absolute deadline
-(RTT legs overlap across frames) then serializes ``svc`` on the
-completer (the chip runs one program at a time). Doubling ``rtt``
-mid-run via :func:`set_weather` leaves the windowed pipeline's
-throughput at min(K/rtt, 1/svc) while the sync pipeline halves — the
-weather-resilience verdict the bench row checks.
+because :meth:`dispatch` returns immediately and :meth:`complete` waits
+out THIS frame's absolute deadline (latency legs overlap across frames)
+then serializes ``svc`` on the completer (one program at a time).
 
 Custom properties (``custom=rtt:60,svc:5,fail-every:0``):
-  * ``rtt``        link round trip per frame, ms (default 0)
+  * ``rtt``        overlappable latency per frame, ms (default 0)
   * ``svc``        serial service time per frame, ms (default 0)
   * ``svc-row``    serial service time PER BATCH ROW, ms (default 0) —
                    with it a stacked batch of R rows costs
                    ``svc + svc-row * ceil(R / dp)``
   * ``mesh``       a ``DxSxT`` spec whose data-parallel degree divides
                    the per-row service across simulated chips (default
-                   dp=1). The mesh half of the ``sharded_serve`` bench
-                   row: rows of one batch run dp-wide, so batch service
-                   scales as ceil(R/dp) — the deterministic stand-in
-                   for a real pod's batch-major fan-out (the 1-core CI
-                   host cannot show a real dp speedup)
+                   dp=1): rows of one batch run dp-wide, so batch
+                   service scales as ceil(R/dp)
   * ``fail-every`` raise on every Nth frame's completion (0 = never) —
                    chaos hook for breaker/shed accounting with frames
                    in flight
@@ -50,23 +42,9 @@ from .base import (FilterFramework, FilterProperties,
                    parse_custom_properties as _parse_custom)
 from .registry import register_filter
 
-# live link weather, keyed by override scope (None = all simlink
-# instances). Written only from the bench/test (API) thread via
-# set_weather and read per frame — single-writer plain store.
-_weather_rtt_ms: Optional[float] = None
-
-
-def set_weather(rtt_ms: Optional[float]) -> None:
-    """Override every simlink instance's RTT mid-run (None = back to
-    each instance's configured value). The bench's weather-doubling
-    knob."""
-    global _weather_rtt_ms
-    _weather_rtt_ms = None if rtt_ms is None else float(rtt_ms)
-
-
 @register_filter
 class SimLinkFilter(FilterFramework):
-    """framework=simlink: remote-link timing model, deterministic math."""
+    """framework=simlink: latency+service timing model, deterministic math."""
 
     NAME = "simlink"
     SUPPORTS_BATCH = True
@@ -105,10 +83,6 @@ class SimLinkFilter(FilterFramework):
     def get_model_info(self):
         return self._in_info, self._in_info
 
-    def _rtt(self) -> float:
-        w = _weather_rtt_ms
-        return self._rtt_s if w is None else w / 1e3
-
     @staticmethod
     def _compute(inputs: Sequence[Any]) -> List[Any]:
         # same-dtype affine map: wraps identically for integer dtypes on
@@ -139,7 +113,7 @@ class SimLinkFilter(FilterFramework):
     # -- synchronous path: the full serial cost per frame -----------------
     def invoke(self, inputs: Sequence[Any]) -> List[Any]:
         n = self._tick()
-        time.sleep(self._rtt() + self._svc(inputs))
+        time.sleep(self._rtt_s + self._svc(inputs))
         self._maybe_fail(n)
         return self._compute(inputs)
 
@@ -149,7 +123,7 @@ class SimLinkFilter(FilterFramework):
         the handle carries the absolute arrival deadline, so RTT legs of
         consecutive in-flight frames overlap in wall time."""
         n = self._tick()
-        return (list(inputs), time.monotonic() + self._rtt(), n)
+        return (list(inputs), time.monotonic() + self._rtt_s, n)
 
     def complete(self, handle: Any) -> List[Any]:
         inputs, deadline, n = handle

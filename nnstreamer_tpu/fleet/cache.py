@@ -10,15 +10,13 @@ fused segment actually compiled — through the crash-consistent
 replays them at ``open()``/``start()`` time and serves its first frame
 from a warm jit cache.
 
-Two layers compose:
-
-* **signature replay** (always on when a cache is installed): the
-  backend records every compiled signature; a restarted replica
-  compiles them *before* advertising readiness, moving the cost out of
-  the serving path entirely — correct on every JAX version/platform;
-* **XLA persistent compilation cache** (best-effort): when the
-  installed JAX supports ``jax_compilation_cache_dir``, the replayed
-  compiles themselves become disk hits, so even the warmup is cheap.
+The backend records every compiled signature; a restarted replica
+compiles them *before* advertising readiness, moving the cost out of
+the serving path entirely. The replayed compiles are themselves disk
+hits where JAX's own persistent compilation cache is warm — that cache
+is a separate thing with its own directory, owned by
+:func:`~..utils.xla_cache.ensure_compile_cache`, and this registry
+never names it.
 
 Processes share one cache through the ``NNS_COMPILE_CACHE`` environment
 variable — the autoscaler exports it to every replica it spawns, so the
@@ -164,27 +162,6 @@ class CompileCache:
         """Total recorded signatures across all model keys."""
         with self._lock:
             return sum(len(v) for v in self._sigs.values())
-
-    def enable_xla_cache(self) -> bool:
-        """Best-effort: point JAX's persistent compilation cache at a
-        subdirectory, so replayed compiles become disk hits. Harmless
-        no-op on JAX builds without the knob."""
-        xla_dir = os.path.join(self.root, "xla")
-        try:
-            os.makedirs(xla_dir, exist_ok=True)
-            import jax
-            jax.config.update("jax_compilation_cache_dir", xla_dir)
-            try:
-                # cache everything, not just slow compiles: the warmup
-                # signatures are exactly the small programs the default
-                # min-compile-time heuristic would skip
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 0.0)
-            except Exception:
-                pass
-            return True
-        except Exception:
-            return False
 
 
 # -- process-wide installation (inherited by spawned replicas) -------------

@@ -8,9 +8,27 @@ readable markers the child prints (see :mod:`.replica_main`).
 
 The process boundary is deliberate: a replica is a *real* unit of
 preemptible capacity — its own interpreter, its own JAX runtime, its
-own snapshot directory — exactly what the subprocess dryrun scaffold
-(parallel/dryrun.py) established for multi-process validation. The
-autoscaler composes these into a fleet.
+own snapshot directory. The autoscaler composes these into a fleet.
+
+What a replica needs from its host. A chip belongs to one process: the
+TPU runtime initialises every chip the process can see and holds them
+until it exits. Established on a v5e host (PR 21): while one process
+holds the chip, a second one that opens a filter fails within seconds —
+``RuntimeError: Unable to initialize backend 'tpu': ABORTED ... libtpu
+multi-process lockfile`` — it neither hangs nor falls back to the CPU
+(the host sets ``JAX_PLATFORMS=tpu,cpu``), so its ``replica-ready``
+never comes and :meth:`ReplicaProcess.wait_ready` times out. So:
+
+* the parent (router, autoscaler) must not open a filter — ``import
+  nnstreamer_tpu`` alone initialises no backend and is safe;
+* each replica whose pipeline opens a ``jax``/``llm`` filter needs a
+  host — or a visible-chip set — of its own; two such replicas on one
+  host cannot both have the chip. Pass a per-replica chip selection
+  through :attr:`ReplicaSpec.env`. Pinning children to chips
+  automatically is ROADMAP Reach 8, which also runs the alternative:
+  several one-chip replicas inside ONE process;
+* replicas whose filters never touch JAX (``custom-easy``, the chaos
+  and elastic tests) share a host freely.
 """
 from __future__ import annotations
 

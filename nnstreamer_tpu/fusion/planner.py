@@ -232,12 +232,9 @@ def plan_fusion(pipeline, inference: Optional[InferenceResult] = None,
                 plan.vetoes.setdefault(elem.name, v)
                 break
             ctx = FusionCtx(elem, cur_caps, config_of(cur_caps))
-            try:
-                fn = elem.device_fn(ctx)
-            except Exception:  # noqa: BLE001 -- decline, don't block launch
-                logger.warning("fusion: %s.device_fn raised; leaving it "
-                               "on the chain path", elem.name, exc_info=True)
-                fn = None
+            # declining is `return None`; a device_fn that RAISES (model
+            # would not open, program would not build) fails the launch
+            fn = elem.device_fn(ctx)
             if fn is None:
                 plan.vetoes.setdefault(
                     elem.name, "device_fn declined at plan time")

@@ -35,6 +35,7 @@ from ..pipeline.pad import Pad
 from ..tensors.buffer import Buffer, Chunk
 from ..tensors.transfer import submit_fetch
 from ..utils.log import logger
+from ..utils.xla_cache import ensure_compile_cache
 
 
 class FusedSegment(TransformElement):
@@ -140,6 +141,9 @@ class FusedSegment(TransformElement):
                 reorder_deadline_s=float(self.reorder_deadline_ms) / 1e3,
                 devices=(len(self._mesh.devices.ravel())
                          if self._mesh is not None else 1))
+        # a run without a filter member (transform ! decoder) compiles
+        # here first
+        ensure_compile_cache()
         self._prewarm_from_cache()
 
     def _cache_key(self) -> str:
@@ -156,7 +160,6 @@ class FusedSegment(TransformElement):
         cc = compile_cache.active()
         if cc is None:
             return
-        cc.enable_xla_cache()
         import jax
         import numpy as np
         for sig, _donate in cc.signatures("fusion", self._cache_key()):
@@ -171,10 +174,11 @@ class FusedSegment(TransformElement):
                 jax.block_until_ready(exe(arrays))
                 self._programs[sig] = exe
                 self.stats.inc("jit_prewarmed")
-            except Exception as exc:
-                # a stale signature only costs its own replay
-                logger.info("%s: cached fused signature %s skipped: %s",
-                            self.name, sig, exc)
+            except (TypeError, ValueError) as exc:
+                # a stale signature fails at trace time and only costs
+                # its own replay; a device-side failure propagates
+                logger.warning("%s: cached fused signature %s no longer "
+                               "traces, skipped: %s", self.name, sig, exc)
 
     def _record_signature(self, sig) -> None:
         from ..fleet import cache as compile_cache

@@ -91,10 +91,8 @@ def _build_vit(size: str = "224", patch: str = "16", d_model: str = "768",
     """``attn``: ``stock`` (flax/XLA attention), ``pallas`` (the fused
     VMEM kernel, ops/attention.py). The param tree is identical either
     way — the toggle changes only how the attention core is scheduled.
-    ``auto`` resolves to stock: measured on v5e, XLA's pattern-matched
-    attention fusion beats the hand kernel at ViT encoder shapes
-    (ops/attention.py docstring carries the numbers); pallas stays
-    available for shapes where XLA's fusion breaks."""
+    ``auto`` resolves to stock; which is faster on the chip is not
+    measured (ROADMAP Design 7)."""
     hw = int(size)
     if attn == "auto":
         attn = "stock"

@@ -47,14 +47,11 @@ def _try_build() -> bool:
                        capture_output=True, timeout=120)
         return True
     except (subprocess.SubprocessError, FileNotFoundError) as e:
-        logger.info("native build unavailable: %s", e)
+        # said once per process (load_native_lib tries once): callers
+        # such as `queue backend=auto` now run their python path
+        logger.warning("native build unavailable, python paths in "
+                       "use: %s", e)
         return False
-
-
-def native_built() -> bool:
-    """True when libnnstpu.so is already on disk — the cheap probe for
-    opportunistic callers that must NOT trigger an on-demand build."""
-    return os.path.exists(_LIB_PATH)
 
 
 def load_native_lib() -> Optional[ctypes.CDLL]:

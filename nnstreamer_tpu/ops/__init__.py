@@ -4,8 +4,8 @@
 (gsttensor_transform.c:56-57 HAVE_ORC) — hand-tuned inner loops for the
 per-element math that wraps every model invoke. Here the hand-tuning
 targets the TPU's VPU via Pallas; every op carries a jnp reference
-implementation used as fallback off-TPU and as the parity oracle in
-tests.
+implementation as the parity oracle. Off the TPU the kernel body runs
+through the Pallas interpreter, never the reference in its place.
 """
 from .normalize import fused_normalize, normalize_reference
 

@@ -17,29 +17,23 @@ KV-cache decode loop (models/transformer.py), not here.
 Drop-in: :func:`fused_attention` matches the flax
 ``MultiHeadDotProductAttention(attention_fn=...)`` contract
 ([B, S, H, D] inputs, softmax over keys), so models opt in per-module
-(models/vit.py ``attn=pallas``). Non-TPU backends fall back to the
-jnp reference implementation — bit-compatible up to dtype rounding —
-so the same model file runs tests on CPU and the kernel on the chip.
+(models/vit.py ``attn=pallas``). The same kernel body runs everywhere:
+compiled by Mosaic on TPU, through the Pallas interpreter on other
+backends (how the CPU tests exercise it). :func:`reference_attention`
+is the oracle, never a stand-in.
 
 No reference analog: the reference's backends hand attention to vendor
 SDKs; on TPU the fusion boundary is ours to place.
 
-Measured verdict (v5e, ViT-B/16 shapes: B=64, S=196, H=12, D=64,
-bf16, 50-call scan chain): stock XLA 88-113 ms, this kernel 123 ms, a
-head-batched variant 147 ms — **XLA's built-in attention fusion wins
-at encoder shapes this small** (its pattern-matched attention keeps
-scores in registers/VMEM already, without this kernel's pad/relayout).
-The kernel therefore ships as an opt-in (``zoo://vit?attn=pallas``),
-validated for parity, while ``attn=auto`` resolves to stock everywhere;
-it earns its keep only where XLA's fusion breaks (very long S, exotic
-masking) — measure before switching. ViT-B/16 MFU with stock attention:
-66-68 % under clean link weather, which is the real answer to "close
-the ViT MFU gap" — there was no attention-fusion gap to close.
+Status: opt-in (``zoo://vit?attn=pallas``), validated for parity;
+``attn=auto`` resolves to stock XLA attention. Whether the kernel beats
+XLA's own attention fusion at any shape on a chip local to the process
+is not measured (ROADMAP Design 7).
 """
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -50,7 +44,7 @@ def _round_up(n: int, m: int) -> int:
 
 
 def reference_attention(q, k, v):
-    """jnp reference (and CPU fallback): f32 softmax, same contract."""
+    """jnp reference (the parity oracle): f32 softmax, same contract."""
     d = q.shape[-1]
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
     p = jax.nn.softmax(s * (d ** -0.5), axis=-1)
@@ -105,21 +99,16 @@ def _fused_bshd(q, k, v, interpret: bool = False):
 
 
 def fused_attention(query, key, value, bias=None, mask=None,
-                    *, interpret: Optional[bool] = None,
                     **unused_kwargs: Any):
     """flax ``attention_fn``-compatible fused attention.
 
-    query/key/value: [B, S, H, D]. bias/mask are unsupported (the
-    encoder models this serves are full-attention); passing one falls
-    back to stock flax attention so correctness never silently changes.
-    ``interpret=True`` forces the Pallas interpreter (CPU testing).
+    query/key/value: [B, S, H, D]. bias/mask are outside the kernel's
+    contract (the encoder models this serves are full-attention);
+    passing one runs stock flax attention, so a mask is never ignored.
     """
     if bias is not None or mask is not None:
         import flax.linen as nn
         return nn.dot_product_attention(query, key, value, bias=bias,
                                         mask=mask)
-    if interpret is None:
-        if jax.devices()[0].platform != "tpu":
-            return reference_attention(query, key, value)
-        interpret = False
-    return _fused_bshd(query, key, value, interpret=interpret)
+    return _fused_bshd(query, key, value,
+                       interpret=jax.devices()[0].platform != "tpu")

@@ -59,20 +59,16 @@ def _normalize_pallas(x2d, scale: float, offset: float, dtype,
 
 
 def fused_normalize(x, scale: float = 1.0 / 127.5, offset: float = 127.5,
-                    dtype=jnp.bfloat16, force_pallas: bool = False):
+                    dtype=jnp.bfloat16):
     """(x - offset) * scale as one fused on-chip pass.
 
     Accepts any rank; internally reshaped to 2D lane-aligned tiles when
-    the element count allows, else padded. Uses Pallas on TPU, the jnp
-    oracle elsewhere; ``force_pallas`` runs the kernel in interpret mode
-    off-TPU (how tests exercise the kernel body on the CPU mesh).
+    the element count allows, else padded. The SAME kernel body runs
+    everywhere: compiled by Mosaic on TPU, through the Pallas
+    interpreter elsewhere (how tests exercise it on the CPU mesh).
+    :func:`normalize_reference` is the oracle, never a stand-in.
     """
-    platform = jax.devices()[0].platform
-    interpret = False
-    if platform != "tpu":
-        if not force_pallas:
-            return normalize_reference(x, scale, offset, dtype)
-        interpret = True
+    interpret = jax.devices()[0].platform != "tpu"
     n = x.size
     # widest lane count (multiple of 128) that divides the element count
     # exactly: no padding copies on the common frame shapes

@@ -35,10 +35,18 @@ def make_mesh(shape: Sequence[int], axis_names: Sequence[str] = AXES,
     if n > len(devices):
         raise ValueError(f"mesh {tuple(shape)} needs {n} devices, "
                          f"have {len(devices)}")
+    # topology-aware on TPU (mesh neighbours are ICI neighbours: a v5e
+    # 2x2 host comes back in ring order 0,1,3,2), a plain reshape on
+    # CPU/virtual devices. What it refuses — 3 of a 2x2 host's chips,
+    # by assertion — is an error here too: a mesh silently laid out
+    # against the topology is not the mesh that was asked for
     try:
         arr = mesh_utils.create_device_mesh(tuple(shape), devices[:n])
-    except Exception:  # CPU/virtual devices: no topology info, plain reshape
-        arr = np.array(devices[:n]).reshape(tuple(shape))
+    except (AssertionError, NotImplementedError, ValueError) as exc:
+        raise ValueError(
+            f"mesh {tuple(shape)} cannot be laid out on {n} of this "
+            f"host's {len(devices)} {devices[0].platform} devices: "
+            f"{exc}") from exc
     return Mesh(arr, tuple(axis_names))
 
 
@@ -62,16 +70,14 @@ def spec_dims(spec: str) -> Optional[Tuple[int, int, int]]:
 def spec_dp(spec: str) -> int:
     """The data-parallel factor a spec declares: parsed statically for
     explicit specs (no device access — safe for lint/admission code);
-    ``auto`` consults the backend via :func:`best_mesh`; anything empty
-    or unparseable is 1 (no snapping, no sharding)."""
+    ``auto`` consults the backend via :func:`best_mesh` (and raises
+    with it when there is none); anything empty or unparseable is 1
+    (no snapping, no sharding)."""
     dims = spec_dims(spec)
     if dims is not None:
         return dims[0]
     if spec in ("auto", "true"):
-        try:
-            return factorization(best_mesh())[0]
-        except Exception:  # noqa: BLE001 — no backend: degrade to unsharded
-            return 1
+        return factorization(best_mesh())[0]
     return 1
 
 

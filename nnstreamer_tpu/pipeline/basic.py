@@ -66,9 +66,12 @@ class Queue(Element):
     ``leaky=downstream`` evicts the oldest queued buffer to make room.
 
     ``backend=auto`` (default) uses the native C++ ring for the common
-    non-leaky case when libnnstpu is built; ``python``/``native`` force
-    one. Leaky modes always use the python queue (eviction needs its
-    internals).
+    non-leaky case, building libnnstpu from ``csrc/`` on first use, and
+    the python queue only where that build cannot run (no toolchain) —
+    so a clean clone and a tree with ``build/`` present run the SAME
+    queue. ``python``/``native`` force one; :attr:`active_backend` says
+    which is in use. Leaky modes always use the python queue (eviction
+    needs its internals).
     """
 
     SINK_TEMPLATES = {"sink": None}
@@ -85,13 +88,11 @@ class Queue(Element):
     def _make_q(self):
         cap = max(1, self.max_size_buffers)
         if self.backend in ("auto", "native") and self.leaky == "none":
-            from ..native.lib import native_available, native_built
-            # auto must never trigger an on-demand `make native` from a
-            # plain pipeline parse — only use a lib already on disk;
-            # explicit backend=native may build
-            usable = (native_available() if self.backend == "native"
-                      else native_built() and native_available())
-            if usable:
+            from ..native.lib import native_available
+            # builds from csrc/ on first use (once per process; a no-op
+            # `make` when the library is fresh): what runs must not
+            # depend on whether a git-ignored build/ happens to exist
+            if native_available():
                 return _NativeQueueAdapter(cap)
             if self.backend == "native":
                 raise RuntimeError(
@@ -101,6 +102,13 @@ class Queue(Element):
             raise ValueError(
                 f"{self.name}: leaky queues need backend=python")
         return _pyqueue.Queue(maxsize=cap)
+
+    @property
+    def active_backend(self) -> str:
+        """``"native"`` (C++ ring) or ``"python"`` — what this queue
+        actually runs on, whatever ``backend`` asked for."""
+        return ("native" if isinstance(self._q, _NativeQueueAdapter)
+                else "python")
 
     def set_property(self, key: str, value) -> None:
         super().set_property(key, value)

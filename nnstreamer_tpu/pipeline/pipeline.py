@@ -152,12 +152,11 @@ class Pipeline:
             for f in report.warnings:
                 logger.warning("pipelint: %s", f)
         if self.fuse and self._fusion_plan is None:
+            # a planner failure fails the launch: carrying on unfused
+            # would deliver from a slower path and say nothing.
+            # fuse=false is the explicit way to run the chain path.
             from ..fusion import fuse_pipeline
-            try:
-                self._fusion_plan = fuse_pipeline(self)
-            except Exception:  # noqa: BLE001 -- never block launch on fusion
-                logger.warning(
-                    "fusion: planner failed; running unfused", exc_info=True)
+            self._fusion_plan = fuse_pipeline(self)
         self._sinks_eos.clear()
         self._eos_evt.clear()
         self._error = None

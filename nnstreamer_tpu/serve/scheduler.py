@@ -72,7 +72,6 @@ class ServeScheduler:
         self.batcher = BucketBatcher(buckets, max_wait_s, max_queue,
                                      snap_multiple=snap)
         self._mesh = None          # built lazily on the first place()
-        self._mesh_failed = False  # insufficient devices: degrade once
         self.deadline_s = max(0.0, float(deadline_s))
         self._invoke_fn = invoke_fn
         self._thread: Optional[threading.Thread] = None
@@ -218,31 +217,19 @@ class ServeScheduler:
         """Lay a stacked batch out across the declared mesh with a
         batch-major NamedSharding device_put — BEFORE dispatch, so the
         downstream filter finds every input already committed and its
-        own placement is a no-op. Degrades to host arrays (logged once)
-        when the mesh cannot be built, e.g. fewer devices than the spec
-        asks for: bucket snapping still applies, sharding does not."""
-        mesh = self._mesh_for_place()
-        if mesh is None:
+        own placement is a no-op. A declared mesh that cannot be built
+        (fewer devices than the spec asks for) raises: serving every
+        batch on one chip under a mesh's name is not a degraded mode
+        anyone asked for."""
+        if not self.mesh_spec:
             return stacked
+        if self._mesh is None:
+            from ..parallel.mesh import mesh_from_spec
+            self._mesh = mesh_from_spec(self.mesh_spec)
         from ..parallel.sharding import place_batch
-        placed = place_batch(stacked, mesh)
+        placed = place_batch(stacked, self._mesh)
         self.stats.inc("placed_batches")
         return placed
-
-    def _mesh_for_place(self):
-        if not self.mesh_spec or self._mesh_failed:
-            return self._mesh
-        if self._mesh is None:
-            try:
-                from ..parallel.mesh import mesh_from_spec
-                self._mesh = mesh_from_spec(self.mesh_spec)
-            except Exception as exc:  # noqa: BLE001 — degrade, keep serving
-                self._mesh_failed = True
-                logger.warning(
-                    "%s: mesh %s unavailable (%s); buckets stay snapped "
-                    "but batches are not mesh-placed", self.name,
-                    self.mesh_spec, exc)
-        return self._mesh
 
     def complete(self, batch: List[Request], outputs: Sequence[Any]) -> None:
         """Demux: row ``i`` of every output tensor goes back to the

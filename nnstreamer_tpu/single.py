@@ -28,7 +28,9 @@ class SingleShot:
         self.props = FilterProperties(
             framework=framework, model_files=models,
             input_info=input_info, output_info=output_info,
-            accelerators=tuple(Accelerator.parse(accelerator)),
+            # empty = framework default (TPU), as on the element
+            accelerators=(tuple(Accelerator.parse(accelerator))
+                          if accelerator else (Accelerator.DEFAULT,)),
             custom_properties=custom)
         self.fw = find_filter(framework)()
         self._opened = False

@@ -34,6 +34,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import numpy as np
 
 from ..utils.log import logger
+from ..utils.xla_cache import ensure_compile_cache
 from .base import (TrainerEvent, TrainerFramework, TrainerProperties,
                    TrainerStatus, register_trainer)
 
@@ -92,6 +93,7 @@ class JaxTrainer(TrainerFramework):
 
     # -- lifecycle --------------------------------------------------------
     def create(self, props: TrainerProperties) -> None:
+        ensure_compile_cache()  # before the zoo init compiles
         self._props = props
         cfg = props.model_config
         if cfg.startswith("zoo://"):

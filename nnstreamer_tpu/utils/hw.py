@@ -65,60 +65,50 @@ def capabilities() -> Dict[str, Any]:
     }
 
 
-# peak dense bf16 TFLOPS per JAX DEVICE by device-kind substring,
-# checked in order (first match wins — "v5 lite" must match before
-# "v5"). Public per-chip figures: v2 45, v3 123, v4 275, v5e 197,
-# v5p 459, v6e 918 — but on v2/v3 jax.devices() enumerates TensorCores
-# (2 per chip) and a single-device jit runs on ONE core, so those
-# entries carry the per-core half to keep MFU honest.
-_PEAK_BF16_TFLOPS = (
-    ("v6 lite", 918.0), ("v6e", 918.0),
-    ("v5 lite", 197.0), ("v5litepod", 197.0), ("v5e", 197.0),
-    ("v5p", 459.0), ("v5", 459.0),
-    ("v4", 275.0), ("v3", 61.5), ("v2", 22.5),
+# Peak figures per JAX DEVICE, keyed by device-kind substring (checked
+# in order). Source: Google Cloud TPU documentation, per-chip figures —
+# v2 45 TFLOP/s / 700 GB/s, v3 123 / 900, v4 275 / 1228, v5e 197 / 819,
+# v5p 459 / 2765, v6e 918 / 1640. On v2/v3 jax.devices() enumerates
+# TensorCores (2 per chip) and a single-device jit runs on ONE core, so
+# those rows carry the per-core half. A kind that matches no row is an
+# error, not a default: a utilization over a guessed peak is a made-up
+# number.
+_PEAKS = (
+    # (kind substring, peak dense bf16 TFLOP/s, peak HBM GB/s)
+    ("v6 lite", 918.0, 1640.0), ("v6e", 918.0, 1640.0),
+    ("v5 lite", 197.0, 819.0), ("v5litepod", 197.0, 819.0),
+    ("v5e", 197.0, 819.0),
+    ("v5p", 459.0, 2765.0),
+    ("v4", 275.0, 1228.0), ("v3", 61.5, 450.0), ("v2", 22.5, 350.0),
 )
 
 
-def _match_peak(table, device, scale: float):
-    """Shared device-kind lookup for the peak tables: resolve the
-    device, require TPU, first substring match wins (the one place the
-    'v5 lite before v5' ordering rule lives)."""
+def _peaks(device):
     import jax
     d = device if device is not None else jax.devices()[0]
-    if d.platform != "tpu":
-        return None
-    kind = getattr(d, "device_kind", "").lower()
-    for sub, value in table:
-        if sub in kind:
-            return value * scale
-    return None
+    kind = getattr(d, "device_kind", "")
+    if d.platform == "tpu":
+        lowered = kind.lower()
+        for sub, tflops, gbps in _PEAKS:
+            if sub in lowered:
+                return tflops * 1e12, gbps * 1e9
+    raise ValueError(
+        f"no peak figures for device kind {kind!r} (platform "
+        f"{d.platform}); add its row to utils/hw.py _PEAKS with its "
+        f"source")
 
 
-def peak_flops(device=None):
-    """Peak dense bf16 FLOPS/s for ``device`` (default: first jax
-    device), or None when the kind is unknown (e.g. CPU) — callers must
-    not fabricate an MFU from a guess."""
-    return _match_peak(_PEAK_BF16_TFLOPS, device, 1e12)
+def peak_flops(device=None) -> float:
+    """Peak dense bf16 FLOP/s for ``device`` (default: first jax
+    device). Raises ValueError for a kind the table does not know."""
+    return _peaks(device)[0]
 
 
-# peak HBM bandwidth (bytes/s) per JAX DEVICE by device-kind substring,
-# same matching/convention rules as _PEAK_BF16_TFLOPS (public per-chip
-# figures: v2 700 GB/s, v3 900, v4 1228, v5e 819, v5p 2765, v6e 1640;
-# v2/v3 carry per-TensorCore halves since jax enumerates cores there)
-_PEAK_HBM_GBPS = (
-    ("v6 lite", 1640.0), ("v6e", 1640.0),
-    ("v5 lite", 819.0), ("v5litepod", 819.0), ("v5e", 819.0),
-    ("v5p", 2765.0), ("v5", 2765.0),
-    ("v4", 1228.0), ("v3", 450.0), ("v2", 350.0),
-)
-
-
-def peak_membw(device=None):
-    """Peak HBM bytes/s for ``device`` (default: first jax device), or
-    None when unknown — callers must not fabricate an MBU from a guess.
-    The honest denominator for decode-phase bandwidth utilization, the
-    generation-side analog of :func:`peak_flops`."""
-    return _match_peak(_PEAK_HBM_GBPS, device, 1e9)
+def peak_membw(device=None) -> float:
+    """Peak HBM bytes/s for ``device`` (default: first jax device) —
+    the denominator for decode-phase bandwidth utilization. Raises
+    ValueError for a kind the table does not know."""
+    return _peaks(device)[1]
 
 
 def is_available(kind: str) -> bool:

@@ -1,7 +1,7 @@
 """Coalescing D2H fetch service (tensors/fetch.py).
 
-The service exists because frame-at-a-time device->host fetches cap a
-pipeline at ~1/RTT fps on a remote-attached chip; these tests pin the
+The service batches frame-at-a-time device->host fetches, each of which
+is a host sync with a fixed cost; these tests pin the
 semantics (transparent Chunk resolution, shape/dtype without sync,
 batching across frames, error delivery) on the CPU backend.
 """
@@ -71,7 +71,7 @@ class TestSubmitFetch:
         """With a slow link (device_get stalled), frames queued behind
         the in-flight RPC must share the NEXT one — frames_per_rpc_avg
         > 1 — and the counters must add up. This is the bench's
-        fetch_coalesce proof hook (VERDICT r4 item 2)."""
+        fetch_coalesce proof hook."""
         real_get = jax.device_get
         gate = threading.Event()
 

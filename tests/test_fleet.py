@@ -635,11 +635,19 @@ class TestElasticFleetSlow:
                 rp.preempt()
             return lat
 
+        # JAX's own persistent cache is placed from outside, per arm:
+        # the cold arm must not find the program a previous test run
+        # left in <root>/.jax_cache
+        def xla(d):
+            return {"JAX_COMPILATION_CACHE_DIR": str(tmp_path / d)}
+
         cold_spec = ReplicaSpec(desc_template=desc,
-                                ckpt_root=str(tmp_path / "ck-cold"))
+                                ckpt_root=str(tmp_path / "ck-cold"),
+                                env=xla("xla-cold"))
         warm_spec = ReplicaSpec(desc_template=desc,
                                 ckpt_root=str(tmp_path / "ck-warm"),
-                                compile_cache=str(tmp_path / "cc"))
+                                compile_cache=str(tmp_path / "cc"),
+                                env=xla("xla-warm"))
 
         cold = run_life(cold_spec, "cold-1")
         seed = run_life(warm_spec, "warm-0")  # records the signature

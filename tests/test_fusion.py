@@ -436,13 +436,20 @@ class TestLifecycle:
         assert len(_segments_of(p)) == 1
         p.stop()
 
-    def test_fusion_failure_never_blocks_launch(self, monkeypatch):
+    def test_fusion_failure_fails_the_launch(self, monkeypatch):
+        """A planner failure is loud: running unfused instead would
+        deliver from a slower path and say nothing. fuse=false is the
+        explicit way onto the chain path."""
         import nnstreamer_tpu.fusion as fusion
         monkeypatch.setattr(
             fusion, "fuse_pipeline",
             lambda *a, **k: (_ for _ in ()).throw(RuntimeError("boom")))
-        p = parse_launch(f"tensortestsrc caps={CAPS_F32} num-buffers=2 ! "
-                         f"{RUN2} ! appsink name=out")
-        p.run(timeout=60)  # unfused, but running
+        desc = (f"tensortestsrc caps={CAPS_F32} num-buffers=2 ! "
+                f"{RUN2} ! appsink name=out")
+        with pytest.raises(RuntimeError, match="boom"):
+            parse_launch(desc).start()
+        p = parse_launch(desc)
+        p.fuse = False
+        p.run(timeout=60)
         assert not _segments_of(p)
         assert len(p["out"].buffers) == 2

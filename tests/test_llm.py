@@ -239,7 +239,7 @@ def test_llamacpp_alias():
 
 def test_prefill_single_dispatch_matches_sequential():
     """Batched prefill: tokens identical to the per-token path with a
-    prefill dispatch count of exactly 1 (VERDICT item: llamacpp n_batch
+    prefill dispatch count of exactly 1 (the llamacpp n_batch
     analog)."""
     import jax
     import jax.numpy as jnp
@@ -581,3 +581,34 @@ def test_llm_loads_trained_weights_from_checkpoint(tmp_path):
         fw.close()
     np.testing.assert_array_equal(fw_tokens[0], fw_tokens[1])
     assert not np.array_equal(fw_tokens[0], out_random)
+
+
+def test_async_failure_is_counted_as_an_invoke_error(monkeypatch):
+    """A failure AFTER invoke_async returned (here: admission in the
+    scheduler thread) has no caller to raise into. It must not be a
+    stream that silently never yields a token: the element's
+    invoke_errors / frames_dropped see it, like a sync invoke failure."""
+    from nnstreamer_tpu.filters.llm import LlmFilter
+
+    def boom(self, prompt, max_len):
+        raise RuntimeError("device refused the prefill")
+
+    monkeypatch.setattr(LlmFilter, "_prefill_prompt", boom)
+    pipe = parse_launch(
+        f'appsrc name=in caps="{CAPS}" '
+        f'! tensor_filter name=f framework=llm model="{ZOO}" '
+        'invoke-async=true invoke-dynamic=true '
+        'custom="max_tokens:4,n_parallel:2,max_len:32" '
+        '! appsink name=out')
+    pipe.start()
+    pipe["in"].push_buffer(Buffer.from_arrays(
+        [np.array([1, 2, 3, 4], np.int32)]))
+    deadline = time.monotonic() + 60
+    while not pipe["f"].stats["invoke_errors"] \
+            and time.monotonic() < deadline:
+        time.sleep(0.05)
+    pipe["in"].end_stream()
+    pipe.stop()
+    assert pipe["f"].stats["invoke_errors"] == 1
+    assert pipe["f"].stats["frames_dropped"] == 1
+    assert pipe["out"].buffers == []

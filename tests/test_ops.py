@@ -15,7 +15,7 @@ from nnstreamer_tpu.ops import fused_normalize, normalize_reference
 def test_kernel_parity_interpret(shape):
     x = np.random.default_rng(0).integers(0, 255, shape, np.uint8,
                                           endpoint=True)
-    out = fused_normalize(jnp.asarray(x), force_pallas=True)
+    out = fused_normalize(jnp.asarray(x))
     ref = normalize_reference(jnp.asarray(x), 1 / 127.5, 127.5)
     assert out.dtype == jnp.bfloat16
     assert out.shape == tuple(shape)
@@ -27,17 +27,20 @@ def test_kernel_parity_interpret(shape):
 def test_custom_scale_offset_and_dtype():
     x = np.array([[0, 255], [128, 64]], np.uint8)
     out = fused_normalize(jnp.asarray(x), scale=2.0, offset=1.0,
-                          dtype=jnp.float32, force_pallas=True)
+                          dtype=jnp.float32)
     np.testing.assert_allclose(
         np.asarray(out), (x.astype(np.float32) - 1.0) * 2.0, rtol=1e-6)
 
 
-def test_oracle_fallback_off_tpu():
-    # without force_pallas the CPU path is the oracle itself
-    x = jnp.asarray(np.arange(16, dtype=np.uint8))
-    np.testing.assert_allclose(
-        np.asarray(fused_normalize(x), np.float32),
-        np.asarray(normalize_reference(x, 1 / 127.5, 127.5), np.float32))
+def test_kernel_body_runs_off_tpu(monkeypatch):
+    """Off the TPU the kernel runs through the Pallas interpreter — the
+    oracle is what it is compared with, never what is returned."""
+    from nnstreamer_tpu.ops import normalize
+    monkeypatch.setattr(
+        normalize, "normalize_reference",
+        lambda *a, **k: pytest.fail("reference used as a stand-in"))
+    out = fused_normalize(jnp.asarray(np.arange(16, dtype=np.uint8)))
+    assert out.shape == (16,) and out.dtype == jnp.bfloat16
 
 
 class TestSparsePack:
@@ -268,9 +271,9 @@ class TestSparseDiffMode:
 
 
 class TestFusedAttention:
-    """ops/attention.py: the Pallas fused-attention kernel (VERDICT r4
-    item 3) — numerical parity with stock flax attention via the
-    interpreter on CPU, plus the fallback/dispatch contract."""
+    """ops/attention.py: the Pallas fused-attention kernel —
+    numerical parity with stock flax attention via the
+    interpreter on CPU, plus the mask dispatch contract."""
 
     def _qkv(self, b=2, s=196, h=4, d=32, dtype=np.float32, seed=0):
         import jax.numpy as jnp
@@ -285,7 +288,7 @@ class TestFusedAttention:
         from nnstreamer_tpu.ops.attention import fused_attention
         q, k, v = self._qkv()
         want = nn.dot_product_attention(q, k, v)
-        got = fused_attention(q, k, v, interpret=True)
+        got = fused_attention(q, k, v)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-6)
 
@@ -297,7 +300,7 @@ class TestFusedAttention:
         for s, d in ((196, 64), (128, 128), (7, 8)):
             q, k, v = self._qkv(b=1, s=s, h=2, d=d, seed=s)
             want = nn.dot_product_attention(q, k, v)
-            got = fused_attention(q, k, v, interpret=True)
+            got = fused_attention(q, k, v)
             np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                        atol=2e-6, err_msg=f"s={s} d={d}")
 

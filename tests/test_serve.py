@@ -821,21 +821,17 @@ class TestMeshServe:
         assert rep["devices"] == 8
         assert rep["placed_batches"] == 1
 
-    def test_scheduler_degrades_when_mesh_unavailable(self):
-        """A spec the host cannot satisfy degrades gracefully: buckets
-        stay snapped, batches stay host arrays, serving continues."""
+    def test_scheduler_refuses_unavailable_mesh(self):
+        """A spec the host cannot satisfy is an error at the first
+        batch — never one chip quietly serving under a mesh's name."""
         sched = ServeScheduler(buckets=(1, 2, 4, 8), max_wait_s=0.01,
-                               mesh_spec="64x1x1", name="ms-degrade")
+                               mesh_spec="64x1x1", name="ms-refuse")
         assert sched.batcher.buckets == [64]
         for i in range(4):
             assert sched.submit(0, [np.full(4, float(i), np.float32)])
-        batch, bucket, stacked = sched.next_batch()
-        assert bucket == 64 and len(batch) == 4
-        assert isinstance(stacked[0], np.ndarray)  # not mesh-placed
-        rep = sched.report()
-        assert rep["mesh"] == "64x1x1"
-        assert rep["devices"] == 0
-        assert rep["placed_batches"] == 0
+        with pytest.raises(ValueError, match="needs 64 devices"):
+            sched.next_batch()
+        assert sched.report()["placed_batches"] == 0
 
     def test_mesh_serve_end_to_end_zero_loss(self):
         """The serve chaos accounting identity with the mesh path

@@ -189,8 +189,8 @@ def test_mux_demux_under_start_stop_churn():
 def test_native_ring_close_race():
     """Producers blocked in push() while the ring is being torn down
     (queue stop): must unblock, not crash, not hang."""
-    from nnstreamer_tpu.native.lib import native_available, native_built
-    if not (native_built() and native_available()):
+    from nnstreamer_tpu.native.lib import native_available
+    if not native_available():
         pytest.skip("libnnstpu not built")
     from nnstreamer_tpu.pipeline.registry import make_element
     from nnstreamer_tpu.tensors.buffer import Buffer, Chunk
@@ -381,9 +381,8 @@ def test_serve_fanout_no_loss_no_duplication():
     assert rep["occupancy_avg"] > 0.0
 
 
-def test_weather_adaptive_qos_bounded_under_slow_fetch(monkeypatch):
-    """Link weather degrades ~100x mid-stream (VERDICT r4 item 7): every
-    D2H fetch is slowed to 0.25 s. The sink's qos=true feedback engages
+def test_render_time_adaptive_qos_bounded_under_slow_fetch(monkeypatch):
+    """D2H degrades ~100x mid-stream: every fetch is slowed to 0.25 s. The sink's qos=true feedback engages
     the tensor_filter's throttle, frames drop AT THE FILTER (counted in
     qos_dropped — no invoke, no fetch ticket), and the fetch backlog
     stays bounded instead of ballooning one ticket per source frame."""

@@ -126,7 +126,7 @@ def test_trainer_resume_from_checkpoint(tmp_path):
 
 
 def test_mesh_checkpoint_round_trip_resumes_sharded(tmp_path, caplog):
-    """VERDICT r3 item 8: save mesh-trainer params, restore onto the
+    """Save mesh-trainer params, restore onto the
     SAME mesh with explicit shardings (no orbax 'Sharding info not
     provided' topology warning), resume training, loss keeps falling."""
     import logging
@@ -172,8 +172,7 @@ def test_mesh_checkpoint_round_trip_resumes_sharded(tmp_path, caplog):
 def test_trainer_pipeline_on_mesh(tmp_path):
     """datareposrc -> tensor_trainer on the 8-virtual-device mesh: the
     sharded train step from parallel/train.py must actually run in the
-    pipeline path, with decreasing loss and params laid out on the mesh
-    (VERDICT r2 item 2 done-criterion)."""
+    pipeline path, with decreasing loss and params laid out on the mesh."""
     import jax
     data, jpath, _, _ = _write_dataset(tmp_path, n=32)
     save = tmp_path / "model_out"

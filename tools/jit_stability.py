@@ -111,9 +111,8 @@ def main(argv=None) -> int:
         snap2 = _run_once(desc, fuse, in_flight, opts.timeout)
         s1, s2 = steady_recompiles(snap1), steady_recompiles(snap2)
         total_steady += s2
-        extra = (f", {monitor.count} compile event(s)"
-                 if monitor.available else "")
-        print(f"{label}: pass1 compiles={s1}, pass2 compiles={s2}{extra}")
+        print(f"{label}: pass1 compiles={s1}, pass2 compiles={s2}, "
+              f"{monitor.count} compile event(s)")
         if s2:
             detail = {k: v for k, v in snap2.items()
                       if v.get("jit_recompiles") or v.get("jit_misses")}
