@@ -1,0 +1,145 @@
+"""Driver kind ``stream_closed`` (a traffic mix names it under ``kind``; run.py
+loads ``drivers/<kind>.py`` and builds its ``Driver``)."""
+from __future__ import annotations
+
+import contextlib
+import threading
+import time
+
+import numpy as np
+
+from nnsbench.generator import (DRAIN_S, FILTER_FAULTS, annotate, counted,
+                                tensor_caps, wait_for)
+
+
+class Driver:
+    """``stream_closed``: one pipeline, buffers of ``frames_per_buffer``
+    frames, at most ``max_outstanding`` of them between the push and the
+    sink: a buffer is pushed as soon as one has arrived. (``appsrc
+    max-buffers=`` is not applied to a launch string's appsrc, whose
+    queue stays 64 deep, so the entry alone does not bound the backlog;
+    the credits do, at the depth the pipeline's own queues and window
+    hold.)"""
+
+    def __init__(self, ctx):
+        self.ctx = ctx
+        t = ctx.traffic
+        self.rows = int(t["frames_per_buffer"])
+        hw = ctx.sizes["image_size"]
+        rng = np.random.default_rng(ctx.seed)
+        self.pool = [rng.integers(0, 255, (self.rows, hw, hw, 3), np.uint8,
+                                  endpoint=True)
+                     for _ in range(int(t["pool_buffers"]))]
+        # which pool buffer the n-th push carries: the seed's order
+        self.order = np.random.default_rng(ctx.seed + 1)
+        self.pipe = None
+        self.pushed = {}          # seq -> (t_push, pool index)
+        self.arrived = {}         # seq -> (t_arrive, logits)
+        self._lock = threading.Lock()
+        self._stop = threading.Event()
+        self._pusher = None
+        self._credits = threading.Semaphore(int(t["max_outstanding"]))
+
+    def setup(self):
+        from nnstreamer_tpu import parse_launch
+        hw = self.ctx.sizes["image_size"]
+        self.pipe = parse_launch(self.ctx.traffic["pipeline"].format(
+            caps=tensor_caps("uint8", f"3:{hw}:{hw}:{self.rows}"),
+            model=self.ctx.model_file))
+        self.pipe["out"].connect(self._on_buffer)
+        self.pipe.start()
+        self._pusher = threading.Thread(target=self._push_loop, daemon=True,
+                                        name="bench-push")
+        self._pusher.start()
+        # warm-up and ramp: the first buffer compiles the one program;
+        # the window opens on a full pipeline
+        wait_for(lambda: len(self.arrived) >= 1 or self._errors(),
+                 self.ctx.compile_wait_s, "the first buffer")
+        time.sleep(float(self.ctx.traffic["ramp_s"]))
+
+    def _errors(self):
+        return self.pipe["f"].stats["invoke_errors"]
+
+    def _push_loop(self):
+        from nnstreamer_tpu import Buffer
+        seq = 0
+        while not self._stop.is_set():
+            with annotate("bench.wait_credit"):
+                if not self._credits.acquire(timeout=0.1):
+                    continue
+            idx = int(self.order.integers(len(self.pool)))
+            with annotate("bench.push"):
+                t = time.perf_counter()
+                with self._lock:
+                    self.pushed[seq] = (t, idx)
+                self.pipe["in"].push_buffer(
+                    Buffer.from_arrays([self.pool[idx]], pts=seq))
+            seq += 1
+
+    def _on_buffer(self, buf):
+        with annotate("bench.pull"):
+            out = np.asarray(buf.chunks[0].host())
+            t = time.perf_counter()
+        with self._lock:
+            self.arrived[buf.pts] = (t, out)
+        self._credits.release()
+
+    def run(self, window):
+        f = self.pipe["f"]
+        window.sample("filter_latency_us", f.latency_average_us)
+        self.base = f.stats.snapshot()
+        window.run()
+        self._stop.set()
+        # whatever was pushed inside the window is waited for
+        due = [s for s, (t, _) in self.pushed.items() if window.inside(t)]
+        try:
+            wait_for(lambda: all(s in self.arrived for s in due)
+                     or self._errors(), DRAIN_S, "the window's buffers")
+        except TimeoutError:
+            pass
+        self.window = window
+        self.counters = {"filter": f.stats.snapshot(),
+                         "filter_base": self.base,
+                         "transfer": f.transfer_report(),
+                         "queue_backend": self.pipe["q0"].active_backend}
+
+    def teardown(self):
+        self._stop.set()
+        if self.pipe is not None:
+            # unblock a pusher stuck on the full entry, then stop
+            with contextlib.suppress(Exception):
+                self.pipe.stop()
+            self._pusher.join(10.0)
+            self.pipe = None
+
+    def results(self):
+        """Counts, latencies and the answers to compare."""
+        w = self.window
+        due = {s: v for s, v in self.pushed.items() if w.inside(v[0])}
+        got = {s: self.arrived[s] for s in due if s in self.arrived}
+        delivered = sum(1 for t, _ in self.arrived.values() if w.inside(t))
+        lat_ms = [(got[s][0] - due[s][0]) * 1e3 for s in got]
+        bad = counted(self.counters["filter"], self.counters["filter_base"],
+                      FILTER_FAULTS + ("jit_recompiles",))
+        return {
+            "attempted": len(due) * self.rows,
+            "failed": (len(due) - len(got)) * self.rows + bad * self.rows,
+            "units_delivered": delivered * self.rows,
+            # a buffer's frames share its latency: one sample a buffer
+            "latencies_ms": lat_ms,
+            "answers": [(due[s][1], got[s][1]) for s in sorted(got)],
+        }
+
+    def check_inputs(self):
+        """(pool index, row) pairs to compare, drawn from the seed, and
+        the frames themselves."""
+        rng = np.random.default_rng(self.ctx.seed + 2)
+        n = int(self.ctx.traffic["check_rows"])
+        # exactly n distinct rows: the reference then always runs the
+        # same shapes, which the compile cache holds after the first run
+        cells = rng.choice(len(self.pool) * self.rows,
+                           min(n, len(self.pool) * self.rows), replace=False)
+        pairs = sorted((int(c) // self.rows, int(c) % self.rows)
+                       for c in cells)
+        frames = np.stack([self.pool[b][r] for b, r in pairs])
+        return pairs, frames
