@@ -30,6 +30,7 @@ from ..tensors.transfer import submit_fetch
 from ..tensors.caps import Caps
 from ..tensors.info import TensorInfo, TensorsConfig, TensorsInfo
 from ..tensors.types import TensorFormat
+from ..obs import context as _obs_ctx
 from ..obs import events as _obs_events
 from ..pipeline.element import Element, TransferError
 from ..pipeline.events import Event, QosEvent
@@ -70,7 +71,7 @@ class TensorFilter(Element):
     SINK_TEMPLATES = {"sink": "other/tensors"}
     SRC_TEMPLATES = {"src": "other/tensors"}
     # under overlap-depth>0 the executor adds dispatch/complete spans
-    SPAN_POINTS = ("chain", "dispatch", "complete")
+    SPAN_POINTS = ("chain", "window-wait", "dispatch", "complete")
     PROPS = {
         "framework": "auto",
         "model": "",
@@ -614,34 +615,37 @@ class TensorFilter(Element):
         upstream queue exactly like a slow synchronous invoke), enqueue
         the device program, and hand completion to the completer
         thread. The chain thread never waits on the device."""
-        t_disp = self._overlap.window.acquire()
-        t0 = time.perf_counter_ns()
-        c0 = getattr(self.fw, "compile_count", 0)
-        try:
-            handle = self.fw.dispatch(inputs,
-                                      donate=bool(self.donate_input))
-        except InvokeDrop:
-            # release FIRST: the accounting below must not be able to
-            # strand the slot (the completer never sees this frame)
-            self._overlap.window.release(t_disp)
-            if self._breaker is not None:
-                self._breaker.record_success()
-            self.stats.inc("frames_dropped")
-            return
-        except Exception as exc:  # noqa: BLE001
-            self._overlap.window.release(t_disp)
-            self._account_invoke_error(exc)
-            self._settle_failed_rows(buf)
-            return
-        try:
-            self._note_recompiles(c0)
-            self._record_dispatch(time.perf_counter_ns() - t0)
-            self._overlap.submit(buf, handle, t_disp)
-        except BaseException:
-            # a dispatch-side failure after acquire: the slot would
-            # otherwise leak window depth permanently
-            self._overlap.window.release(t_disp)
-            raise
+        t_disp = self._overlap.window.acquire(
+            ctx=_obs_ctx.ctx_of(buf), element=self.name)
+        with self._overlap.dispatching(buf):
+            t0 = time.perf_counter_ns()
+            c0 = getattr(self.fw, "compile_count", 0)
+            try:
+                handle = self.fw.dispatch(inputs,
+                                          donate=bool(self.donate_input))
+            except InvokeDrop:
+                # release FIRST: the accounting below must not be able
+                # to strand the slot (the completer never sees this
+                # frame)
+                self._overlap.window.release(t_disp)
+                if self._breaker is not None:
+                    self._breaker.record_success()
+                self.stats.inc("frames_dropped")
+                return
+            except Exception as exc:  # noqa: BLE001
+                self._overlap.window.release(t_disp)
+                self._account_invoke_error(exc)
+                self._settle_failed_rows(buf)
+                return
+            try:
+                self._note_recompiles(c0)
+                self._record_dispatch(time.perf_counter_ns() - t0)
+                self._overlap.submit(buf, handle, t_disp)
+            except BaseException:
+                # a dispatch-side failure after acquire: the slot would
+                # otherwise leak window depth permanently
+                self._overlap.window.release(t_disp)
+                raise
 
     def _complete_frame(self, entry) -> Buffer:
         """COMPLETER side: materialize one frame's results and run the

@@ -52,6 +52,13 @@ log = logging.getLogger(__name__)
 _SKIP = object()
 
 
+def _ctx_of(buf):
+    """The frame's trace context. Harness stubs may hand the executor
+    bare objects; only real Buffers carry the extras dict it rides in."""
+    extras = getattr(buf, "extras", None)
+    return extras.get(_obs_ctx.CTX_KEY) if extras is not None else None
+
+
 class _InFlight:
     """One dispatched frame awaiting completion."""
 
@@ -194,20 +201,18 @@ class OverlapExecutor:
 
     # ---- dispatcher side (chain thread) --------------------------------
 
+    def dispatching(self, buf):
+        """The frame's dispatch span: the element wraps its staging and
+        enqueue of the device program (and :meth:`submit`) in it."""
+        return _obs_spans.region("nns.filter.dispatch", "dispatch",
+                                 _ctx_of(buf), name=f"{self._name}:dispatch",
+                                 element=self._name)
+
     def submit(self, buf, payload, t_dispatch_ns: int) -> None:
         """Hand a dispatched frame to the completer. The caller must
         already hold a window slot (``window.acquire()``) — the element
         acquires BEFORE dispatching so backpressure lands before device
         work is queued, and passes the returned timestamp here."""
-        if _obs_spans.ENABLED:
-            # harness stubs may hand the executor bare objects; only
-            # real Buffers carry the extras dict a context rides in
-            extras = getattr(buf, "extras", None)
-            ctx = extras.get(_obs_ctx.CTX_KEY) if extras is not None \
-                else None
-            if ctx is not None:
-                _obs_spans.record_span(f"{self._name}:dispatch", "dispatch",
-                                       time.time_ns(), 0, ctx)
         with self._cv:
             self._ensure_thread()
             entry = _InFlight(self._seq, buf, payload, t_dispatch_ns)
@@ -269,21 +274,17 @@ class OverlapExecutor:
             try:
                 outbuf: Any = None
                 err: Optional[BaseException] = None
-                t_wall = time.time_ns() if _obs_spans.ENABLED else 0
-                try:
-                    outbuf = self._complete_cb(entry)
-                except BaseException as exc:  # noqa: BLE001 — accounted
-                    err = exc
-                if t_wall:
-                    extras = getattr(entry.buf, "extras", None)
-                    ctx = extras.get(_obs_ctx.CTX_KEY) \
-                        if extras is not None else None
-                    if ctx is not None:
-                        dur = time.time_ns() - t_wall
-                        _obs_spans.record_span(f"{self._name}:complete",
-                                               "complete", t_wall, dur,
-                                               ctx)
-                        ctx.c_ns += dur
+                ctx = _ctx_of(entry.buf)
+                with _obs_spans.region(
+                        "nns.filter.complete", "complete", ctx,
+                        name=f"{self._name}:complete",
+                        element=self._name) as span:
+                    try:
+                        outbuf = self._complete_cb(entry)
+                    except BaseException as exc:  # noqa: BLE001 — accounted
+                        err = exc
+                if ctx is not None:
+                    ctx.c_ns += span.dur_ns
                 if err is None:
                     ready = ([outbuf] if self._reorder is None
                              else self._reorder.push(entry.seq, outbuf))

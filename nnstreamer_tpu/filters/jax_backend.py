@@ -37,6 +37,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs import spans as _obs_spans
 from ..tensors.info import TensorsInfo
 from ..utils.log import logger
 from ..utils.xla_cache import ensure_compile_cache
@@ -110,6 +111,8 @@ class JaxFilter(FilterFramework):
         # persistent compile cache identity (fleet/cache.py): model URI
         # + mesh spec — donation variants key per entry, not per model
         self._cache_key = ""
+        # names the jitted program ``jit_nns_filter_<stem>`` in a trace
+        self._model_stem = "model"
 
     # -- lifecycle --------------------------------------------------------
     def open(self, props: FilterProperties) -> None:
@@ -177,12 +180,15 @@ class JaxFilter(FilterFramework):
                         warmed, self._cache_key)
 
     def _load_model(self, model: str, props: FilterProperties) -> None:
+        self._model_stem = os.path.splitext(os.path.basename(
+            model.rstrip("/")))[0]
         if model.startswith("zoo://"):
             from ..models import zoo
             parsed = urllib.parse.urlparse(model)
             kwargs = {k: v[0] for k, v in
                       urllib.parse.parse_qs(parsed.query).items()}
             name = parsed.netloc or parsed.path.lstrip("/")
+            self._model_stem = name
             (self._apply, self._params,
              self._in_info, self._out_info) = zoo.build(name, **kwargs)
         elif model.endswith(".py"):
@@ -227,11 +233,9 @@ class JaxFilter(FilterFramework):
         exe = self._jit_cache.get(key)
         if exe is None:
             import jax
-            fn = self._apply
-
-            def call(params, *xs):
-                return fn(params, *xs)
-
+            # a stable program name for the trace's XLA Modules line
+            call = _obs_spans.named_program(
+                "nns_filter_" + self._model_stem, self._apply)
             exe = jax.jit(call, donate_argnums=donate_idx) if donate_idx \
                 else jax.jit(call)
             self._jit_cache[key] = exe
@@ -335,19 +339,23 @@ class JaxFilter(FilterFramework):
             if self._mesh is not None:
                 xs = self._place_inputs(inputs)
             else:
-                xs = []
-                staged: List[int] = []
-                for i, x in enumerate(inputs):
-                    if isinstance(x, jax.Array):
-                        if len(x.sharding.device_set) > 1:
-                            # mesh-committed upstream output: collapse
-                            # to this chip (upstream-owned, not donated)
-                            x = jax.device_put(x, self._device)
-                        xs.append(x)
-                    else:
-                        xs.append(jax.device_put(np.asarray(x),
-                                                 self._device))
-                        staged.append(i + 1)  # 1-based: arg 0 is params
+                xs = list(inputs)
+                # 1-based: arg 0 is params
+                staged = [i + 1 for i, x in enumerate(xs)
+                          if not isinstance(x, jax.Array)]
+                if staged:
+                    host = [np.asarray(xs[i - 1]) for i in staged]
+                    with _obs_spans.region(
+                            "nns.transfer.upload", "transfer",
+                            arrays=len(host),
+                            bytes=sum(a.nbytes for a in host)):
+                        for i, a in zip(staged, host):
+                            xs[i - 1] = jax.device_put(a, self._device)
+                for i, x in enumerate(xs):
+                    if len(x.sharding.device_set) > 1:
+                        # mesh-committed upstream output: collapse to
+                        # this chip (upstream-owned, not donated)
+                        xs[i] = jax.device_put(x, self._device)
                 if donate and staged \
                         and self._device.platform in self._DONATION_PLATFORMS:
                     donate_idx = tuple(staged)

@@ -43,6 +43,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from ..obs import spans as _obs_spans
 from ..tensors.info import TensorsInfo
 from ..utils.atomic import Counters
 from ..utils.log import logger
@@ -157,26 +158,31 @@ class _PagedBackend:
         span = max(plen, min(plen + int(budget), self.max_len))
         return -(-span // self.bs)
 
-    def _insert_span(self, blocks: List[int], k_np, v_np,
-                     valid: int) -> None:
-        """Block-align (k_np, v_np) [L, n, H, Dh] (first ``valid`` rows
-        real) and write them into ``blocks``. Rows past ``valid`` are
-        zeros the decode loop overwrites before its validity mask can
-        reach them — the same padded-tail argument as prefill's."""
+    def _insert_span(self, blocks: List[int], k, v, valid: int) -> None:
+        """Block-align (k, v) [L, n, H, Dh] (first ``valid`` rows real;
+        device arrays are fetched here) and write them into ``blocks``.
+        Rows past ``valid`` are zeros the decode loop overwrites before
+        its validity mask can reach them — the same padded-tail
+        argument as prefill's. The whole host round trip is one
+        ``nns.llm.kv_copy`` span: for a fresh prefill its fetch is
+        where the scheduler thread waits for the device."""
         import jax.numpy as jnp
 
-        layers, _, heads, hd = k_np.shape
-        spanf = len(blocks) * self.bs
-        kb = np.zeros((layers, spanf, heads, hd), k_np.dtype)
-        vb = np.zeros((layers, spanf, heads, hd), v_np.dtype)
-        n = min(int(valid), spanf, k_np.shape[1])
-        kb[:, :n] = k_np[:, :n]
-        vb[:, :n] = v_np[:, :n]
-        sh = (layers, len(blocks), self.bs, heads, hd)
-        self.pool = self.f._pool_insert(
-            self.pool, jnp.asarray(kb.reshape(sh)),
-            jnp.asarray(vb.reshape(sh)),
-            jnp.asarray(np.asarray(blocks, np.int32)))
+        with _obs_spans.region("nns.llm.kv_copy", "llm",
+                               blocks=len(blocks)):
+            k_np, v_np = np.asarray(k), np.asarray(v)
+            layers, _, heads, hd = k_np.shape
+            spanf = len(blocks) * self.bs
+            kb = np.zeros((layers, spanf, heads, hd), k_np.dtype)
+            vb = np.zeros((layers, spanf, heads, hd), v_np.dtype)
+            n = min(int(valid), spanf, k_np.shape[1])
+            kb[:, :n] = k_np[:, :n]
+            vb[:, :n] = v_np[:, :n]
+            sh = (layers, len(blocks), self.bs, heads, hd)
+            self.pool = self.f._pool_insert(
+                self.pool, jnp.asarray(kb.reshape(sh)),
+                jnp.asarray(vb.reshape(sh)),
+                jnp.asarray(np.asarray(blocks, np.int32)))
 
     def _suffix_prefill(self, past_k, past_v, past_len: int,
                         suffix: np.ndarray):
@@ -189,10 +195,12 @@ class _PagedBackend:
             sb *= 2
         padded = np.zeros(sb, np.int32)
         padded[:suffix.size] = suffix
-        return self.f._prefill_past(
-            self.f._params, past_k, past_v,
-            jnp.asarray(past_len, jnp.int32), jnp.asarray(padded[None]),
-            jnp.asarray(suffix.size, jnp.int32))
+        with _obs_spans.region("nns.llm.prefill", "llm", bucket=sb,
+                               tokens=int(suffix.size), past=int(past_len)):
+            return self.f._prefill_past(
+                self.f._params, past_k, past_v,
+                jnp.asarray(past_len, jnp.int32), jnp.asarray(padded[None]),
+                jnp.asarray(suffix.size, jnp.int32))
 
     def admit(self, slot: int, prompt: np.ndarray, budget: int) -> None:
         from .kvpool import chain_hashes
@@ -223,17 +231,18 @@ class _PagedBackend:
                 while nbb < len(cov):
                     nbb *= 2
                 phys_pad = list(cov) + [cov[-1]] * (nbb - len(cov))
-                pk, pv = f._pool_gather(
-                    self.pool, jnp.asarray(np.asarray(phys_pad, np.int32)))
+                with _obs_spans.region("nns.llm.kv_copy", "llm",
+                                       blocks=len(phys_pad)):
+                    pk, pv = f._pool_gather(
+                        self.pool,
+                        jnp.asarray(np.asarray(phys_pad, np.int32)))
                 l1, sk, sv = self._suffix_prefill(pk, pv, p0, prompt[p0:])
                 f.stats.add(prefill_dispatches=1, prefill_cached_tokens=p0,
                             prefill_computed_tokens=plen - p0)
-                self._insert_span(fresh, np.asarray(sk), np.asarray(sv),
-                                  plen - p0)
+                self._insert_span(fresh, sk, sv, plen - p0)
             else:
                 l1, c1 = f._prefill_prompt(prompt, self.max_len)
-                self._insert_span(allb, np.asarray(c1["k"][:, 0]),
-                                  np.asarray(c1["v"][:, 0]), plen)
+                self._insert_span(allb, c1["k"][:, 0], c1["v"][:, 0], plen)
             if f._prefix_cache and hashes:
                 self.mgr.commit(hashes, allb[:len(hashes)])
             self._seat(slot, allb, need, plen, l1)
@@ -416,18 +425,21 @@ class LlmFilter(FilterFramework):
         self._opts = _parse_custom(props.custom_properties)
         cfg = self._cfg
 
-        def step(params, cache, token):
-            return tfm.decode_step(params, cache, token, cfg)
-
-        def pre(params, cache, tokens, true_len):
-            return tfm.prefill(params, cache, tokens, cfg,
-                               true_len=true_len)
-
-        self._decode = jax.jit(step)
-        self._prefill = jax.jit(pre)
-        self._decode_multi = jax.jit(
-            lambda p, c, t, a: tfm.decode_step_multi(p, c, t, a, cfg))
-        self._insert = jax.jit(tfm.cache_insert)
+        # every jitted program carries a stable name into the trace's
+        # XLA Modules line (jit_nns_llm_<what>)
+        named = _obs_spans.named_program
+        self._decode = jax.jit(named(
+            "nns_llm_decode",
+            lambda p, c, t: tfm.decode_step(p, c, t, cfg)))
+        self._prefill = jax.jit(named(
+            "nns_llm_prefill",
+            lambda p, c, toks, tl: tfm.prefill(p, c, toks, cfg,
+                                               true_len=tl)))
+        self._decode_multi = jax.jit(named(
+            "nns_llm_decode_multi",
+            lambda p, c, t, a: tfm.decode_step_multi(p, c, t, a, cfg)))
+        self._insert = jax.jit(named("nns_llm_cache_insert",
+                                     tfm.cache_insert))
         self._tfm = tfm
         self._n_parallel = int(self._opts.get("n_parallel", "1"))
         # custom=chunk:K folds K sample+decode rounds into one scanned
@@ -467,14 +479,18 @@ class LlmFilter(FilterFramework):
             self._pool_mgr = KVBlockPool(n_blocks, self._block_size,
                                          name="llm")
             max_len = self._batch_max_len
-            self._decode_paged = jax.jit(
+            self._decode_paged = jax.jit(named(
+                "nns_llm_decode_paged",
                 lambda p, pool, tbl, idx, t, a: tfm.decode_step_paged(
-                    p, pool, tbl, idx, t, a, cfg, max_len=max_len))
-            self._pool_insert = jax.jit(tfm.pool_insert)
-            self._pool_gather = jax.jit(tfm.pool_gather)
-            self._prefill_past = jax.jit(
+                    p, pool, tbl, idx, t, a, cfg, max_len=max_len)))
+            self._pool_insert = jax.jit(named("nns_llm_pool_insert",
+                                              tfm.pool_insert))
+            self._pool_gather = jax.jit(named("nns_llm_pool_gather",
+                                              tfm.pool_gather))
+            self._prefill_past = jax.jit(named(
+                "nns_llm_prefill_past",
                 lambda p, pk, pv, pl, toks, tl: tfm.prefill_with_past(
-                    p, pk, pv, pl, toks, cfg, true_len=tl))
+                    p, pk, pv, pl, toks, cfg, true_len=tl)))
         with self._cond:
             # prompts queued before a close() belong to the previous
             # session (and carry its ctx buffers) — never replay them
@@ -557,10 +573,13 @@ class LlmFilter(FilterFramework):
         bucket = min(bucket, max_len)
         padded = np.zeros(bucket, np.int32)
         padded[:prompt.size] = prompt
-        cache = self._tfm.init_cache(self._cfg, batch=1, max_len=max_len)
-        logits, cache = self._prefill(
-            self._params, cache, jnp.asarray(padded[None, :]),
-            jnp.asarray(prompt.size, jnp.int32))
+        with _obs_spans.region("nns.llm.prefill", "llm", bucket=bucket,
+                               tokens=int(prompt.size)):
+            cache = self._tfm.init_cache(self._cfg, batch=1,
+                                         max_len=max_len)
+            logits, cache = self._prefill(
+                self._params, cache, jnp.asarray(padded[None, :]),
+                jnp.asarray(prompt.size, jnp.int32))
         self.stats.add(prefill_dispatches=1,
                        prefill_computed_tokens=int(prompt.size))
         return logits, cache
@@ -590,9 +609,11 @@ class LlmFilter(FilterFramework):
         if fn is None:
             import jax
             tfm, cfg = self._tfm, self._cfg
-            fn = jax.jit(lambda p, c, l, k, a: tfm.decode_chunk_multi(
-                p, c, l, k, a, cfg, steps=steps, temperature=temperature,
-                top_k=top_k, top_p=top_p))
+            fn = jax.jit(_obs_spans.named_program(
+                "nns_llm_chunk",
+                lambda p, c, l, k, a: tfm.decode_chunk_multi(
+                    p, c, l, k, a, cfg, steps=steps,
+                    temperature=temperature, top_k=top_k, top_p=top_p)))
             self._chunk_jits[key] = fn
         return fn
 
@@ -606,11 +627,12 @@ class LlmFilter(FilterFramework):
             import jax
             tfm, cfg = self._tfm, self._cfg
             max_len = self._batch_max_len
-            fn = jax.jit(
+            fn = jax.jit(_obs_spans.named_program(
+                "nns_llm_chunk_paged",
                 lambda p, pool, tbl, idx, l, k, a: tfm.decode_chunk_paged(
                     p, pool, tbl, idx, l, k, a, cfg, steps=steps,
                     max_len=max_len, temperature=temperature,
-                    top_k=top_k, top_p=top_p))
+                    top_k=top_k, top_p=top_p)))
             self._chunk_jits[key] = fn
         return fn
 
@@ -773,12 +795,11 @@ class LlmFilter(FilterFramework):
         context (minted here when the invoke carried none) rides the
         wire, so prefill -> handoff -> decode renders as one tree."""
         from ..checkpoint.state import token_sha
-        from ..obs import spans as _spans
         try:
             max_tokens = int(self._opts.get("max_tokens", "16"))
             t0 = time.time_ns()
             tctx = _ctx_of(ctx)
-            if tctx is None and _spans.ENABLED:
+            if tctx is None and _obs_spans.ENABLED:
                 from ..obs import context as _obs_ctx
                 tctx = _obs_ctx.TraceContext(_obs_ctx.next_id(), 0, t0)
             l1, c1 = self._prefill_prompt(flat, self._batch_max_len)
@@ -786,8 +807,8 @@ class LlmFilter(FilterFramework):
             k_np = np.asarray(c1["k"][:, 0, :t])
             v_np = np.asarray(c1["v"][:, 0, :t])
             if tctx is not None:
-                _spans.record_span("llm-prefill", "llm", t0,
-                                   max(0, time.time_ns() - t0), tctx)
+                _obs_spans.record_span("llm-prefill", "llm", t0,
+                                       max(0, time.time_ns() - t0), tctx)
             ack = self._handoff_sender().send(
                 token_sha(flat), flat, k_np, v_np,
                 np.asarray(l1[0], np.float32), remaining=max_tokens,
@@ -916,10 +937,9 @@ class LlmFilter(FilterFramework):
         tctx = s.get("tctx")
         if tctx is None:
             return
-        from ..obs import spans as _spans
         t0 = s.get("t0") or time.time_ns()
-        _spans.record_span("llm-decode", "llm", t0,
-                           max(0, time.time_ns() - t0), tctx)
+        _obs_spans.record_span("llm-decode", "llm", t0,
+                               max(0, time.time_ns() - t0), tctx)
 
     def _sched_body(self) -> None:
         import jax
@@ -945,9 +965,11 @@ class LlmFilter(FilterFramework):
         while not self._stop.is_set():
             # -- admit pending streams into free slots
             with self._cond:
-                while all(s is None for s in streams) and not self._pending \
-                        and not self._stop.is_set():
-                    self._cond.wait(0.1)
+                if all(s is None for s in streams) and not self._pending:
+                    with _obs_spans.region("nns.llm.wait", "llm"):
+                        while not self._pending \
+                                and not self._stop.is_set():
+                            self._cond.wait(0.1)
                 if self._stop.is_set():
                     return
                 admit = []
@@ -960,12 +982,23 @@ class LlmFilter(FilterFramework):
                 kv = entry[3] if len(entry) > 3 else None
                 budget = max_tokens if rem is None else int(rem)
                 t_admit = time.time_ns()
+                tctx = kv.get("ctx") if kv is not None else _ctx_of(ctx)
                 try:
-                    if kv is not None:
-                        backend.admit_handoff(slot, prompt, kv, budget)
-                    else:
-                        self._check_prompt(prompt, max_len)
-                        backend.admit(slot, prompt, budget)
+                    # the ring keeps the local admission as the
+                    # conversation's ``llm-prefill`` node; a handoff's
+                    # context already chains prefill -> kv-handoff, so
+                    # its admission hangs off no frame
+                    with _obs_spans.region(
+                            "nns.llm.admit", "llm",
+                            tctx if kv is None else None,
+                            name="llm-prefill" if kv is None
+                            else "llm-admit",
+                            slot=slot, tokens=int(np.size(prompt))):
+                        if kv is not None:
+                            backend.admit_handoff(slot, prompt, kv, budget)
+                        else:
+                            self._check_prompt(prompt, max_len)
+                            backend.admit(slot, prompt, budget)
                 except _PoolFull:
                     # token-budgeted admission: not enough KV blocks
                     # right now — requeue; running streams release
@@ -976,12 +1009,6 @@ class LlmFilter(FilterFramework):
                     logger.exception("llm: prompt rejected at admission")
                     self._report_async_error(exc)
                     continue
-                tctx = kv.get("ctx") if kv is not None else _ctx_of(ctx)
-                if tctx is not None and kv is None:
-                    from ..obs import spans as _spans
-                    _spans.record_span("llm-prefill", "llm", t_admit,
-                                       max(0, time.time_ns() - t_admit),
-                                       tctx)
                 # per-stream PRNG key: the sample sequence matches the
                 # n_parallel=1 path for the same seed, independent of
                 # which other prompts happen to be in flight. rem
@@ -1036,32 +1063,35 @@ class LlmFilter(FilterFramework):
             else:
                 tok = jnp.argmax(backend.logits, -1)
             tok = tok.astype(jnp.int32)
-            tok_host = jax.device_get(tok)  # ONE fetch for all slots
-            for slot, s in enumerate(streams):
-                if s is None:
-                    continue
-                self._dispatch([tok_host[slot:slot + 1]], s["ctx"])
-                with self._cond:
-                    # bookkeeping under _cond: a preemption snapshot
-                    # reads (prompt, emitted, remaining) coherently
-                    s["emitted"].append(int(tok_host[slot]))
-                    s["remaining"] -= 1
-                    s["pos"] += 1
-                # pos is one past the next decode's cache-write position
-                # (the write lands at pos-1), so the stream survives
-                # while pos <= max_len — matching the single-stream
-                # loop's emit-then-check ordering exactly
-                if s["remaining"] <= 0 or s["pos"] > max_len:
-                    streams[slot] = None
-                    # keep the mask current: a lane that just finished
-                    # must not keep writing/advancing its cache in the
-                    # trailing decode (the decode step also position-
-                    # guards at max_len)
-                    active_np[slot] = False
-                    backend.free(slot)
-                    self._finish_span(s)
+            with _obs_spans.region("nns.llm.fetch", "llm"):
+                tok_host = jax.device_get(tok)  # ONE fetch for all slots
+            with _obs_spans.region("nns.llm.emit", "llm"):
+                for slot, s in enumerate(streams):
+                    if s is None:
+                        continue
+                    self._dispatch([tok_host[slot:slot + 1]], s["ctx"])
+                    with self._cond:
+                        # bookkeeping under _cond: a preemption snapshot
+                        # reads (prompt, emitted, remaining) coherently
+                        s["emitted"].append(int(tok_host[slot]))
+                        s["remaining"] -= 1
+                        s["pos"] += 1
+                    # pos is one past the next decode's cache-write
+                    # position (the write lands at pos-1), so the stream
+                    # survives while pos <= max_len — matching the
+                    # single-stream loop's emit-then-check ordering
+                    if s["remaining"] <= 0 or s["pos"] > max_len:
+                        streams[slot] = None
+                        # keep the mask current: a lane that just
+                        # finished must not keep writing/advancing its
+                        # cache in the trailing decode (the decode step
+                        # also position-guards at max_len)
+                        active_np[slot] = False
+                        backend.free(slot)
+                        self._finish_span(s)
             if active_np.any():
-                backend.step(tok, active_np)
+                with _obs_spans.region("nns.llm.chunk", "llm", steps=1):
+                    backend.step(tok, active_np)
                 self.stats.add(decode_dispatches=1, decode_steps=1)
 
     def _sched_chunk(self, streams, active_np, backend, max_len,
@@ -1096,24 +1126,27 @@ class LlmFilter(FilterFramework):
                               for s in streams])
         else:
             keys = jnp.zeros((len(streams), 2), jnp.uint32)
-        toks, keys = backend.chunk(k, temperature, keys, active_np)
+        with _obs_spans.region("nns.llm.chunk", "llm", steps=k):
+            toks, keys = backend.chunk(k, temperature, keys, active_np)
         self.stats.add(decode_dispatches=1, decode_steps=k)
-        toks_host = np.asarray(toks)  # [k, M]: ONE fetch for the chunk
-        for slot, s in enumerate(streams):
-            if s is None:
-                continue
-            for j in range(min(k, emits_left[slot])):
-                self._dispatch([toks_host[j, slot:slot + 1]], s["ctx"])
-                with self._cond:
-                    s["emitted"].append(int(toks_host[j, slot]))
-                    s["remaining"] -= 1
-                    s["pos"] += 1
-            if temperature > 0:
-                s["key"] = keys[slot]
-            if s["remaining"] <= 0 or s["pos"] > max_len:
-                streams[slot] = None
-                backend.free(slot)
-                self._finish_span(s)
+        with _obs_spans.region("nns.llm.fetch", "llm"):
+            toks_host = np.asarray(toks)  # [k, M]: ONE fetch for the chunk
+        with _obs_spans.region("nns.llm.emit", "llm"):
+            for slot, s in enumerate(streams):
+                if s is None:
+                    continue
+                for j in range(min(k, emits_left[slot])):
+                    self._dispatch([toks_host[j, slot:slot + 1]], s["ctx"])
+                    with self._cond:
+                        s["emitted"].append(int(toks_host[j, slot]))
+                        s["remaining"] -= 1
+                        s["pos"] += 1
+                if temperature > 0:
+                    s["key"] = keys[slot]
+                if s["remaining"] <= 0 or s["pos"] > max_len:
+                    streams[slot] = None
+                    backend.free(slot)
+                    self._finish_span(s)
 
 
 register_alias("llamacpp", "llm")

@@ -25,10 +25,13 @@ convention. Stats live in the locked :class:`utils.atomic.Counters`
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Callable, List, Optional
 
+from ..obs import context as _obs_ctx
+from ..obs import spans as _obs_spans
 from ..pipeline.element import TransformElement
 from ..pipeline.events import CapsEvent, QosEvent
 from ..pipeline.pad import Pad
@@ -263,7 +266,9 @@ class FusedSegment(TransformElement):
             # jit tracing/compilation errors surface here on the chain
             # thread in BOTH modes; with a window the device execution
             # itself is still in flight when this returns
-            outs = exe(arrays)
+            with (self._overlap.dispatching(buf)
+                  if self._overlap is not None else contextlib.nullcontext()):
+                outs = exe(arrays)
         except Exception:
             # device program failed (trace or dispatch): count it on
             # the breaker, then let Element.chain apply the segment's
@@ -279,7 +284,8 @@ class FusedSegment(TransformElement):
         if tracer is not None:
             tracer.observe(f"fusion/{self.name}", dt)
         if self._overlap is not None:
-            t_disp = self._overlap.window.acquire()
+            t_disp = self._overlap.window.acquire(
+                ctx=_obs_ctx.ctx_of(buf), element=self.name)
             try:
                 self._overlap.submit(buf, outs, t_disp)
             except BaseException:
@@ -370,7 +376,8 @@ class FusedSegment(TransformElement):
         # one jax.jit object per caps signature: jit would retrace a
         # shared object silently, which would skew the hit/miss stats
         # the trace report promises
-        return jax.jit(program)
+        return jax.jit(_obs_spans.named_program(
+            "nns_fused_" + self.name, program))
 
     def _shed_frame(self, buf: Buffer) -> None:
         self.stats.inc("shed")

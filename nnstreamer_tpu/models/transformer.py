@@ -159,20 +159,22 @@ def block(h, layer, positions, cfg: GPTConfig, return_kv: bool = False):
     computation (no duplicated block body)."""
     b, s, d = h.shape
     hd, nh = cfg.head_dim, cfg.n_heads
-    x = rmsnorm(h, layer["ln1"])
-    q = (x @ layer["wq"]).reshape(b, s, nh, hd)
-    k = (x @ layer["wk"]).reshape(b, s, nh, hd)
-    v = (x @ layer["wv"]).reshape(b, s, nh, hd)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
-    attn = _attention(q, k, v, positions, cfg)
-    h = h + attn.reshape(b, s, d) @ layer["wo"]
-    h = _constrain(h, cfg, (cfg.data_axis, cfg.seq_axis, None))
-    x = rmsnorm(h, layer["ln2"])
-    ff = jax.nn.silu(x @ layer["w1"]) * (x @ layer["w3"])
-    ff = _constrain(ff, cfg, (cfg.data_axis, cfg.seq_axis, cfg.model_axis))
-    h = h + ff @ layer["w2"]
-    h = _constrain(h, cfg, (cfg.data_axis, cfg.seq_axis, None))
+    with jax.named_scope("block/attn"):
+        x = rmsnorm(h, layer["ln1"])
+        q = (x @ layer["wq"]).reshape(b, s, nh, hd)
+        k = (x @ layer["wk"]).reshape(b, s, nh, hd)
+        v = (x @ layer["wv"]).reshape(b, s, nh, hd)
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+        attn = _attention(q, k, v, positions, cfg)
+        h = h + attn.reshape(b, s, d) @ layer["wo"]
+        h = _constrain(h, cfg, (cfg.data_axis, cfg.seq_axis, None))
+    with jax.named_scope("block/mlp"):
+        x = rmsnorm(h, layer["ln2"])
+        ff = jax.nn.silu(x @ layer["w1"]) * (x @ layer["w3"])
+        ff = _constrain(ff, cfg, (cfg.data_axis, cfg.seq_axis, cfg.model_axis))
+        h = h + ff @ layer["w2"]
+        h = _constrain(h, cfg, (cfg.data_axis, cfg.seq_axis, None))
     return (h, k, v) if return_kv else h
 
 
@@ -180,12 +182,14 @@ def forward(params, tokens, cfg: GPTConfig):
     """tokens [B,S] int32 -> logits [B,S,V] float32."""
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-    h = jnp.take(params["embed"], tokens, axis=0)
-    h = _constrain(h, cfg, (cfg.data_axis, cfg.seq_axis, None))
+    with jax.named_scope("embed"):
+        h = jnp.take(params["embed"], tokens, axis=0)
+        h = _constrain(h, cfg, (cfg.data_axis, cfg.seq_axis, None))
     for layer in params["layers"]:
         h = block(h, layer, positions, cfg)
-    h = rmsnorm(h, params["ln_f"])
-    logits = (h @ params["head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        h = rmsnorm(h, params["ln_f"])
+        logits = (h @ params["head"]).astype(jnp.float32)
     return _constrain(logits, cfg, (cfg.data_axis, cfg.seq_axis, cfg.model_axis))
 
 
@@ -228,8 +232,9 @@ def prefill(params, cache, tokens, cfg: GPTConfig, true_len=None):
     """
     b, t = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
-    h = jnp.take(params["embed"], tokens, axis=0)
-    h = _constrain(h, cfg, (cfg.data_axis, cfg.seq_axis, None))
+    with jax.named_scope("embed"):
+        h = jnp.take(params["embed"], tokens, axis=0)
+        h = _constrain(h, cfg, (cfg.data_axis, cfg.seq_axis, None))
     new_k, new_v = [], []
     for i, layer in enumerate(params["layers"]):
         h, k, v = block(h, layer, positions, cfg, return_kv=True)
@@ -237,12 +242,13 @@ def prefill(params, cache, tokens, cfg: GPTConfig, true_len=None):
             cache["k"][i], k.astype(cache["k"].dtype), (0, 0, 0, 0)))
         new_v.append(jax.lax.dynamic_update_slice(
             cache["v"][i], v.astype(cache["v"].dtype), (0, 0, 0, 0)))
-    h = rmsnorm(h, params["ln_f"])
-    t_eff = jnp.asarray(t if true_len is None else true_len, jnp.int32)
-    # dynamic index on the seq axis; clamps (never wraps) when out of
-    # range, so a zero-length prompt cannot read the padded tail
-    h_last = jax.lax.dynamic_slice_in_dim(h, t_eff - 1, 1, axis=1)[:, 0]
-    logits = (h_last @ params["head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        h = rmsnorm(h, params["ln_f"])
+        t_eff = jnp.asarray(t if true_len is None else true_len, jnp.int32)
+        # dynamic index on the seq axis; clamps (never wraps) when out of
+        # range, so a zero-length prompt cannot read the padded tail
+        h_last = jax.lax.dynamic_slice_in_dim(h, t_eff - 1, 1, axis=1)[:, 0]
+        logits = (h_last @ params["head"]).astype(jnp.float32)
     cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v),
              "index": t_eff}
     return logits, cache
@@ -301,7 +307,8 @@ def decode_step_multi(params, cache, token, active, cfg: GPTConfig):
     b = token.shape[0]
     pos = cache["index"]                       # [B]
     positions = pos[:, None]                   # [B,1]
-    h = jnp.take(params["embed"], token[:, None], axis=0)
+    with jax.named_scope("embed"):
+        h = jnp.take(params["embed"], token[:, None], axis=0)
     max_len = cache["k"].shape[2]
     valid = jnp.arange(max_len)[None, :] <= pos[:, None]   # [B,L]
     ok = active & (pos < max_len)              # may write + advance
@@ -318,31 +325,34 @@ def decode_step_multi(params, cache, token, active, cfg: GPTConfig):
     new_k, new_v = [], []
     for i, layer in enumerate(params["layers"]):
         hd, nh = cfg.head_dim, cfg.n_heads
-        x = rmsnorm(h, layer["ln1"])
-        q = rope((x @ layer["wq"]).reshape(b, 1, nh, hd), positions,
-                 cfg.rope_theta)
-        k1 = rope((x @ layer["wk"]).reshape(b, 1, nh, hd), positions,
-                  cfg.rope_theta)
-        v1 = (x @ layer["wv"]).reshape(b, 1, nh, hd)
-        k = upd(cache["k"][i],
-                jnp.where(lane, k1.astype(cache["k"].dtype),
-                          row(cache["k"][i], pos)), pos)
-        v = upd(cache["v"][i],
-                jnp.where(lane, v1.astype(cache["v"].dtype),
-                          row(cache["v"][i], pos)), pos)
-        new_k.append(k)
-        new_v.append(v)
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
-        scores = scores * (hd ** -0.5)
-        scores = jnp.where(valid[:, None, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-        h = h + attn.reshape(b, 1, -1) @ layer["wo"]
-        x = rmsnorm(h, layer["ln2"])
-        ff = jax.nn.silu(x @ layer["w1"]) * (x @ layer["w3"])
-        h = h + ff @ layer["w2"]
-    h = rmsnorm(h, params["ln_f"])
-    logits = (h[:, 0] @ params["head"]).astype(jnp.float32)
+        with jax.named_scope("block/attn"):
+            x = rmsnorm(h, layer["ln1"])
+            q = rope((x @ layer["wq"]).reshape(b, 1, nh, hd), positions,
+                     cfg.rope_theta)
+            k1 = rope((x @ layer["wk"]).reshape(b, 1, nh, hd), positions,
+                      cfg.rope_theta)
+            v1 = (x @ layer["wv"]).reshape(b, 1, nh, hd)
+            k = upd(cache["k"][i],
+                    jnp.where(lane, k1.astype(cache["k"].dtype),
+                              row(cache["k"][i], pos)), pos)
+            v = upd(cache["v"][i],
+                    jnp.where(lane, v1.astype(cache["v"].dtype),
+                              row(cache["v"][i], pos)), pos)
+            new_k.append(k)
+            new_v.append(v)
+            scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+            scores = scores * (hd ** -0.5)
+            scores = jnp.where(valid[:, None, None, :], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+            attn = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+            h = h + attn.reshape(b, 1, -1) @ layer["wo"]
+        with jax.named_scope("block/mlp"):
+            x = rmsnorm(h, layer["ln2"])
+            ff = jax.nn.silu(x @ layer["w1"]) * (x @ layer["w3"])
+            h = h + ff @ layer["w2"]
+    with jax.named_scope("lm_head"):
+        h = rmsnorm(h, params["ln_f"])
+        logits = (h[:, 0] @ params["head"]).astype(jnp.float32)
     cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v),
              "index": pos + ok.astype(jnp.int32)}
     return logits, cache
@@ -498,7 +508,8 @@ def decode_step_paged(params, pool, table, index, token, active,
     hd, nh = cfg.head_dim, cfg.n_heads
     pos = index                                # [B]
     positions = pos[:, None]
-    h = jnp.take(params["embed"], token[:, None], axis=0)
+    with jax.named_scope("embed"):
+        h = jnp.take(params["embed"], token[:, None], axis=0)
     valid = jnp.arange(max_len)[None, :] <= pos[:, None]
     ok = active & (pos < max_len)
     lane = ok[:, None, None, None]
@@ -513,31 +524,34 @@ def decode_step_paged(params, pool, table, index, token, active,
     off = pos % bs_blk
     k_rows, v_rows = [], []
     for i, layer in enumerate(params["layers"]):
-        kc = pool["k"][i][table].reshape(b, -1, nh, hd)[:, :max_len]
-        vc = pool["v"][i][table].reshape(b, -1, nh, hd)[:, :max_len]
-        x = rmsnorm(h, layer["ln1"])
-        q = rope((x @ layer["wq"]).reshape(b, 1, nh, hd), positions,
-                 cfg.rope_theta)
-        k1 = rope((x @ layer["wk"]).reshape(b, 1, nh, hd), positions,
-                  cfg.rope_theta)
-        v1 = (x @ layer["wv"]).reshape(b, 1, nh, hd)
-        kd = jnp.where(lane, k1.astype(kc.dtype), row(kc, pos))
-        vd = jnp.where(lane, v1.astype(vc.dtype), row(vc, pos))
-        k = upd(kc, kd, pos)
-        v = upd(vc, vd, pos)
-        k_rows.append(kd[:, 0])
-        v_rows.append(vd[:, 0])
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
-        scores = scores * (hd ** -0.5)
-        scores = jnp.where(valid[:, None, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-        h = h + attn.reshape(b, 1, -1) @ layer["wo"]
-        x = rmsnorm(h, layer["ln2"])
-        ff = jax.nn.silu(x @ layer["w1"]) * (x @ layer["w3"])
-        h = h + ff @ layer["w2"]
-    h = rmsnorm(h, params["ln_f"])
-    logits = (h[:, 0] @ params["head"]).astype(jnp.float32)
+        with jax.named_scope("block/attn"):
+            kc = pool["k"][i][table].reshape(b, -1, nh, hd)[:, :max_len]
+            vc = pool["v"][i][table].reshape(b, -1, nh, hd)[:, :max_len]
+            x = rmsnorm(h, layer["ln1"])
+            q = rope((x @ layer["wq"]).reshape(b, 1, nh, hd), positions,
+                     cfg.rope_theta)
+            k1 = rope((x @ layer["wk"]).reshape(b, 1, nh, hd), positions,
+                      cfg.rope_theta)
+            v1 = (x @ layer["wv"]).reshape(b, 1, nh, hd)
+            kd = jnp.where(lane, k1.astype(kc.dtype), row(kc, pos))
+            vd = jnp.where(lane, v1.astype(vc.dtype), row(vc, pos))
+            k = upd(kc, kd, pos)
+            v = upd(vc, vd, pos)
+            k_rows.append(kd[:, 0])
+            v_rows.append(vd[:, 0])
+            scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+            scores = scores * (hd ** -0.5)
+            scores = jnp.where(valid[:, None, None, :], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+            attn = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+            h = h + attn.reshape(b, 1, -1) @ layer["wo"]
+        with jax.named_scope("block/mlp"):
+            x = rmsnorm(h, layer["ln2"])
+            ff = jax.nn.silu(x @ layer["w1"]) * (x @ layer["w3"])
+            h = h + ff @ layer["w2"]
+    with jax.named_scope("lm_head"):
+        h = rmsnorm(h, params["ln_f"])
+        logits = (h[:, 0] @ params["head"]).astype(jnp.float32)
     pool = {"k": pool["k"].at[:, tgt, off].set(jnp.stack(k_rows),
                                                mode="drop"),
             "v": pool["v"].at[:, tgt, off].set(jnp.stack(v_rows),
@@ -603,40 +617,44 @@ def prefill_with_past(params, past_k, past_v, past_len, tokens,
     # padded past rows sit at absolute positions < pos_q, so the causal
     # mask alone would admit them — the column-validity mask is load-bearing
     col_ok = jnp.concatenate([past_cols < p0, jnp.ones((s,), bool)])
-    h = jnp.take(params["embed"], tokens, axis=0)
+    with jax.named_scope("embed"):
+        h = jnp.take(params["embed"], tokens, axis=0)
     new_k, new_v = [], []
     for i, layer in enumerate(params["layers"]):
-        x = rmsnorm(h, layer["ln1"])
-        q = rope((x @ layer["wq"]).reshape(b, s, nh, hd), pos_q,
-                 cfg.rope_theta)
-        k = rope((x @ layer["wk"]).reshape(b, s, nh, hd), pos_q,
-                 cfg.rope_theta)
-        v = (x @ layer["wv"]).reshape(b, s, nh, hd)
-        fk = jnp.concatenate(
-            [jnp.broadcast_to(past_k[i][None].astype(k.dtype),
-                              (b, p, nh, hd)), k], axis=1)
-        fv = jnp.concatenate(
-            [jnp.broadcast_to(past_v[i][None].astype(v.dtype),
-                              (b, p, nh, hd)), v], axis=1)
-        pos_k = jnp.concatenate(
-            [jnp.broadcast_to(past_cols, (b, p)), pos_q], axis=1)
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, fk).astype(jnp.float32)
-        scores = scores * (hd ** -0.5)
-        mask = (pos_q[:, None, :, None] >= pos_k[:, None, None, :]) \
-            & col_ok[None, None, None, :]
-        scores = jnp.where(mask, scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", probs, fv)
-        h = h + attn.reshape(b, s, -1) @ layer["wo"]
-        x = rmsnorm(h, layer["ln2"])
-        ff = jax.nn.silu(x @ layer["w1"]) * (x @ layer["w3"])
-        h = h + ff @ layer["w2"]
+        with jax.named_scope("block/attn"):
+            x = rmsnorm(h, layer["ln1"])
+            q = rope((x @ layer["wq"]).reshape(b, s, nh, hd), pos_q,
+                     cfg.rope_theta)
+            k = rope((x @ layer["wk"]).reshape(b, s, nh, hd), pos_q,
+                     cfg.rope_theta)
+            v = (x @ layer["wv"]).reshape(b, s, nh, hd)
+            fk = jnp.concatenate(
+                [jnp.broadcast_to(past_k[i][None].astype(k.dtype),
+                                  (b, p, nh, hd)), k], axis=1)
+            fv = jnp.concatenate(
+                [jnp.broadcast_to(past_v[i][None].astype(v.dtype),
+                                  (b, p, nh, hd)), v], axis=1)
+            pos_k = jnp.concatenate(
+                [jnp.broadcast_to(past_cols, (b, p)), pos_q], axis=1)
+            scores = jnp.einsum("bqhd,bkhd->bhqk", q, fk).astype(jnp.float32)
+            scores = scores * (hd ** -0.5)
+            mask = (pos_q[:, None, :, None] >= pos_k[:, None, None, :]) \
+                & col_ok[None, None, None, :]
+            scores = jnp.where(mask, scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+            attn = jnp.einsum("bhqk,bkhd->bqhd", probs, fv)
+            h = h + attn.reshape(b, s, -1) @ layer["wo"]
+        with jax.named_scope("block/mlp"):
+            x = rmsnorm(h, layer["ln2"])
+            ff = jax.nn.silu(x @ layer["w1"]) * (x @ layer["w3"])
+            h = h + ff @ layer["w2"]
         new_k.append(k)
         new_v.append(v)
-    h = rmsnorm(h, params["ln_f"])
-    t_eff = jnp.asarray(s if true_len is None else true_len, jnp.int32)
-    h_last = jax.lax.dynamic_slice_in_dim(h, t_eff - 1, 1, axis=1)[:, 0]
-    logits = (h_last @ params["head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        h = rmsnorm(h, params["ln_f"])
+        t_eff = jnp.asarray(s if true_len is None else true_len, jnp.int32)
+        h_last = jax.lax.dynamic_slice_in_dim(h, t_eff - 1, 1, axis=1)[:, 0]
+        logits = (h_last @ params["head"]).astype(jnp.float32)
     # single-stream path (b == 1): drop the batch dim so the suffix KV
     # has the same [L, S, H, Dh] layout as shipped / gathered KV
     return logits, jnp.stack(new_k)[:, 0], jnp.stack(new_v)[:, 0]

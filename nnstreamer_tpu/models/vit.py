@@ -38,19 +38,23 @@ class EncoderBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        h = nn.LayerNorm(dtype=self.dtype)(x)
-        attn_kwargs = {}
-        if self.fused:
-            from ..ops.attention import fused_attention
-            attn_kwargs["attention_fn"] = fused_attention
-        h = nn.MultiHeadDotProductAttention(
-            num_heads=self.heads, dtype=self.dtype, **attn_kwargs)(h, h)
-        x = x + h
-        h = nn.LayerNorm(dtype=self.dtype)(x)
-        h = nn.Dense(self.d_model * self.mlp_ratio, dtype=self.dtype)(h)
-        h = nn.gelu(h)
-        h = nn.Dense(self.d_model, dtype=self.dtype)(h)
-        return x + h
+        # the scopes name each device op's half of the block in a
+        # profiler trace (they touch neither params nor the program)
+        with jax.named_scope("block/attn"):
+            h = nn.LayerNorm(dtype=self.dtype)(x)
+            attn_kwargs = {}
+            if self.fused:
+                from ..ops.attention import fused_attention
+                attn_kwargs["attention_fn"] = fused_attention
+            h = nn.MultiHeadDotProductAttention(
+                num_heads=self.heads, dtype=self.dtype, **attn_kwargs)(h, h)
+            x = x + h
+        with jax.named_scope("block/mlp"):
+            h = nn.LayerNorm(dtype=self.dtype)(x)
+            h = nn.Dense(self.d_model * self.mlp_ratio, dtype=self.dtype)(h)
+            h = nn.gelu(h)
+            h = nn.Dense(self.d_model, dtype=self.dtype)(h)
+            return x + h
 
 
 class ViT(nn.Module):
@@ -66,21 +70,23 @@ class ViT(nn.Module):
     def __call__(self, x):
         # patch embedding: one conv with stride=kernel=patch (a dense
         # [p*p*3, d] matmul per patch on the MXU)
-        x = nn.Conv(self.d_model, (self.patch, self.patch),
-                    strides=(self.patch, self.patch), padding="VALID",
-                    dtype=self.dtype)(x)
-        b, hp, wp, d = x.shape
-        x = x.reshape(b, hp * wp, d)
-        pos = self.param("pos_embed", nn.initializers.normal(0.02),
-                         (1, hp * wp, d), jnp.float32)
-        x = x + pos.astype(self.dtype)
+        with jax.named_scope("patch_embed"):
+            x = nn.Conv(self.d_model, (self.patch, self.patch),
+                        strides=(self.patch, self.patch), padding="VALID",
+                        dtype=self.dtype)(x)
+            b, hp, wp, d = x.shape
+            x = x.reshape(b, hp * wp, d)
+            pos = self.param("pos_embed", nn.initializers.normal(0.02),
+                             (1, hp * wp, d), jnp.float32)
+            x = x + pos.astype(self.dtype)
         for _ in range(self.layers):
             x = EncoderBlock(self.d_model, self.heads,
                              dtype=self.dtype, fused=self.fused)(x)
-        x = nn.LayerNorm(dtype=self.dtype)(x)
-        x = x.mean(axis=1)  # mean-pool (no cls token: shape-stable)
-        return nn.Dense(self.classes, dtype=jnp.float32)(
-            x.astype(jnp.float32))
+        with jax.named_scope("head"):
+            x = nn.LayerNorm(dtype=self.dtype)(x)
+            x = x.mean(axis=1)  # mean-pool (no cls token: shape-stable)
+            return nn.Dense(self.classes, dtype=jnp.float32)(
+                x.astype(jnp.float32))
 
 
 @register_model("vit")

@@ -14,6 +14,8 @@ One scrape renders, in the standard ``name{labels} value`` text format:
 * when a pipeline has a tracer attached, the full ``trace.report()``
   flattened leaf-by-leaf — every Counters/Reservoir the tracer already
   aggregates becomes a scrapeable series;
+* each local device's memory in use, peak and limit, where the backend
+  reports them;
 * flight-recorder structured-event counts by kind.
 
 Pipelines register at ``start()`` and unregister at ``stop()``
@@ -158,6 +160,32 @@ def _flatten(prefix: str, obj, out: List[Tuple[str, float]]) -> None:
         n = _num(obj)
         if n is not None:
             out.append((prefix, n))
+
+
+# memory_stats() key -> the ``kind`` label
+_MEMORY_KINDS = (("bytes_in_use", "in_use"), ("peak_bytes_in_use", "peak"),
+                 ("bytes_limit", "limit"))
+
+
+def _device_memory_lines() -> List[str]:
+    """``nns_device_memory_bytes{device,kind}`` per local device."""
+    import jax
+    try:
+        devices = jax.local_devices()
+    except RuntimeError:   # no backend could start: a scrape still answers
+        return []
+    out: List[str] = []
+    for d in devices:
+        stats = d.memory_stats() or {}
+        for key, kind in _MEMORY_KINDS:
+            if key in stats:
+                out.append(
+                    f"nns_device_memory_bytes"
+                    f"{_labels(device=f'{d.platform}:{d.id}', kind=kind)}"
+                    f" {int(stats[key])}")
+    if out:
+        out.insert(0, "# TYPE nns_device_memory_bytes gauge")
+    return out
 
 
 def render() -> str:
@@ -334,6 +362,10 @@ def render() -> str:
             lines.append(
                 f"nns_fleet_lifecycle_total"
                 f"{_labels(autoscaler=auto.name, counter=k)} {n}")
+
+    # 3e) device memory, where the backend reports it (TPU, GPU; the
+    # CPU backend's memory_stats() is None, so the family is absent)
+    lines.extend(_device_memory_lines())
 
     # 4) attached tracers: the full report, flattened — every
     # Counters/Reservoir trace.py aggregates becomes a series

@@ -13,11 +13,26 @@ A span is the tuple::
 with wall-clock (epoch) timestamps so spans recorded in different
 processes align in one Chrome trace. ``NNS_TPU_OBS=0`` turns the whole
 layer off (the obs-overhead gate's control arm).
+
+The bridge to ``jax.profiler``: a profiler trace counts from its
+session's start on the device's clock, so the rings' epoch stamps
+cannot be laid beside it — the spans have to be IN the trace.
+:func:`region` (work a thread does) is a ``TraceAnnotation`` for its
+whole extent; :func:`record_span` (a wait measured after the fact)
+with ``prof=`` drops an end-stamped marker annotation carrying
+``dur_ns``, from which a reader rebuilds ``[end - dur, end]``. Every
+annotation carries ``trace`` / ``span`` / ``parent`` (and whatever
+else the site names, e.g. ``element``) as metadata, so the spans of
+one frame share an identifier in the xplane as they do in the ring.
+Profiler names are ``nns.<layer>.<what>``. Outside a profiler session
+an annotation is a flag test in C++; ``chain_span`` is not bridged.
 """
 from __future__ import annotations
 
 import os
+import re
 import threading
+import time
 from collections import deque
 from typing import List, Optional, Tuple
 
@@ -91,12 +106,27 @@ def clear() -> None:
 
 # -- recording ----------------------------------------------------------
 
+_annotation = None     # jax.profiler.TraceAnnotation, bound on first use
+
+
+def _bind_annotation():
+    """Bound lazily (as ``_observe_e2e`` is): ``obs/`` imports without
+    jax, and the profiler module loads only once a span is bridged."""
+    global _annotation
+    from jax.profiler import TraceAnnotation
+    _annotation = TraceAnnotation
+    return TraceAnnotation
+
+
 def record_span(name: str, cat: str, ts_ns: int, dur_ns: int,
                 ctx: Optional[TraceContext] = None,
-                parent: Optional[int] = None) -> int:
+                parent: Optional[int] = None,
+                prof: Optional[str] = None, **meta) -> int:
     """Record one span; with a context the span parents onto the
     context's current span and becomes the new current (the linear
-    causality chain). Returns the span id (0 when recording is off)."""
+    causality chain). ``prof`` names the marker annotation that carries
+    the span into a running profiler trace (module docstring). Returns
+    the span id (0 when recording is off)."""
     if not ENABLED:
         return 0
     sid = _BASE | (next(_IDS) & 0xFFFFFF)   # next_id(), inlined (hot)
@@ -106,12 +136,90 @@ def record_span(name: str, cat: str, ts_ns: int, dur_ns: int,
         ring = _new_ring()
     if ctx is not None:
         p = ctx.span_id if parent is None else parent
-        ring.append((name, cat, ts_ns, dur_ns, ctx.trace_id, sid, p))
+        trace_id = ctx.trace_id
         ctx.span_id = sid
     else:
-        ring.append((name, cat, ts_ns, dur_ns, 0, sid,
-                     0 if parent is None else parent))
+        p = 0 if parent is None else parent
+        trace_id = 0
+    ring.append((name, cat, ts_ns, dur_ns, trace_id, sid, p))
+    if prof is not None:
+        with (_annotation or _bind_annotation())(
+                prof, dur_ns=dur_ns, trace=trace_id, span=sid, parent=p,
+                **meta):
+            pass
     return sid
+
+
+class _Region:
+    """One open :func:`region`: ring tuple on exit, profiler annotation
+    for the whole extent."""
+
+    __slots__ = ("name", "cat", "ctx", "ann", "sid", "trace_id", "parent",
+                 "t0", "dur_ns")
+
+    def __init__(self, name, cat, ctx, prof, meta):
+        self.name, self.cat, self.ctx = name, cat, ctx
+        self.sid = _BASE | (next(_IDS) & 0xFFFFFF)
+        try:
+            stack = _tls.open
+        except AttributeError:
+            stack = _tls.open = []
+        if ctx is not None:
+            self.trace_id, self.parent = ctx.trace_id, ctx.span_id
+        elif stack:
+            self.trace_id, self.parent = stack[-1]
+        else:
+            self.trace_id = self.parent = 0
+        self.ann = (_annotation or _bind_annotation())(
+            prof, trace=self.trace_id, span=self.sid, parent=self.parent,
+            **meta)
+
+    def __enter__(self):
+        _tls.open.append((self.trace_id, self.sid))
+        self.t0 = time.time_ns()
+        self.ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self.ann.__exit__(*exc)
+        dur = self.dur_ns = time.time_ns() - self.t0
+        _tls.open.pop()
+        try:
+            ring = _tls.ring
+        except AttributeError:
+            ring = _new_ring()
+        ring.append((self.name, self.cat, self.t0, dur, self.trace_id,
+                     self.sid, self.parent))
+        if self.ctx is not None:
+            self.ctx.span_id = self.sid
+        return False
+
+
+class _Off:
+    """``region()`` with recording off: nothing built, nothing kept."""
+
+    dur_ns = 0       # what callers add to a context's accumulators
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_OFF = _Off()
+
+
+def region(prof: str, cat: str, ctx: Optional[TraceContext] = None,
+           name: Optional[str] = None, **meta):
+    """Context manager around work a thread DOES (dispatch, a transfer
+    call, an admission). On exit the ring gets the span under ``name``
+    (default: ``prof``), parented on ``ctx`` when given (and advancing
+    it), else on the thread's open region; for its whole extent it is
+    also the profiler annotation ``prof`` with ``meta`` as metadata."""
+    if not ENABLED:
+        return _OFF
+    return _Region(name or prof, cat, ctx, prof, meta)
 
 
 def record_root(name: str, ctx: TraceContext) -> int:
@@ -123,6 +231,19 @@ def record_root(name: str, ctx: TraceContext) -> int:
     _ring().append((name, "source", ctx.t0_ns, 0, ctx.trace_id, sid, 0))
     ctx.span_id = sid
     return sid
+
+
+def named_program(name: str, fn):
+    """``fn`` under the stable name its jitted program carries in a
+    profiler trace: ``jax.jit`` names the module ``jit_<__name__>``, so
+    the ``XLA Modules`` line reads ``jit_nns_<layer>_<what>`` whatever
+    closure or lambda built it. ``name`` is made an identifier (an
+    element or model name may hold ``-`` or ``.``). ``fn`` itself is
+    left alone: module-level functions are shared."""
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+    program.__name__ = program.__qualname__ = re.sub(r"\W", "_", name)
+    return program
 
 
 _observe_e2e = None    # metrics.observe_e2e, bound on first sink frame

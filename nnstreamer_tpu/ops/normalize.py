@@ -47,15 +47,18 @@ def _normalize_pallas(x2d, scale: float, offset: float, dtype,
     rows = x2d.shape[0]
     tile = min(_TILE_ROWS, rows)
     grid = (rows + tile - 1) // tile
-    return pl.pallas_call(
-        functools.partial(_kernel, scale, offset, dtype),
-        grid=(grid,),
-        in_specs=[pl.BlockSpec((tile, x2d.shape[1]),
-                               lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((tile, x2d.shape[1]), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(x2d.shape, dtype),
-        interpret=interpret,
-    )(x2d)
+    # scope and kernel name: what a profiler trace calls this kernel
+    with jax.named_scope("fused_normalize"):
+        return pl.pallas_call(
+            functools.partial(_kernel, scale, offset, dtype),
+            grid=(grid,),
+            in_specs=[pl.BlockSpec((tile, x2d.shape[1]),
+                                   lambda i: (i, 0))],
+            out_specs=pl.BlockSpec((tile, x2d.shape[1]), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct(x2d.shape, dtype),
+            interpret=interpret,
+            name="nns_fused_normalize",
+        )(x2d)
 
 
 def fused_normalize(x, scale: float = 1.0 / 127.5, offset: float = 127.5,

@@ -56,6 +56,22 @@ class _NativeQueueAdapter:
         return len(self._ring)
 
 
+def _record_queue_wait(name: str, item) -> None:
+    """A buffer left a queue (``queue``'s worker pop, ``appsrc``'s src
+    loop pop): turn its entry stamp into the queue-wait span and the
+    context's queue attribution."""
+    if not _obs_spans.ENABLED:
+        return
+    qt = item.extras.pop(_obs_ctx.QT_KEY, None)
+    ctx = item.extras.get(_obs_ctx.CTX_KEY)
+    if qt is None or ctx is None:
+        return
+    wait = max(0, time.time_ns() - qt)
+    _obs_spans.record_span(name, "queue", qt, wait, ctx,
+                           prof="nns.queue.wait", element=name)
+    ctx.q_ns += wait
+
+
 @register_element("queue")
 class Queue(Element):
     """Thread boundary with a bounded buffer queue.
@@ -220,15 +236,7 @@ class Queue(Element):
                         self.forward_event(item)
                 else:
                     self.stats.add(buffers=1, bytes=item.nbytes)
-                    if _obs_spans.ENABLED:
-                        qt = item.extras.pop(_obs_ctx.QT_KEY, None)
-                        if qt is not None:
-                            ctx = item.extras.get(_obs_ctx.CTX_KEY)
-                            if ctx is not None:
-                                wait = max(0, time.time_ns() - qt)
-                                _obs_spans.record_span(self.name, "queue",
-                                                       qt, wait, ctx)
-                                ctx.q_ns += wait
+                    _record_queue_wait(self.name, item)
                     self.srcpad.push(item)
             except FlowError:
                 break
@@ -301,12 +309,34 @@ class AppSrc(SrcElement):
     ``end_stream``; the src loop relays into the pipeline."""
 
     PROPS = {"caps": "", "max-buffers": 64}
+    SPAN_POINTS = ("source-root", "queue-wait", "chain")
 
     def __init__(self, name=None, **props):
         super().__init__(name, **props)
-        self._q: _pyqueue.Queue = _pyqueue.Queue(maxsize=max(1, self.max_buffers))
+        self._q: _pyqueue.Queue = self._make_q()
+
+    def _make_q(self) -> _pyqueue.Queue:
+        return _pyqueue.Queue(maxsize=max(1, int(self.max_buffers)))
+
+    def set_property(self, key: str, value) -> None:
+        super().set_property(key, value)
+        # the launch parser applies properties after __init__: rebuild
+        # the entry queue then, as Queue does (during Element.__init__
+        # _q does not exist yet; once started, the app thread may be
+        # blocked on the queue that would be dropped)
+        if key.replace("_", "-") == "max-buffers" and "_q" in self.__dict__:
+            if self._started:
+                raise RuntimeError(
+                    f"{self.name}: cannot reconfigure a running appsrc")
+            self._q = self._make_q()
 
     def push_buffer(self, buf: Buffer) -> None:
+        if _obs_spans.ENABLED:
+            # the frame is born HERE, not when the src loop pops it: the
+            # wait in this entry queue belongs to its latency
+            if _obs_ctx.ctx_of(buf) is None:
+                _obs_spans.record_root(self.name, _obs_ctx.stamp(buf))
+            buf.extras[_obs_ctx.QT_KEY] = time.time_ns()
         self._q.put(buf)
 
     def end_stream(self) -> None:
@@ -321,7 +351,10 @@ class AppSrc(SrcElement):
                 item = self._q.get(timeout=0.1)
             except _pyqueue.Empty:
                 continue
-            return None if item is _SENTINEL else item
+            if item is _SENTINEL:
+                return None
+            _record_queue_wait(self.name, item)
+            return item
         return None
 
 

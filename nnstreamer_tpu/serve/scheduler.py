@@ -192,6 +192,13 @@ class ServeScheduler:
         batch = self.batcher.next_batch(stop)
         if batch is None:
             return None
+        with _obs_spans.region("nns.serve.form_batch", "serve",
+                               element=self.name, rows=len(batch)):
+            return self._form_batch(batch)
+
+    def _form_batch(self, batch):
+        """Popped requests -> (requests, bucket, stacked + placed
+        arrays), with the per-request queue accounting."""
         bucket = self.batcher.bucket_for(len(batch))
         now = time.monotonic()
         with self._mlock:
@@ -209,7 +216,8 @@ class ServeScheduler:
                     wait = int((now - r.t_arrival) * 1e9)
                     _obs_spans.record_span(f"{self.name}:queue_wait",
                                            "queue", t_wall - wait, wait,
-                                           r.ctx)
+                                           r.ctx, prof="nns.queue.wait",
+                                           element=self.name)
                     r.ctx.q_ns += wait
         return batch, bucket, self.place(stack_requests(batch, bucket))
 
@@ -227,7 +235,9 @@ class ServeScheduler:
             from ..parallel.mesh import mesh_from_spec
             self._mesh = mesh_from_spec(self.mesh_spec)
         from ..parallel.sharding import place_batch
-        placed = place_batch(stacked, self._mesh)
+        with _obs_spans.region("nns.serve.place", "serve",
+                               element=self.name):
+            placed = place_batch(stacked, self._mesh)
         self.stats.inc("placed_batches")
         return placed
 
@@ -236,6 +246,12 @@ class ServeScheduler:
         request that contributed input row ``i`` (padded rows have no
         request and are dropped). A failing per-row callback (its client
         died mid-reply) must not starve the other rows of the batch."""
+        with _obs_spans.region("nns.serve.complete", "serve",
+                               element=self.name, rows=len(batch)):
+            self._complete(batch, outputs)
+
+    def _complete(self, batch: List[Request],
+                  outputs: Sequence[Any]) -> None:
         now = time.monotonic()
         import jax
         # ONE batched D2H transfer for every device output (host arrays

@@ -43,6 +43,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from ..obs import spans as _obs_spans
 from ..utils import flowmarks as flow
 
 # cap on arrays per RPC so one giant drain can't add unbounded latency
@@ -101,6 +102,7 @@ class _Coalescer:
     the thread and provide :meth:`_rpc`."""
 
     THREAD_NAME = "nns-transfer"
+    SPAN = "nns.transfer"         # the span around each batched call
 
     def __init__(self):
         self._q: List[_Ticket] = []
@@ -191,9 +193,12 @@ class _Coalescer:
             flat = [a for t in grab for a in (t.arrays or ())]
             t0 = _time.perf_counter()
             try:
-                if _sim_rtt_s > 0.0:
-                    _time.sleep(_sim_rtt_s)
-                results = self._rpc(grab, flat)
+                with _obs_spans.region(
+                        self.SPAN, "transfer", arrays=len(flat),
+                        bytes=sum(a.nbytes for a in flat)):
+                    if _sim_rtt_s > 0.0:
+                        _time.sleep(_sim_rtt_s)
+                    results = self._rpc(grab, flat)
                 last_rpc = _time.perf_counter() - t0
                 self._account(len(grab), len(flat))
             except BaseException:  # noqa: BLE001 - isolate per frame below
@@ -231,6 +236,7 @@ class _Downloader(_Coalescer):
     """D2H: one batched ``jax.device_get`` per RPC."""
 
     THREAD_NAME = "nns-fetch"
+    SPAN = "nns.transfer.fetch"
 
     def _rpc(self, tickets: List[_Ticket], flat: List[Any]) -> List[Any]:
         import jax
@@ -243,6 +249,7 @@ class _Uploader(_Coalescer):
     call."""
 
     THREAD_NAME = "nns-upload"
+    SPAN = "nns.transfer.upload"
 
     def _rpc(self, tickets: List[_Ticket], flat: List[Any]) -> List[Any]:
         import jax
@@ -408,10 +415,15 @@ class InFlightWindow:
         self._last_ns: Optional[int] = None
 
     @flow.acquires("window-slot")
-    def acquire(self, timeout: Optional[float] = None) -> Optional[int]:
+    def acquire(self, timeout: Optional[float] = None, ctx=None,
+                element: str = "") -> Optional[int]:
         """Take a window slot; returns the dispatch timestamp (ns) to
-        hand back to :meth:`release`, or None on timeout."""
+        hand back to :meth:`release`, or None on timeout. With the
+        frame's trace context the blocked time is also that frame's
+        window-wait span on the calling (chain) thread, charged to its
+        queue attribution."""
         import time as _time
+        t_wall = _time.time_ns() if ctx is not None else 0
         t0 = _time.perf_counter_ns()
         with self._cv:
             while self._inflight >= self.limit:
@@ -426,7 +438,13 @@ class InFlightWindow:
                 self._peak = self._inflight
             if self._first_ns is None:
                 self._first_ns = now
-            return now
+        if ctx is not None:
+            _obs_spans.record_span(f"{element}:window_wait", "queue", t_wall,
+                                   now - t0, ctx,
+                                   prof="nns.filter.window_wait",
+                                   element=element)
+            ctx.q_ns += now - t0
+        return now
 
     @flow.settles("window-slot")
     def release(self, t_dispatch_ns: int) -> None:
