@@ -68,6 +68,29 @@ def infer_batch_dim(sel: TensorsInfo, model: TensorsInfo) -> Optional[int]:
 
 @register_element("tensor_filter")
 class TensorFilter(Element):
+    """Runs a model on every buffer through a filter backend
+    (``framework``).
+    ``framework=jax`` converts the model's parameters to their compute
+    dtype once per load, not once per buffer: a parameter leaf whose
+    every use in the traced program is a conversion to one narrower
+    floating dtype (flax's float32 kernels under a bfloat16 module) is
+    converted on the device by one program, ``jit_nns_filter_prepare``,
+    and the per-buffer program ``jit_nns_filter_<model>`` is built from
+    the same trace without those conversions (``filters/prepare.py``);
+    every input signature, mesh mode and a fused segment share the one
+    converted tree. It is redone when the parameters are replaced
+    (``reload_model()``, the resume after ``suspend``). The loaded tree
+    stays on the device as the source of both, so the copy costs the
+    converted leaves' bytes in the narrow dtype on top (ViT-H/14: 2.53 GB
+    of float32 + 1.26 GB of bfloat16). ``transfer_report()`` carries
+    ``prepared_leaves`` and ``prepared_bytes`` (0 where no leaf
+    qualifies: a model used in float32, a tree already in bfloat16, which
+    get ``jax.jit`` of their ``apply_fn`` and their own arrays as
+    before); the span ring and a profiler trace hold one
+    ``nns.filter.prepare`` span per load with ``leaves``, ``bytes_in``
+    and ``bytes_out``. No property selects it: the values are rounded
+    the same way whenever it is done."""
+
     SINK_TEMPLATES = {"sink": "other/tensors"}
     SRC_TEMPLATES = {"src": "other/tensors"}
     # under overlap-depth>0 the executor adds dispatch/complete spans
@@ -741,8 +764,17 @@ class TensorFilter(Element):
 
     def transfer_report(self) -> dict:
         """Window occupancy / overlap stats for trace.report()'s
-        ``transfer`` block; {} when running synchronously."""
-        return self._overlap.report() if self._overlap is not None else {}
+        ``transfer`` block, with the backend's ``prepared_leaves`` /
+        ``prepared_bytes`` (filters/prepare.py: parameters held a
+        second time in their compute dtype); {} when running
+        synchronously with nothing prepared."""
+        rep = self._overlap.report() if self._overlap is not None else {}
+        prepared = getattr(self.fw, "prepared_report", None)
+        if callable(prepared):
+            held = prepared()
+            if rep or held["prepared_leaves"]:
+                rep = {**rep, **held}
+        return rep
 
     # -- circuit breaker ---------------------------------------------------
     def _shed_frame(self, buf: Buffer) -> None:

@@ -41,6 +41,7 @@ from ..obs import spans as _obs_spans
 from ..tensors.info import TensorsInfo
 from ..utils.log import logger
 from ..utils.xla_cache import ensure_compile_cache
+from . import prepare as _prepare
 from .base import (Accelerator, FilterEvent, FilterFramework,
                    FilterProperties,
                    parse_custom_properties as _parse_custom)
@@ -93,7 +94,17 @@ class JaxFilter(FilterFramework):
 
     def __init__(self):
         self._apply: Optional[Callable] = None
+        # the tree as the model handed it over: what reload and suspend read
         self._params: Any = None
+        # filters/prepare.py: {leaf index: dtype} the first program
+        # traced after a load found convertible (None: none traced
+        # yet), and the tree holding those leaves converted, which
+        # every program that agrees on the set runs on
+        self._narrow: Optional[Dict[int, Any]] = None
+        self._prepared: Any = None
+        self._prepared_bytes = 0
+        # jit-cache keys of the programs that take the converted tree
+        self._on_prepared: set = set()
         self._in_info: Optional[TensorsInfo] = None
         self._out_info: Optional[TensorsInfo] = None
         self._jit_cache: Dict[Tuple, Any] = {}
@@ -165,8 +176,7 @@ class JaxFilter(FilterFramework):
                     xs = self._place_inputs(xs)
                 else:
                     xs = [jax.device_put(x, self._device) for x in xs]
-                out = self._executable(sig, donate)(self._params, *xs)
-                jax.block_until_ready(out)
+                jax.block_until_ready(self._run(xs, donate))
                 warmed += 1
             except (TypeError, ValueError) as exc:
                 # a stale signature (model shape change across versions)
@@ -214,34 +224,109 @@ class JaxFilter(FilterFramework):
     def close(self) -> None:
         self._apply = None
         self._params = None
+        self._drop_programs()
+
+    def _drop_programs(self) -> None:
+        """Forget what was built from the parameters that are being
+        replaced or unloaded: the programs, the converted leaves and
+        the set they were chosen by. The next program built redoes all
+        three from ``self._params`` as it then stands."""
         self._jit_cache.clear()
+        self._on_prepared.clear()
+        self._narrow = self._prepared = None
+        self._prepared_bytes = 0
+
+    def prepared_report(self) -> Dict[str, int]:
+        """How many parameter leaves the loaded model holds a second
+        time in their compute dtype, and that copy's bytes (0, 0 where
+        no leaf qualified): the element's ``transfer_report()``."""
+        return {"prepared_leaves": len(self._narrow or ()),
+                "prepared_bytes": self._prepared_bytes}
 
     # -- info -------------------------------------------------------------
     def get_model_info(self):
         return self._in_info, self._out_info
 
     # -- invoke -----------------------------------------------------------
-    def _executable(self, sig: Tuple,
-                    donate_idx: Tuple[int, ...] = ()) -> Callable:
-        """One compiled executable per input signature (shape/dtype tuple).
+    def _run(self, xs: Sequence[Any],
+             donate_idx: Tuple[int, ...] = ()) -> Any:
+        """Enqueue the program for placed inputs ``xs`` (lock held).
+        One compiled executable per input signature (shape/dtype tuple).
         Recompile-on-new-signature is the static-shape answer to dynamic
         models (SURVEY.md §7 hard part (a)). ``donate_idx`` (1-based:
         arg 0 is params, which are NEVER donated) selects inputs whose
         device buffers XLA may alias into the outputs; it is part of the
-        cache key because donation changes the compiled program."""
+        cache key because donation changes the compiled program.
+
+        The model is traced here, once per program, and the program is
+        built from that trace: over the converted leaves where the
+        trace agrees with the loaded model's set (filters/prepare.py),
+        else ``jax.jit`` of ``apply_fn`` itself on the loaded tree."""
+        sig = tuple((tuple(x.shape), str(x.dtype)) for x in xs)
         key = (sig, donate_idx) if donate_idx else sig
         exe = self._jit_cache.get(key)
         if exe is None:
             import jax
-            # a stable program name for the trace's XLA Modules line
-            call = _obs_spans.named_program(
-                "nns_filter_" + self._model_stem, self._apply)
-            exe = jax.jit(call, donate_argnums=donate_idx) if donate_idx \
-                else jax.jit(call)
+
+            def jit(fn):
+                # a stable program name for the trace's XLA Modules line
+                fn = _obs_spans.named_program(
+                    "nns_filter_" + self._model_stem, fn)
+                return jax.jit(fn, donate_argnums=donate_idx) \
+                    if donate_idx else jax.jit(fn)
+
+            exe = jit(self._apply)
+            closed, out_tree, narrow = _prepare.trace(exe, self._params, xs)
+            if self._converted(self._params, narrow) is not None:
+                exe = jit(_prepare.program(closed, out_tree, narrow))
+                self._on_prepared.add(key)
             self._jit_cache[key] = exe
             self.compile_count += 1
             self._record_signature(sig, donate_idx)
-        return exe
+        return exe(self._prepared if key in self._on_prepared
+                   else self._params, *xs)
+
+    def _converted(self, params: Any, narrow: Dict[int, Any]) -> Any:
+        """The tree a program may run on whose trace of ``params``
+        found ``narrow`` convertible (lock held): the loaded model's
+        converted tree, shared by every signature, or None where the
+        trace does not agree with it — nothing qualifies, another set
+        than the first program's (the leaves' uses are ``apply_fn``'s,
+        not the input shape's, so this is a guard), or ``params`` are no
+        longer the loaded ones (a fused segment planned before a
+        reload). The first trace after a load decides the set and
+        converts."""
+        if params is not self._params:
+            return None
+        if self._narrow is None:
+            self._narrow = narrow
+            if narrow:
+                self._prepared = self._convert(narrow)
+        return self._prepared \
+            if narrow and narrow == self._narrow else None
+
+    def _convert(self, narrow: Dict[int, Any]) -> Any:
+        import jax
+        leaves, treedef = jax.tree.flatten(self._params)
+        idx = sorted(narrow)
+        src = [leaves[i] for i in idx]
+        dtypes = [narrow[i] for i in idx]
+        self._prepared_bytes = sum(
+            x.size * d.itemsize for x, d in zip(src, dtypes))
+        # a fused segment reaches here inside ITS trace: the conversion
+        # must run now, not be staged into that program
+        with _obs_spans.region(
+                "nns.filter.prepare", "filter", leaves=len(idx),
+                bytes_in=sum(x.nbytes for x in src),
+                bytes_out=self._prepared_bytes), \
+                jax.ensure_compile_time_eval():
+            out = _prepare.convert(src, dtypes)
+        for i, x in zip(idx, out):
+            leaves[i] = x
+        logger.info("jax filter: %d parameter leaves (%d bytes) converted "
+                    "once for %s", len(idx), self._prepared_bytes,
+                    self._model_stem)
+        return treedef.unflatten(leaves)
 
     def _record_signature(self, sig: Tuple,
                           donate_idx: Tuple[int, ...]) -> None:
@@ -310,8 +395,7 @@ class JaxFilter(FilterFramework):
                       jax.device_put(x if isinstance(x, jax.Array)
                                      else np.asarray(x), self._device)
                       for x in inputs]
-            sig = tuple((tuple(x.shape), str(x.dtype)) for x in xs)
-            out = self._executable(sig)(self._params, *xs)
+            out = self._run(xs)
         if isinstance(out, (list, tuple)):
             return list(out)
         return [out]
@@ -359,8 +443,7 @@ class JaxFilter(FilterFramework):
                 if donate and staged \
                         and self._device.platform in self._DONATION_PLATFORMS:
                     donate_idx = tuple(staged)
-            sig = tuple((tuple(x.shape), str(x.dtype)) for x in xs)
-            out = self._executable(sig, donate_idx)(self._params, *xs)
+            out = self._run(xs, donate_idx)
         return out
 
     def complete(self, handle: Any) -> List[Any]:
@@ -380,7 +463,9 @@ class JaxFilter(FilterFramework):
         apply/params, for the fusion compiler to inline into a larger
         jit program (fusion/segment.py). Params are captured by value:
         the closure stays valid across suspend/reload, it just keeps
-        serving the params it was planned with.
+        serving the params it was planned with. Traced while they are
+        still the loaded ones, it inlines the program over the converted
+        leaves, as ``_run`` builds it, from its one trace of the model.
 
         In mesh mode the closed-over params are mesh-committed
         jax.Arrays, so the fused program compiles over the mesh with
@@ -396,7 +481,15 @@ class JaxFilter(FilterFramework):
                 return None
 
         def fn(*xs):
-            return apply_fn(params, *xs)
+            import jax
+            closed, out_tree, narrow = _prepare.trace(
+                jax.jit(apply_fn), params,
+                [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in xs])
+            with self._lock:
+                tree = self._converted(params, narrow)
+            if tree is None:
+                tree, narrow = params, {}
+            return _prepare.program(closed, out_tree, narrow)(tree, *xs)
 
         return fn
 
@@ -418,7 +511,7 @@ class JaxFilter(FilterFramework):
                 self._mesh = fresh._mesh
                 self._param_sharding = fresh._param_sharding
                 self._device = fresh._device
-                self._jit_cache.clear()
+                self._drop_programs()
             return True
         if event == FilterEvent.SUSPEND:
             # Drop HBM copies; reopen transparently on next invoke
@@ -426,7 +519,7 @@ class JaxFilter(FilterFramework):
             import jax
             with self._lock:
                 self._params = jax.device_get(self._params)
-                self._jit_cache.clear()
+                self._drop_programs()
                 self._suspended = True
             return True
         if event == FilterEvent.RESUME:
