@@ -1,0 +1,65 @@
+"""Grouped expert product: each token's rows go through the weights of
+the expert it was routed to, and through no other.
+
+A sparse expert layer holds ``G`` experts and gets, for ``T`` tokens, a
+choice of ``K`` experts each, of which any number may be held here
+(the others live on other chips). How many token-expert pairs an expert
+serves is data, not a shape, so the dense forms either drop pairs over
+a capacity or multiply every token with every expert. This one does
+neither: the pairs are sorted by expert (:func:`group_by_expert`), each
+expert's run is cut into tiles of ``tile`` rows, and a device loop
+whose trip count is the number of tiles actually occupied runs one
+SwiGLU expert on one tile a turn (:func:`grouped_swiglu`). Exact, no
+capacity, no dropped pair; the cost follows the pairs served, padded to
+a tile an expert. (``jax.lax.ragged_dot`` states the same product, but
+needs a row buffer sized for the worst routing, ``T x K`` rows, where
+this needs one tile.)
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+
+def group_by_expert(choice, held_first: int, held_count: int):
+    """``choice`` [T, K] int32 expert ids over the whole router ->
+    ``(order, counts)``: the ``T * K`` pairs (pair ``p`` is token
+    ``p // K``) sorted by expert, pairs of experts outside
+    ``[held_first, held_first + held_count)`` last, a stable sort; and
+    how many pairs each held expert serves, int32 [held_count]."""
+    local = choice.reshape(-1) - held_first
+    key = jnp.where((local >= 0) & (local < held_count), local, held_count)
+    counts = jnp.sum(key[:, None] == jnp.arange(held_count)[None, :],
+                     axis=0, dtype=jnp.int32)
+    return jnp.argsort(key, stable=True), counts
+
+
+def grouped_swiglu(x, order, counts, pair_weight, w1, w3, w2, *, tile: int):
+    """``x`` [T, d]; ``order``, ``counts`` from :func:`group_by_expert`;
+    ``pair_weight`` [T, K] float32; ``w1``, ``w3`` [G, d, f], ``w2``
+    [G, f, d] -> float32 [T, d]: for each token the sum over its pairs
+    with a held expert of ``weight * (silu(x w1) * (x w3)) w2``."""
+    t, d = x.shape
+    k = order.shape[0] // t
+    weight = pair_weight.reshape(-1)
+    tiles = (counts + tile - 1) // tile          # tiles of each expert
+    tile_end = jnp.cumsum(tiles)
+    row0 = jnp.cumsum(counts) - counts           # an expert's first row
+
+    def one_tile(i, acc):
+        g = jnp.sum(tile_end <= i, dtype=jnp.int32)      # this tile's expert
+        rows = (i - (tile_end[g] - tiles[g])) * tile + jnp.arange(tile)
+        live = rows < counts[g]
+        pair = order[jnp.where(live, row0[g] + rows, 0)]
+        tok = pair // k
+        xt = x[tok]
+        up = jnp.dot(xt, w3[g], preferred_element_type=jnp.float32)
+        gate = jnp.dot(xt, w1[g], preferred_element_type=jnp.float32)
+        y = jnp.dot((jax.nn.silu(gate) * up).astype(x.dtype), w2[g],
+                    preferred_element_type=jnp.float32)
+        y = y * jnp.where(live, weight[pair], 0.0)[:, None]
+        # a dead row's index lies past the end and is dropped
+        return acc.at[jnp.where(live, tok, t)].add(y, mode="drop")
+
+    return jax.lax.fori_loop(0, tile_end[-1], one_tile,
+                             jnp.zeros((t, d), jnp.float32))
