@@ -86,9 +86,10 @@ class TensorFilter(Element):
     ``prepared_leaves`` and ``prepared_bytes`` (0 where no leaf
     qualifies: a model used in float32, a tree already in bfloat16, which
     get ``jax.jit`` of their ``apply_fn`` and their own arrays as
-    before); the span ring and a profiler trace hold one
-    ``nns.filter.prepare`` span per load with ``leaves``, ``bytes_in``
-    and ``bytes_out``. No property selects it: the values are rounded
+    before) and, read off the same trace, ``kernel_calls``: the Pallas
+    kernels the program calls, name -> call sites; the span ring and a
+    profiler trace hold one ``nns.filter.prepare`` span per load with
+    ``leaves``, ``bytes_in`` and ``bytes_out``. No property selects it: the values are rounded
     the same way whenever it is done."""
 
     SINK_TEMPLATES = {"sink": "other/tensors"}
@@ -766,13 +767,15 @@ class TensorFilter(Element):
         """Window occupancy / overlap stats for trace.report()'s
         ``transfer`` block, with the backend's ``prepared_leaves`` /
         ``prepared_bytes`` (filters/prepare.py: parameters held a
-        second time in their compute dtype); {} when running
-        synchronously with nothing prepared."""
+        second time in their compute dtype) and ``kernel_calls`` (the
+        program's Pallas kernels by name, with their call sites); {}
+        when running synchronously with nothing prepared and no
+        kernel."""
         rep = self._overlap.report() if self._overlap is not None else {}
         prepared = getattr(self.fw, "prepared_report", None)
         if callable(prepared):
             held = prepared()
-            if rep or held["prepared_leaves"]:
+            if rep or held["prepared_leaves"] or held["kernel_calls"]:
                 rep = {**rep, **held}
         return rep
 

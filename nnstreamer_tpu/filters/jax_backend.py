@@ -103,6 +103,8 @@ class JaxFilter(FilterFramework):
         self._narrow: Optional[Dict[int, Any]] = None
         self._prepared: Any = None
         self._prepared_bytes = 0
+        # {kernel name: call sites} in that first program's trace
+        self._kernel_calls: Dict[str, int] = {}
         # jit-cache keys of the programs that take the converted tree
         self._on_prepared: set = set()
         self._in_info: Optional[TensorsInfo] = None
@@ -235,13 +237,18 @@ class JaxFilter(FilterFramework):
         self._on_prepared.clear()
         self._narrow = self._prepared = None
         self._prepared_bytes = 0
+        self._kernel_calls = {}
 
-    def prepared_report(self) -> Dict[str, int]:
+    def prepared_report(self) -> Dict[str, Any]:
         """How many parameter leaves the loaded model holds a second
         time in their compute dtype, and that copy's bytes (0, 0 where
-        no leaf qualified): the element's ``transfer_report()``."""
+        no leaf qualified), and ``kernel_calls``: the Pallas kernels of
+        the first program traced after the load, by name, with their
+        call sites ({} for a model in plain XLA). The element's
+        ``transfer_report()``."""
         return {"prepared_leaves": len(self._narrow or ()),
-                "prepared_bytes": self._prepared_bytes}
+                "prepared_bytes": self._prepared_bytes,
+                "kernel_calls": dict(self._kernel_calls)}
 
     # -- info -------------------------------------------------------------
     def get_model_info(self):
@@ -277,6 +284,8 @@ class JaxFilter(FilterFramework):
 
             exe = jit(self._apply)
             closed, out_tree, narrow = _prepare.trace(exe, self._params, xs)
+            if self._narrow is None:
+                self._kernel_calls = _prepare.kernel_calls(closed)
             if self._converted(self._params, narrow) is not None:
                 exe = jit(_prepare.program(closed, out_tree, narrow))
                 self._on_prepared.add(key)

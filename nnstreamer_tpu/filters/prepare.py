@@ -68,6 +68,32 @@ def narrowable(closed, n_leaves: int) -> Dict[int, Any]:
     return out
 
 
+def kernel_calls(closed) -> Dict[str, int]:
+    """``{kernel name: call sites}`` of the Pallas kernels in the traced
+    program, nested programs (loops, branches, ``jit``) included: what
+    says that a model's kernel is on the path, read once off the trace
+    and not off a device profile. Empty for a program in plain XLA."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    found: Dict[str, int] = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                name = str(eqn.params.get("name") or "pallas_call")
+                found[name] = found.get(name, 0) + 1
+                continue            # the kernel's own body is not a site
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (tuple, list)) \
+                        else (value,):
+                    if isinstance(sub, ClosedJaxpr):
+                        walk(sub.jaxpr)
+                    elif isinstance(sub, Jaxpr):
+                        walk(sub)
+
+    walk(closed.jaxpr)
+    return found
+
+
 def _narrowed(closed, narrow: Dict[int, Any]):
     """``closed`` taking the ``narrow`` leaves in their target dtype:
     those inputs retyped, their conversions dropped, each conversion's

@@ -47,6 +47,7 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops.grouped import group_by_expert, grouped_swiglu
 from ..ops.sparse_attention import blocked_causal_attention, topk_mask
@@ -62,6 +63,9 @@ BLOCK_Q = 512             # queries an attention block
 # served and hardly the routing, and a tile's products still hide under
 # the read of its weights
 EXPERT_TILE = 512
+# the columns a head's rotation is cut out at: a multiple of the lane
+# width, so that cutting them out and putting them back shifts no lane
+ROPE_ALIGN = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -194,6 +198,33 @@ def rope_interleaved(x, positions, theta: float):
     return out.reshape(x.shape).astype(x.dtype)
 
 
+def rope_columns(x, positions, theta: float, nope: int):
+    """``x`` [..., S, D] with the columns from ``nope`` on rotated as
+    :func:`rope_interleaved` rotates them and the others as they are:
+    what ``concatenate([x[..., :nope], rope(x[..., nope:])])`` gives,
+    to the bit. A pair's partner comes from a product with a constant
+    0 / +-1 matrix (one term a sum: exact) and not from a shuffle of
+    lanes, and only the columns from the last multiple of
+    ``ROPE_ALIGN`` at or before ``nope`` are read and written back."""
+    d = x.shape[-1]
+    cut = nope // ROPE_ALIGN * ROPE_ALIGN
+    rp, off = d - nope, nope - cut
+    freqs = theta ** (-jnp.arange(0, rp, 2, dtype=jnp.float32) / rp)
+    ang = jnp.repeat(positions.astype(jnp.float32)[:, None] * freqs, 2, -1)
+    cos = jnp.pad(jnp.cos(ang), ((0, 0), (off, 0)), constant_values=1.0)
+    sin = jnp.pad(jnp.sin(ang), ((0, 0), (off, 0)))
+    # (a, b) -> (a cos - b sin, a sin + b cos): x cos + (x @ swap) sin
+    swap = np.zeros((d - cut, d - cut), np.float32)
+    for c in range(off, d - cut, 2):
+        swap[c + 1, c], swap[c, c + 1] = -1.0, 1.0
+    tail = x[..., cut:]
+    partner = jnp.einsum("...d,de->...e", tail, jnp.asarray(swap, x.dtype),
+                         precision=jax.lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)
+    tail = tail.astype(jnp.float32) * cos + partner * sin
+    return x.at[..., cut:].set(tail.astype(x.dtype))
+
+
 def _mm(x, w):
     """Product accumulated in float32, handed on in the stream's dtype."""
     return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
@@ -207,23 +238,37 @@ def swiglu(x, p):
                    preferred_element_type=jnp.float32)
 
 
+def _mm_heads(x, w):
+    """``x`` [S, r] by ``w`` [r, H, d] -> [H, S, d], accumulated in
+    float32: head-major as the product writes it, which is how the
+    attention kernel reads a head (``ops/sparse_attention.py``)."""
+    return jnp.einsum("sr,rhd->hsd", x, w,
+                      preferred_element_type=jnp.float32).astype(x.dtype)
+
+
 def mla_qkv(x, a, positions, cfg: GLMDSAConfig):
     """The latent projections of normed ``x`` [S, d]: ``c_q`` [S, r_q]
     and per-head ``q`` [S, H, nope+rope], ``k`` [S, H, nope+rope] (the
-    one roped key part repeated to every head), ``v`` [S, H, v]."""
-    s, h = x.shape[0], cfg.num_attention_heads
-    nope, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    one roped key part repeated to every head), ``v`` [S, H, v].
+
+    All three are views of head-major arrays, which no transpose or
+    concatenation makes: ``k`` and ``v`` are two products; the key
+    columns of ``wkv_b`` take ``rope`` columns of zeros a head, and the
+    roped part is added into the gap they leave (one operand of each
+    sum is zero: exact)."""
+    h, nope = cfg.num_attention_heads, cfg.qk_nope_head_dim
+    r, rp = cfg.kv_lora_rank, cfg.qk_rope_head_dim
     c_q = rmsnorm(_mm(x, a["wq_a"]), a["q_norm"], cfg.rms_norm_eps)
-    q = _mm(c_q, a["wq_b"]).reshape(s, h, -1)
-    q = jnp.concatenate([q[..., :nope], rope_interleaved(
-        q[..., nope:], positions, cfg.rope_theta)], -1)
+    q = rope_columns(_mm_heads(c_q, a["wq_b"].reshape(-1, h, nope + rp)),
+                     positions, cfg.rope_theta, nope)
     kv = _mm(x, a["wkv_a"])
     c_kv = rmsnorm(kv[:, :r], a["kv_norm"], cfg.rms_norm_eps)
-    k_r = rope_interleaved(kv[:, None, r:], positions, cfg.rope_theta)
-    kvb = _mm(c_kv, a["wkv_b"]).reshape(s, h, -1)
-    k = jnp.concatenate([kvb[..., :nope], jnp.broadcast_to(
-        k_r, (s, h, k_r.shape[-1]))], -1)
-    return c_q, q, k, kvb[..., nope:]
+    k_r = rope_interleaved(kv[:, r:], positions, cfg.rope_theta)
+    w = a["wkv_b"].reshape(r, h, -1)
+    k = _mm_heads(c_kv, jnp.pad(w[..., :nope], ((0, 0), (0, 0), (0, rp)))) \
+        + jnp.pad(k_r, ((0, 0), (nope, 0)))
+    v = _mm_heads(c_kv, w[..., nope:])
+    return (c_q,) + tuple(jnp.transpose(t, (1, 0, 2)) for t in (q, k, v))
 
 
 def indexer_qkw(x, c_q, ix, positions, cfg: GLMDSAConfig):
@@ -278,7 +323,11 @@ def attend(h, layer, cfg: GLMDSAConfig):
         q, k, v, scale=q.shape[-1] ** -0.5, block_q=BLOCK_Q,
         key_mask=selected, scope="block/attn")
     with jax.named_scope("block/attn"):
-        return h + _mm(o.reshape(s, -1), layer["attn"]["wo"])
+        # over (head, v) as the attention wrote them: no [S, H * v] copy
+        wo = layer["attn"]["wo"].reshape(o.shape[1], o.shape[2], -1)
+        return h + jnp.einsum("shv,hvd->sd", o, wo,
+                              preferred_element_type=jnp.float32
+                              ).astype(h.dtype)
 
 
 def route(x, moe, cfg: GLMDSAConfig):

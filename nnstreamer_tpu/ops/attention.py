@@ -10,8 +10,12 @@ stance: hand-fuse only what XLA cannot).
 
 Scope: non-causal full-sequence attention with sequence lengths that
 fit VMEM after padding to the 128-lane tile (S_pad^2 f32 scores; fine
-through S≈1024 — the ViT/encoder regime). Longer or causal decode
-sequences belong to the ring/Ulysses paths (parallel/ring.py) or the
+through S≈1024 — the ViT/encoder regime). Causal attention of long
+sequences, with or without a per-query key selection, is
+``ops/sparse_attention.py``'s ``nns_masked_attention`` (key tiles walked
+with a running softmax, no S bound; the lm block of models/glm_dsa.py
+runs it); the two share no logic. Sequences split over chips belong to
+the ring/Ulysses paths (parallel/ring.py), cached decode to the
 KV-cache decode loop (models/transformer.py), not here.
 
 Drop-in: :func:`fused_attention` matches the flax

@@ -145,7 +145,8 @@ def test_nothing_to_convert_same_program_same_arrays(conversions, model,
     x = np.random.default_rng(0).random(shape, np.float32)
     fw.invoke([x])
     assert fw.prepared_report() == {"prepared_leaves": 0,
-                                    "prepared_bytes": 0}
+                                    "prepared_bytes": 0,
+                                    "kernel_calls": {}}
     assert conversions == [] and fw._prepared is None
     exe, = fw._jit_cache.values()
     assert not fw._on_prepared
@@ -195,7 +196,8 @@ def test_leaf_with_a_float32_use_is_not_converted(tmp_path):
     assert fw._prepared["w"] is fw._params["w"]
     assert fw._prepared["k"].dtype == jnp.bfloat16
     assert fw.prepared_report() == {"prepared_leaves": 1,
-                                    "prepared_bytes": 16 * 8 * 2}
+                                    "prepared_bytes": 16 * 8 * 2,
+                                    "kernel_calls": {}}
     want = np.asarray(jax.jit(fw._apply)(fw._params, x))
     assert got.tobytes() == want.tobytes()
     fw.close()
@@ -389,3 +391,38 @@ def test_aot_estimate_smoke(topology):
     assert rows and all(n > 0 and c > 0 for n, c in rows.values())
     assert sum(c for _, c in rows.values()) \
         < sum(c for _, c in tool.fusion_cycles(loaded).values())
+
+
+@pytest.mark.parametrize("lo,hi,masked", [(3584, 4096, True),
+                                          (1536, 2048, False)],
+                         ids=["masked", "causal"])
+def test_mosaic_takes_the_masked_attention_kernel(topology, lo, hi, masked):
+    """What the interpreter cannot show: the TPU's compiler lays out
+    ``nns_masked_attention`` at the lm cell's widths (64 heads of 256,
+    S = 4096, the module's own tiles) within the VMEM it asks for."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from nnstreamer_tpu.ops import sparse_attention as sa
+    dev = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name=topology).devices[0])
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    def block(q, k, v, keep, out):
+        return sa._attend_block(q, k, v, keep if masked else None, out,
+                                lo=lo, hi=hi, tq=sa.TILE_Q, tk=sa.TILE_K,
+                                scale=1 / 16, interpret=False)
+
+    heads = spec((64, 4096, 256))
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        # conftest pins float32 products for the CPU's sake; the chip
+        # multiplies bfloat16 operands as they are
+        with jax.default_matmul_precision("default"):
+            text = jax.jit(block).lower(
+                heads, heads, heads, spec((hi - lo, hi), jnp.int8), heads
+            ).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert "tpu_custom_call" in text and "nns_masked_attention" in text

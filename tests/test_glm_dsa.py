@@ -14,9 +14,10 @@ import jax.numpy as jnp
 
 from nnstreamer_tpu import Buffer, parse_launch
 from nnstreamer_tpu.models import glm_dsa, zoo
+from nnstreamer_tpu.ops import sparse_attention
 from nnstreamer_tpu.ops.grouped import group_by_expert, grouped_swiglu
-from nnstreamer_tpu.ops.sparse_attention import (blocked_causal_attention,
-                                                 topk_mask)
+from nnstreamer_tpu.ops.sparse_attention import (
+    blocked_causal_attention, reference_blocked_attention, topk_mask)
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark"))
@@ -334,3 +335,108 @@ def test_pipeline_gives_the_direct_calls_three_tensors(window):
         assert [x.dtype for x in g] == [np.float32, np.float32, np.int32]
         for a, b in zip(g, w):
             np.testing.assert_array_equal(a, np.asarray(b))
+
+
+def _keep(case, s, block_q, tile_k, rng):
+    """The ``[S, S]`` selection of a kernel case (True = keep; the
+    causal rule is the function's own), None for a causal-only one."""
+    rows, cols = np.arange(s)[:, None], np.arange(s)[None, :]
+    if case in ("causal", "bfloat16_causal"):
+        return None
+    if case == "single_key":
+        return cols == rng.integers(0, rows + 1)
+    keep = rng.random((s, s)) < 0.5
+    if case == "tile_dropped":
+        # rows of the later blocks lose every key of the first key tile,
+        # every third row every key of its last whole tile as well
+        keep[block_q:, :tile_k] = False
+        last = np.maximum(rows // tile_k - 1, 0) * tile_k
+        keep &= ~((rows % 3 == 0) & (rows >= 2 * tile_k)
+                  & (cols >= last) & (cols < last + tile_k))
+    return keep | (cols == rows)
+
+
+@pytest.mark.parametrize("case,s,block_q,tile_q,dtype,tol", [
+    ("causal", 256, 64, 64, jnp.float32, 2e-5),
+    ("masked", 256, 64, 32, jnp.float32, 2e-5),
+    # S a multiple of neither tile, block_q not one of the key tile
+    ("masked_ragged", 300, 96, 32, jnp.float32, 2e-5),
+    ("tile_dropped", 384, 128, 64, jnp.float32, 2e-5),
+    ("single_key", 256, 128, 128, jnp.float32, 2e-5),
+    # the weights reach the second product in bfloat16, as the oracle's
+    ("bfloat16", 256, 64, 64, jnp.bfloat16, 2e-2),
+    ("bfloat16_causal", 200, 64, 64, jnp.bfloat16, 2e-2),
+])
+def test_masked_attention_kernel_is_the_plain_block(monkeypatch, case, s,
+                                                    block_q, tile_q, dtype,
+                                                    tol):
+    """``nns_masked_attention`` (the same body the chip compiles, here
+    through the Pallas interpreter) against the block in plain XLA,
+    several key tiles a query tile: causal-only and masked blocks, a row
+    that keeps nothing of a whole key tile (no NaN, same result), one
+    key a row, ragged sizes; ``key_mask`` once a block, in order."""
+    tile_k = 128
+    monkeypatch.setattr(sparse_attention, "TILE_Q", tile_q)
+    monkeypatch.setattr(sparse_attention, "TILE_K", tile_k)
+    rng = np.random.default_rng(len(case))
+    q, k = (jnp.asarray(rng.standard_normal((s, 2, 24)), dtype)
+            for _ in range(2))
+    v = jnp.asarray(rng.standard_normal((s, 2, 16)), dtype)
+    keep = _keep(case, s, block_q, tile_k, rng)
+    calls = []
+
+    def key_mask(lo, hi):
+        calls.append((lo, hi))
+        # the first block causal-only, as the model's is
+        return None if lo == 0 else jnp.asarray(keep[lo:hi, :hi])
+
+    mask = None if keep is None else key_mask
+    got = blocked_causal_attention(q, k, v, scale=0.3, block_q=block_q,
+                                   key_mask=mask)
+    blocks = [(lo, min(lo + block_q, s)) for lo in range(0, s, block_q)]
+    assert calls == (blocks if mask else [])
+    want = reference_blocked_attention(q, k, v, scale=0.3, block_q=block_q,
+                                       key_mask=mask)
+    assert got.shape == want.shape == (s, 2, 16) and got.dtype == dtype
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=tol)
+    if dtype == jnp.bfloat16:
+        # and against the oracle in float32 throughout: a bfloat16's
+        # rounding of the weights and of the result, no more
+        exact = reference_blocked_attention(
+            *(x.astype(jnp.float32) for x in (q, k, v)), scale=0.3,
+            block_q=block_q, key_mask=mask)
+        np.testing.assert_allclose(got, exact, atol=3e-2)
+
+
+def test_block_without_a_query_tile_raises():
+    x = jnp.zeros((24, 1, 8))
+    with pytest.raises(ValueError, match="block_q=12"):
+        blocked_causal_attention(x, x, x, scale=1.0, block_q=12)
+
+
+@pytest.mark.parametrize("uri,dims,calls", [
+    # 3 layers x 4 blocks of 16 queries (the fixture's BLOCK_Q)
+    ("zoo://glm_dsa?seq=64&held_first=8&held_count=8",
+     ("int32", "64"), {"nns_masked_attention": 12}),
+    ("zoo://mlp", ("float32", "64:4"), {}),
+], ids=["glm_dsa", "plain_xla"])
+def test_backend_reports_the_kernels_it_calls(uri, dims, calls):
+    """``kernel_calls`` beside ``prepared_leaves``: the kernel's name
+    with its call sites in the traced program, nothing for a model in
+    plain XLA."""
+    caps = (f"other/tensors,format=static,num_tensors=1,types=(string)"
+            f"{dims[0]},dimensions=(string){dims[1]},framerate=0/1")
+    p = parse_launch(f'appsrc name=in caps="{caps}" ! tensor_filter name=f '
+                     f'framework=jax model={uri} in-flight=2 ! '
+                     f'appsink name=out')
+    p.start()
+    shape = tuple(int(d) for d in reversed(dims[1].split(":")))
+    p["in"].push_buffer(Buffer.from_arrays([np.zeros(shape, dims[0])]))
+    p["in"].end_stream()
+    assert p.wait_eos(timeout=120)
+    report = p["f"].transfer_report()
+    p.stop()
+    assert report["kernel_calls"] == calls
+    assert report["prepared_leaves"] == 0
