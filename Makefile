@@ -50,11 +50,13 @@ fuse-parity:
 async-parity:
 	env JAX_PLATFORMS=cpu python tools/fuse_parity.py --mode async
 
-# `make shard-parity` = the sharded-serving byte-parity oracle: every
+# `make shard-parity` = the sharded-serving parity oracle: every
 # mesh-declaring pipeline in the corpus (plus a built-in representative
-# suite) must produce byte-identical sink output sharded across the
-# 8-virtual-device mesh and single-chip (tools/shard_parity.py exits
-# nonzero on any divergence, and on vacuous coverage).
+# suite) must produce the same sink output sharded across the
+# 8-virtual-device mesh and single-chip: the same bytes, floats within
+# SHARD_RTOL where the shardings sum in different orders
+# (tools/shard_parity.py exits nonzero on any divergence, and on
+# vacuous coverage).
 shard-parity:
 	env JAX_PLATFORMS=cpu python tools/shard_parity.py
 
@@ -115,8 +117,8 @@ chaos-llm:
 chaos-elastic:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_fleet.py -q -m slow
 
-# `make obs-overhead` = the observability cost gate: the devres bench
-# row run with frame tracing on (NNS_TPU_OBS=1) vs hard-off, in
+# `make obs-overhead` = the observability cost gate: a devres-shaped
+# pipeline run with frame tracing on (NNS_TPU_OBS=1) vs hard-off, in
 # subprocesses, best-of-3 each — fails if the traced arm's fps is more
 # than 3% below the control (tools/obs_overhead.py).
 obs-overhead:

@@ -7,8 +7,8 @@ at the full width of models the repo already has, with random weights
 made from a seed:
 
   label    the README pipeline: MobileNet-v2 -> image_labeling, default
-           properties, then the properties every bench row sets
-           (prefetch-host, in-flight window, input donation)
+           properties, then the properties the benchmark's stream
+           cells set (prefetch-host, in-flight window) and input donation
   serve    tensor_serve_src ! ViT-B/16 ! tensor_serve_sink answering four
            in-process tensor_query_client pipelines over loopback
   decode   paged continuous-batching decode on the largest decoder the
@@ -61,7 +61,7 @@ LABEL_MODEL = ("zoo://mobilenet_v2",
 SERVE_MODEL = ("zoo://vit",
                "zoo://vit?size=32&patch=16&d_model=64&layers=2&heads=4"
                "&classes=16")
-# the largest decoder the repo configures (bench.py LLM_LARGE)
+# a 1.0 B-parameter decoder at the zoo's widths
 DECODE_MODEL = ("zoo://gpt?vocab=32000&d_model=1536&n_heads=16&n_layers=24",
                 "zoo://gpt?vocab=256&d_model=64&n_heads=4&n_layers=2")
 ATTN_SHAPE = ((8, 196, 12, 64), (1, 20, 2, 8))       # ViT-B/16: [B,S,H,D]

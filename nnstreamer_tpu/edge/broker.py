@@ -35,15 +35,15 @@ from ..utils.log import logger
 from .listener import TcpListener
 from .protocol import MsgKind, recv_msg, send_msg
 
-# live in-process brokers, for trace.report()'s broker block (tests and
-# single-host fleets run the broker in-process; a weak set never keeps a
+# live in-process brokers, for the pipeline report's broker block
+# (obs/report.py; tests and single-host fleets run the broker in-process; a weak set never keeps a
 # stopped broker alive)
 _LIVE: "weakref.WeakSet[DiscoveryBroker]" = weakref.WeakSet()
 
 
 def live_broker_stats() -> Dict[str, int]:
     """Aggregate counters of every live in-process broker (the
-    trace.report() surfacing hook). {} when no broker is running."""
+    pipeline report's hook). {} when no broker is running."""
     out: Dict[str, int] = {}
     for b in list(_LIVE):
         for k, v in b.stats.snapshot().items():

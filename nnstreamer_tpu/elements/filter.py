@@ -764,7 +764,7 @@ class TensorFilter(Element):
                 for o in outputs]
 
     def transfer_report(self) -> dict:
-        """Window occupancy / overlap stats for trace.report()'s
+        """Window occupancy / overlap stats for the pipeline report's
         ``transfer`` block, with the backend's ``prepared_leaves`` /
         ``prepared_bytes`` (filters/prepare.py: parameters held a
         second time in their compute dtype) and ``kernel_calls`` (the

@@ -355,7 +355,7 @@ class JaxFilter(FilterFramework):
     def mesh(self):
         """The live Mesh in mesh mode (None per-chip) — read by the
         fused-segment compiler, the in-flight window's per-mesh slot
-        accounting, and trace.report()'s devices fields."""
+        accounting, and the pipeline report's devices fields."""
         return self._mesh
 
     def _input_sharding(self, x):

@@ -337,7 +337,7 @@ class FusedSegment(TransformElement):
         super().handle_event(pad, event)
 
     def transfer_report(self) -> dict:
-        """Window occupancy / overlap stats for trace.report()'s
+        """Window occupancy / overlap stats for the pipeline report's
         ``transfer`` block; {} when running synchronously."""
         return self._overlap.report() if self._overlap is not None else {}
 

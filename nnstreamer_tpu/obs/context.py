@@ -4,8 +4,7 @@ One context object rides in ``Buffer.extras[CTX_KEY]`` from the source
 that stamped it to whatever finally settles the frame — across queue
 hops (extras survive the queue), element rewrites (``copy_meta_from`` /
 ``with_chunks`` copy extras; elements that mint fresh buffers inherit
-the chain thread's current context, mirroring ``utils.trace``'s
-birth-stamp inheritance), and wire hops (``edge.wire`` re-creates the
+the chain thread's current context), and wire hops (``edge.wire`` re-creates the
 context on the receiving side from the negotiated trace field).
 
 The context is deliberately mutable: each recorded span advances
@@ -24,8 +23,8 @@ import threading
 import time
 from typing import Optional
 
-# extras key; must not collide with utils.trace's "_trace*" namespace
-# (test_trace pins that tracing-off leaves no "_trace" keys behind)
+# extras key: the one observability key a buffer carries (``t0_ns`` is
+# the frame's birth for the spans, the e2e histograms and the report)
 CTX_KEY = "_obs_ctx"
 # queue-entry wall stamp (pipeline/basic.py Queue): set on put, consumed
 # on the worker's pop to record the queue-wait span
@@ -76,8 +75,10 @@ class TraceContext:
 
 
 # chain-thread inheritance for elements that mint fresh buffers
-# (converter, mux, aggregator, decoders): the last context seen on this
-# thread re-attaches, exactly like utils.trace's birth inheritance
+# (converter, mux, aggregator, decoders): they drop the extras, but
+# their output is pushed synchronously inside the chain of the buffer
+# that caused it, so the last context seen on this thread re-attaches.
+# Sources stamp explicitly, so a root buffer never inherits.
 _tls = threading.local()
 
 

@@ -11,9 +11,9 @@ One scrape renders, in the standard ``name{labels} value`` text format:
 * every ``ServeScheduler``'s occupancy gauges and queue-delay /
   batch-latency ``Reservoir`` percentiles (live, the series ROADMAP's
   autoscaler item polls);
-* when a pipeline has a tracer attached, the full ``trace.report()``
-  flattened leaf-by-leaf — every Counters/Reservoir the tracer already
-  aggregates becomes a scrapeable series;
+* when a pipeline has tracing enabled, its full ``report()``
+  (``obs/report.py``) flattened leaf-by-leaf — every Counters/Reservoir
+  it aggregates becomes a scrapeable series;
 * each local device's memory in use, peak and limit, where the backend
   reports them;
 * flight-recorder structured-event counts by kind.
@@ -23,10 +23,13 @@ Pipelines register at ``start()`` and unregister at ``stop()``
 """
 from __future__ import annotations
 
+import random
 import re
 import threading
+import time
 import weakref
-from typing import Dict, List, Optional, Tuple
+from collections import deque
+from typing import Dict, List, Optional, Sequence, Tuple
 
 # log-ish bucket ladder (seconds) for end-to-end frame latency: sub-ms
 # local pipelines through multi-second cold paths
@@ -67,6 +70,83 @@ class Histogram:
             acc += c
             cum.append(acc)
         return cum, total, n
+
+
+# bounded per-series sample budget: 512 f64 samples = 4 KB per element,
+# enough for +/- a few percent on p99 at streaming rates
+_RESERVOIR_K = 512
+
+
+def _nearest_rank(samples, qs: Sequence[int]) -> Dict[str, float]:
+    s = sorted(samples)
+    if not s:
+        return {f"p{q}": 0.0 for q in qs}
+    top = len(s) - 1
+    return {f"p{q}": s[min(top, int(round(q / 100.0 * top)))] for q in qs}
+
+
+class Reservoir:
+    """Algorithm-R bounded reservoir: O(1) cost per observation, fixed
+    memory, uniformly representative of the whole stream — the classic
+    answer to "percentiles without keeping every sample". Seeded, so a
+    rerun of the same stream reports the same numbers."""
+
+    __slots__ = ("k", "n", "samples", "_rng")
+
+    def __init__(self, k: int = _RESERVOIR_K, seed: int = 0):
+        self.k = max(1, int(k))
+        self.n = 0
+        self.samples: list = []
+        self._rng = random.Random(seed)
+
+    def add(self, value: float) -> None:
+        self.n += 1
+        if len(self.samples) < self.k:
+            self.samples.append(value)
+        else:
+            j = self._rng.randrange(self.n)
+            if j < self.k:
+                self.samples[j] = value
+
+    def percentiles(self, qs: Sequence[int] = (50, 95, 99)) -> Dict[str, float]:
+        return _nearest_rank(self.samples, qs)
+
+
+class WindowReservoir:
+    """Time-windowed percentiles: samples older than ``window_s`` fall
+    out. An all-stream reservoir is right for post-hoc tail reporting
+    but wrong as a *control signal* — a burst's 300ms queue delays
+    would linger in it long after the backlog drained, so an autoscaler
+    reading p95 would never see recovery and never scale down. Bounded
+    at ``k`` samples (newest win) so a burst can't grow memory."""
+
+    __slots__ = ("window_s", "k", "n", "_buf")
+
+    def __init__(self, window_s: float = 2.0, k: int = _RESERVOIR_K):
+        self.window_s = max(1e-3, float(window_s))
+        self.k = max(1, int(k))
+        self.n = 0
+        self._buf: deque = deque()  # (t_mono, value), oldest first
+
+    def _prune(self, now: float) -> None:
+        horizon = now - self.window_s
+        buf = self._buf
+        while buf and (buf[0][0] < horizon or len(buf) > self.k):
+            buf.popleft()
+
+    def add(self, value: float, now: Optional[float] = None) -> None:
+        now = time.monotonic() if now is None else now
+        self.n += 1
+        self._buf.append((now, value))
+        self._prune(now)
+
+    def samples(self, now: Optional[float] = None) -> list:
+        self._prune(time.monotonic() if now is None else now)
+        return [v for _, v in self._buf]
+
+    def percentiles(self, qs: Sequence[int] = (50, 95, 99),
+                    now: Optional[float] = None) -> Dict[str, float]:
+        return _nearest_rank(self.samples(now), qs)
 
 
 class _E2E:
@@ -367,8 +447,8 @@ def render() -> str:
     # CPU backend's memory_stats() is None, so the family is absent)
     lines.extend(_device_memory_lines())
 
-    # 4) attached tracers: the full report, flattened — every
-    # Counters/Reservoir trace.py aggregates becomes a series
+    # 4) pipelines with tracing enabled: the full report, flattened —
+    # every Counters/Reservoir obs/report.py aggregates becomes a series
     emitted_trace_type = False
     for p in pipelines:
         tracer = getattr(p, "tracer", None)

@@ -36,8 +36,7 @@ import time
 from collections import deque
 from typing import List, Optional, Tuple
 
-from .context import (CTX_KEY, TraceContext, _BASE, _IDS, _tls as _ctx_tls,
-                      next_id)
+from .context import TraceContext, _BASE, _IDS, next_id
 
 # per-thread ring capacity: at ~6 spans per frame per process this
 # holds many seconds of a fast pipeline's history; tune via env
@@ -249,22 +248,28 @@ def named_program(name: str, fn):
 _observe_e2e = None    # metrics.observe_e2e, bound on first sink frame
 
 
-def chain_span(element, buf, ts_ns: int, dur_ns: int) -> None:
+def traced(element) -> bool:
+    """Whether the element's pipeline has tracing enabled
+    (``Pipeline.enable_tracing()``): with ``ENABLED``, the two things
+    that turn the stamp and the hop on."""
+    pipeline = element.pipeline
+    return pipeline is not None and pipeline.tracer is not None
+
+
+def chain_span(element, ctx: TraceContext, ts_ns: int, dur_ns: int) -> None:
     """The per-element hop: one span per buffer through ``chain()``,
-    attributed to compute. Sinks additionally settle the frame's
-    end-to-end histogram. ``ensure_ctx`` + ``record_span`` are inlined:
-    this is the single hottest call in the whole obs plane (once per
-    element per frame) and the obs-overhead gate prices every function
-    call made here."""
-    extras = buf.extras
-    ctx = extras.get(CTX_KEY)
-    if ctx is None:                  # fresh buffer: chain-thread inherit
-        ctx = getattr(_ctx_tls, "ctx", None)
-        if ctx is None:
-            return
-        extras[CTX_KEY] = ctx
-    else:
-        _ctx_tls.ctx = ctx
+    attributed to compute, and the arrival in the pipeline's report
+    (``obs/report.py``) when it has tracing enabled. Sinks additionally
+    settle the frame's end-to-end histogram. ``ctx`` is what
+    ``ensure_ctx`` gave ``chain()`` on entry. ``record_span`` is
+    inlined: this is the single hottest call in the whole obs plane
+    (once per element per frame) and the obs-overhead gate prices every
+    function call made here."""
+    pipeline = element.pipeline
+    if pipeline is not None and pipeline.tracer is not None:
+        pipeline.tracer.arrive(element.name, ctx, ts_ns)
+    if not ENABLED:                  # tracing alone: no ring, no e2e
+        return
     sid = _BASE | (next(_IDS) & 0xFFFFFF)
     try:
         ring = _tls.ring

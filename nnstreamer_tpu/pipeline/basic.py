@@ -31,6 +31,7 @@ class _NativeQueueAdapter:
     def __init__(self, capacity: int):
         from ..native.lib import NativeRing
         self._ring = NativeRing(capacity)
+        self.closed = False
 
     def put(self, item) -> None:
         self._ring.push(item, -1)
@@ -50,6 +51,7 @@ class _NativeQueueAdapter:
         return self.get(timeout=0)
 
     def close(self) -> None:
+        self.closed = True
         self._ring.close()
 
     def qsize(self) -> int:
@@ -142,6 +144,14 @@ class Queue(Element):
 
     def start(self) -> None:
         super().start()
+        if isinstance(self._q, _NativeQueueAdapter) and self._q.closed:
+            # a closed ring stays closed: a restarted element (rapid
+            # start/stop cycles) gets a live one. Here and not in
+            # stop(): a producer still in its push loop after stop()
+            # must keep meeting the closed ring (push returns 'closed');
+            # a live one, with no worker to drain it, would fill and
+            # block that producer for good.
+            self._q = self._make_q()
         self._running = True
         self._thread = threading.Thread(
             target=self._worker, name=f"queue:{self.name}", daemon=True)
@@ -169,25 +179,25 @@ class Queue(Element):
         if self._thread is not None and self._thread is not threading.current_thread():
             self._thread.join(timeout=5.0)
             self._thread = None
-        if isinstance(self._q, _NativeQueueAdapter):
-            # a closed ring stays closed: rebuild so a restarted element
-            # (rapid start/stop cycles) gets a live queue again
-            self._q = self._make_q()
 
     def chain(self, pad: Pad, item) -> None:
         if isinstance(item, Event):
             self._q.put(item)  # events are serialized: never dropped
             return
-        # the queue bypasses Element.chain (no do_chain), so the tracing
-        # hook must fire here explicitly (stats['buffers'] is counted by
-        # the worker on pop — counting here too would double it)
-        tracer = getattr(self.pipeline, "tracer", None)
-        if tracer is not None:
-            tracer.record(self, item)
-        if _obs_spans.ENABLED:
-            # entry stamp: the worker's pop turns it into the
-            # queue-wait span (+ queue attribution on the context)
-            item.extras[_obs_ctx.QT_KEY] = time.time_ns()
+        # the queue bypasses Element.chain (no do_chain), so its hop is
+        # taken here (stats['buffers'] is counted by the worker on pop —
+        # counting here too would double it)
+        traced = _obs_spans.traced(self)
+        if _obs_spans.ENABLED or traced:
+            now = time.time_ns()
+            if _obs_spans.ENABLED:
+                # entry stamp: the worker's pop turns it into the
+                # queue-wait span (+ queue attribution on the context)
+                item.extras[_obs_ctx.QT_KEY] = now
+            if traced:
+                ctx = _obs_ctx.ensure_ctx(item)
+                if ctx is not None:
+                    self.pipeline.tracer.arrive(self.name, ctx, now)
         if self.leaky == "upstream":
             # GStreamer leaky=upstream: drop the incoming buffer when full
             try:

@@ -100,7 +100,7 @@ class Element:
         self._started = False
         # atomic counter map: chain threads, the fault supervisor, and
         # network reader threads all mutate these while Pipeline.stats()
-        # and trace.report() read them from the user thread
+        # and the pipeline's report() read them from the user thread
         self.stats = Counters({"buffers": 0, "bytes": 0, "proctime_ns": 0,
                                "events": 0,
                                # fault-policy accounting (fault/policy.py):
@@ -195,10 +195,13 @@ class Element:
             self.stats.inc("events")
             self.handle_event(pad, item)
             return
-        tracer = getattr(self.pipeline, "tracer", None)
-        if tracer is not None:
-            tracer.record(self, item)
-        t_wall = time.time_ns() if _obs_spans.ENABLED else 0
+        obs = _obs_spans.ENABLED or _obs_spans.traced(self)
+        if obs:
+            # the frame's context becomes this thread's current one
+            # BEFORE do_chain: a fresh buffer minted inside, pushed on
+            # synchronously, inherits this frame's and not the last's
+            ctx = _obs_ctx.ensure_ctx(item)
+            t_wall = time.time_ns()
         t0 = time.perf_counter_ns()
         try:
             self.do_chain(pad, item)
@@ -214,9 +217,10 @@ class Element:
         dt = time.perf_counter_ns() - t0
         # one lock round-trip for the whole per-buffer bump
         self.stats.add(buffers=1, bytes=item.nbytes, proctime_ns=dt)
-        if _obs_spans.ENABLED:
-            # per-hop frame span into the per-thread ring (obs/spans.py)
-            _obs_spans.chain_span(self, item, t_wall, dt)
+        if obs and ctx is not None:
+            # the per-hop record: frame span into the per-thread ring,
+            # arrival into the pipeline's report (obs/spans.py)
+            _obs_spans.chain_span(self, ctx, t_wall, dt)
 
     def do_chain(self, pad: Pad, buf: Buffer) -> None:
         raise NotImplementedError
@@ -595,12 +599,11 @@ class SrcElement(Element):
                 sup.ok()
             if buf is None:
                 break
-            tracer = getattr(self.pipeline, "tracer", None)
-            if tracer is not None:
-                tracer.stamp(buf)
-            if _obs_spans.ENABLED and _obs_ctx.ctx_of(buf) is None:
-                # root of this frame's span tree (a source that already
-                # attached a context — serve batch adoption — keeps it)
+            if ((_obs_spans.ENABLED or _obs_spans.traced(self))
+                    and _obs_ctx.ctx_of(buf) is None):
+                # the frame's birth and the root of its span tree (a
+                # source that already attached a context — serve batch
+                # adoption — keeps it)
                 _obs_spans.record_root(self.name, _obs_ctx.stamp(buf))
             self.srcpad.push(buf)
             self._pushed += 1

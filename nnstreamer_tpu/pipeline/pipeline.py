@@ -68,9 +68,10 @@ class Pipeline:
         self._fusion_plan = None
 
     def enable_tracing(self):
-        """Attach a Tracer (≙ GstShark proctime/interlatency/framerate
-        tracers, SURVEY.md §5); returns it for report()."""
-        from ..utils.trace import Tracer
+        """Turn on the per-element report (≙ GstShark proctime /
+        interlatency / framerate tracers, SURVEY.md §5), fed by the span
+        layer's hop (obs/report.py); returns it for report()."""
+        from ..obs.report import Tracer
         self.tracer = Tracer()
         return self.tracer
 

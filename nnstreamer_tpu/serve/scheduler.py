@@ -27,9 +27,9 @@ import numpy as np
 
 from ..obs import events as _obs_events
 from ..obs import spans as _obs_spans
+from ..obs.metrics import Reservoir, WindowReservoir
 from ..utils.atomic import Counters
 from ..utils.log import logger
-from ..utils.trace import Reservoir, WindowReservoir
 from .batcher import BucketBatcher, Request, stack_requests
 
 # serve_src/serve_sink pairing by id (≙ the query elements' SERVER_TABLE)
@@ -76,7 +76,7 @@ class ServeScheduler:
         self._invoke_fn = invoke_fn
         self._thread: Optional[threading.Thread] = None
         self._stop_evt = threading.Event()
-        self.tracer = None  # optional utils.trace.Tracer (observe() sink)
+        self.tracer = None  # the pipeline's obs.report.Tracer, if enabled
         self._mlock = threading.Lock()
         # queue delay is the autoscaler's control signal: windowed, so
         # a drained backlog stops reading as pressure within seconds

@@ -20,6 +20,7 @@ import numpy as np
 
 from .info import TensorInfo, TensorsInfo
 from .meta import TensorMetaInfo
+from .transfer import PendingHost
 from .types import TensorType
 
 
@@ -37,7 +38,7 @@ class BufferFlags(enum.IntFlag):
 
 class Chunk:
     """One tensor memory: a host ndarray, a device jax.Array, or a
-    :class:`~..tensors.fetch.PendingHost` (a D2H fetch in flight, started
+    :class:`~..tensors.transfer.PendingHost` (a D2H fetch in flight, started
     by the filter's prefetch-host pool).
 
     ``meta`` is present on flexible/sparse streams (self-describing header,
@@ -52,7 +53,6 @@ class Chunk:
 
     def _settle(self) -> Any:
         """Resolve an in-flight fetch (blocking) and cache the result."""
-        from .fetch import PendingHost
         d = self._data
         if isinstance(d, PendingHost):
             d = self._data = d.resolve()
@@ -61,7 +61,6 @@ class Chunk:
     # -- residency --------------------------------------------------------
     @property
     def is_device(self) -> bool:
-        from .fetch import PendingHost
         d = self._data
         if isinstance(d, PendingHost):
             # still device-reachable until the fetch lands: chained
@@ -75,7 +74,6 @@ class Chunk:
         host fetch is in flight this is non-blocking while the device
         array is still reachable (device consumers proceed in HBM);
         otherwise it blocks for the fetched host copy."""
-        from .fetch import PendingHost
         d = self._data
         if isinstance(d, PendingHost):
             if not d.done and d.dev is not None:
@@ -95,7 +93,6 @@ class Chunk:
     def device(self, device=None, sharding=None):
         """Materialize on device (H2D transfer if host-resident)."""
         import jax
-        from .fetch import PendingHost
         d = self._data
         if isinstance(d, PendingHost):
             # prefer the still-live device array: no wait, no H2D

@@ -1,8 +1,6 @@
 """Bidirectional coalescing host<->device transfer service.
 
-Generalizes the one-way D2H fetch coalescer (this module's ancestor
-lived in ``tensors/fetch.py``, which now re-exports from here) into the
-transfer layer the async overlapped executor sits on:
+The transfer layer the async overlapped executor sits on:
 
   * **download** — the original coalescing D2H fetcher: frames enqueue
     their outputs with :func:`submit_fetch` and leave immediately
@@ -26,10 +24,10 @@ Nagle-style linger below lets stragglers join without ever delaying a
 lone frame by more than 5% of the measured call time. What either buys
 on a chip local to the process is not measured (ROADMAP Design 2).
 
-``transfer_stats()`` reports both directions; ``fetch_stats()`` keeps
-the historical download-only contract. ``trace.report()`` surfaces the
-same numbers in its ``transfer`` block together with each element's
-window occupancy and overlap ratio.
+``transfer_stats()`` reports both directions; ``fetch_stats()`` the
+download side alone. A pipeline's ``report()`` (``obs/report.py``)
+surfaces the same numbers in its ``transfer`` block together with each
+element's window occupancy and overlap ratio.
 
 The reference has no analog (host pointers are free there); this is the
 TPU-native cost model talking (SURVEY.md §7 hard part (b): device
@@ -372,7 +370,7 @@ def fetch_stats(reset: bool = False) -> dict:
 
 def transfer_stats(reset: bool = False) -> Dict[str, dict]:
     """Both directions' coalescer counters, keyed ``download`` /
-    ``upload`` — the service half of ``trace.report()``'s ``transfer``
+    ``upload`` — the service half of the pipeline report's ``transfer``
     block (the per-element half is each window's report)."""
     return {"download": _downloader.stats(reset=reset),
             "upload": _uploader.stats(reset=reset)}

@@ -2,10 +2,10 @@
 
 ``Element.stats`` (and the scheduler/batcher/breaker stat tables) are
 mutated from chain threads, supervised source loops, network reader
-threads and timer callbacks, while ``Pipeline.stats()`` and
-``trace.report()`` read them from the user thread. A plain dict makes
-every ``stats[k] += 1`` a read-modify-write race; Counters gives each
-mutation one lock round-trip and gives readers a single coherent
+threads and timer callbacks, while ``Pipeline.stats()`` and the
+pipeline's ``report()`` read them from the user thread. A plain dict
+makes every ``stats[k] += 1`` a read-modify-write race; Counters gives
+each mutation one lock round-trip and gives readers a single coherent
 ``snapshot()``.
 
 The internal ``_lock`` is a LEAF of the lock hierarchy: no Counters

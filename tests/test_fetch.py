@@ -1,4 +1,4 @@
-"""Coalescing D2H fetch service (tensors/fetch.py).
+"""Coalescing D2H fetch service (the download side of tensors/transfer.py).
 
 The service batches frame-at-a-time device->host fetches, each of which
 is a host sync with a fixed cost; these tests pin the
@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from nnstreamer_tpu.tensors.buffer import Buffer, Chunk
-from nnstreamer_tpu.tensors import fetch as F
+from nnstreamer_tpu.tensors import transfer as F
 
 
 @pytest.fixture
@@ -139,7 +139,7 @@ class TestChunkIntegration:
 
         good = F.submit_fetch([dev_arrays[0]])
         bad_ticket = F._Ticket([Boom()])
-        F._coalescer.submit(bad_ticket)
+        F._downloader.submit(bad_ticket)
         also_good = F.submit_fetch([dev_arrays[1]])
         np.testing.assert_allclose(
             F.resolve(good[0]), np.arange(12).reshape(3, 4) * 2.0)

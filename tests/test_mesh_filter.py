@@ -334,11 +334,15 @@ def test_sharded_dispatch_occupies_one_window_slot():
 def test_mesh_filter_window_reports_mesh_devices():
     """A windowed mesh filter's transfer_report carries the mesh span,
     and the dispatch/complete split stays correct: every frame settles
-    through the window with byte parity intact."""
+    through the window with the bytes the same sharded program gives
+    when invoked synchronously."""
     x = np.random.RandomState(11).randn(8, 64).astype(np.float32)
     ref = _open_model("zoo://mlp?dtype=float32")
-    want = np.asarray(ref.invoke([x])[0])
+    single = np.asarray(ref.invoke([x])[0])
     ref.close()
+    fw = _open_model("zoo://mlp?dtype=float32", "mesh:8x1x1")
+    want = np.asarray(fw.invoke([x])[0])
+    fw.close()
     p = parse_launch(
         f'appsrc name=in caps="{CAPS8x64}" '
         '! tensor_filter name=f framework=jax '
@@ -356,4 +360,17 @@ def test_mesh_filter_window_reports_mesh_devices():
     assert rep["window"] == 2
     assert rep["completed"] == 4
     assert len(got) == 4
+    # the window changes when a frame is dispatched and completed, not
+    # its program or its sharding: byte-equal to the synchronous invoke
     assert all(g[0][2] == want.tobytes() for g in got)
+    # Across shardings XLA promises the same mathematics, not the same
+    # bytes: at 8 rows over 8 devices each device multiplies a [1, 64]
+    # row where the single chip multiplies [8, 64], and the CPU backend
+    # sums the products of the two shapes in different orders (read:
+    # 7.2e-7 on outputs up to 2.3 = 3 float32 ulps; at 8 rows a device,
+    # test_batch64_sharded_invoke_byte_identical, the bytes agree).
+    # 1e-5 of the largest output is 84 float32 ulps, room for a sum of
+    # 128 products, and 800 times tighter than one bfloat16 ulp: a
+    # lower precision, a dropped or a shifted row still fails.
+    # tools/shard_parity.py holds its mesh pipelines to the same rule.
+    assert np.abs(want - single).max() <= 1e-5 * np.abs(single).max()

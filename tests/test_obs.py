@@ -233,6 +233,33 @@ class TestPipelineSpans:
             assert {"agg", "out"} <= {s[0] for s in spans}
 
 
+    def test_utils_imports_nothing_above_itself(self):
+        """The import graph's floor: ``utils/`` is what every package
+        may import, so it imports none of them (the per-pipeline report
+        that condensed the wire, session, fusion and transfer counters
+        lives in ``obs/report.py``, not in ``utils/``)."""
+        import ast
+        root = Path(obs_spans.__file__).resolve().parents[1]
+        above = {"edge", "tensors", "serve", "fusion", "filters", "obs"}
+        found = []
+        for path in sorted((root / "utils").glob("*.py")):
+            for node in ast.walk(ast.parse(path.read_text())):
+                if isinstance(node, ast.ImportFrom):
+                    parts = (node.module or "").split(".")
+                    if node.level == 0 and parts[0] == "nnstreamer_tpu":
+                        parts = parts[1:]
+                    elif node.level != 2:
+                        continue     # level 1: a sibling inside utils/
+                    names = parts[:1] or [a.name for a in node.names]
+                elif isinstance(node, ast.Import):
+                    names = [a.name.split(".")[1] for a in node.names
+                             if a.name.startswith("nnstreamer_tpu.")]
+                else:
+                    continue
+                found += [(path.name, n) for n in names if n in above]
+        assert found == []
+
+
 # ------------------------------------------------------ flight recorder
 
 class TestFlightRecorder:

@@ -382,7 +382,7 @@ class TestBrokerFleet:
             broker.stop()
 
     def test_broker_stats_surface_in_trace_report(self):
-        from nnstreamer_tpu.utils.trace import Tracer
+        from nnstreamer_tpu.obs.report import Tracer
         broker = DiscoveryBroker(port=0)
         broker.start()
         try:

@@ -20,7 +20,8 @@ from nnstreamer_tpu.analysis.flow import check_identities
 from nnstreamer_tpu.filters import register_custom_easy
 from nnstreamer_tpu.serve import BucketBatcher, Request, ServeScheduler, \
     stack_requests
-from nnstreamer_tpu.utils.trace import Reservoir, Tracer
+from nnstreamer_tpu.obs.metrics import Reservoir
+from nnstreamer_tpu.obs.report import Tracer
 from nnstreamer_tpu.utils.watchdog import Watchdog
 
 
@@ -685,7 +686,7 @@ class TestPercentiles:
         # deterministic clock via explicit `now`: burst-era samples must
         # fall out of the window, or an autoscaler reading p95 as its
         # control signal would never see recovery (and never scale down)
-        from nnstreamer_tpu.utils.trace import WindowReservoir
+        from nnstreamer_tpu.obs.metrics import WindowReservoir
         r = WindowReservoir(window_s=2.0)
         for i in range(50):
             r.add(300_000.0, now=10.0 + i * 0.01)  # 300ms burst delays
@@ -697,7 +698,7 @@ class TestPercentiles:
         assert r.n == 70  # lifetime count survives the pruning
 
     def test_window_reservoir_bounded_and_empty_window(self):
-        from nnstreamer_tpu.utils.trace import WindowReservoir
+        from nnstreamer_tpu.obs.metrics import WindowReservoir
         r = WindowReservoir(window_s=60.0, k=16)
         for i in range(1000):
             r.add(float(i), now=100.0 + i * 1e-4)

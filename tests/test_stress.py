@@ -389,7 +389,7 @@ def test_render_time_adaptive_qos_bounded_under_slow_fetch(monkeypatch):
     import jax
 
     from nnstreamer_tpu.pipeline.parser import parse_launch
-    from nnstreamer_tpu.tensors.fetch import fetch_stats
+    from nnstreamer_tpu.tensors.transfer import fetch_stats
 
     real_get = jax.device_get
 

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """obs-overhead gate: frame tracing must cost < 3% fps.
 
-Runs the devres-shaped bench row (device-resident tensortestsrc pool ->
+Runs a devres-shaped pipeline (device-resident tensortestsrc pool ->
 jax filter -> delivery queue -> appsink) twice in SUBPROCESSES — once
 with the observability plane enabled (NNS_TPU_OBS=1, the default) and
 once hard-disabled (NNS_TPU_OBS=0, the control arm) — and fails when
@@ -15,11 +15,11 @@ rep (the gate compares ceilings — a GC pause in one rep must not fail
 the build; the systematic cost we are bounding survives best-of, noise
 does not).
 
-The model is a zoo MLP sized so one buffer costs what the real devres
-row's per-buffer dispatch costs (~1-2 ms on the CPU mesh) — the real
-row (mobilenet_v2 @ batch 32) is minutes per child on CPU, far too
-slow for `make check`, and a sub-100us toy model prices nothing but
-the GIL. Same shape, CI-sized cadence.
+The model is a zoo MLP sized so one buffer costs what a vision model's
+per-buffer dispatch costs (~1-2 ms on the CPU mesh) — mobilenet_v2 at
+batch 32 is minutes per child on CPU, far too slow for `make check`,
+and a sub-100us toy model prices nothing but the GIL. Same shape,
+CI-sized cadence.
 
 Exit 0 = within budget; 1 = overhead above budget; 2 = harness failure.
 """
@@ -39,8 +39,8 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 BUDGET_PCT = 3.0
 CAPS = ('"other/tensors,format=static,num_tensors=1,'
         'types=(string)float32,dimensions=(string)1024"')
-# ~8.4M MACs/frame: ~1-2 ms on one CPU host thread, the per-buffer
-# cadence of the real devres row (see module docstring)
+# ~8.4M MACs/frame: ~1-2 ms on one CPU host thread (see module
+# docstring)
 MODEL = '"zoo://mlp?in_dim=1024&hidden=4096&out_dim=256&dtype=float32"'
 
 

@@ -1,13 +1,13 @@
 #!/usr/bin/env python
-"""Sharded-serving byte-parity gate: mesh output must equal single-chip.
+"""Sharded-serving parity gate: mesh output must equal single-chip.
 
 Runs every runnable pipeline in the repo's corpus (tests/*.py string
 literals + README.md code blocks, extracted by tools/lint_corpus.py)
 that declares a ``mesh:DxSxT`` tensor_filter twice — once as authored
 (the batch laid out batch-major across the mesh) and once with the mesh
 spec stripped from every filter (the single-chip path) — and compares
-every sink's output byte-for-byte (dtype, shape, raw bytes, per buffer,
-per chunk). A built-in representative suite (batch-major zoo invoke,
+every sink's output per buffer, per chunk: dtype, shape and raw bytes,
+floats that differ held to ``SHARD_RTOL`` (below). A built-in representative suite (batch-major zoo invoke,
 elementwise chain, fused mesh segment) always runs, so the gate tests
 something even if the extracted corpus yields no mesh pipelines.
 
@@ -16,8 +16,8 @@ fusion decisions are float-order-sensitive for matmul chains, so fused
 matmul parity is only approximate even without a mesh. The explicit
 fused-mesh case in the built-in suite uses the elementwise
 toyseg!toyscale oracle chain, which is bit-exact across XLA fusion AND
-mesh partitioning. Exit status is nonzero iff any mesh pipeline
-produced bytes differing from its single-chip twin — or if nothing was
+mesh partitioning. Exit status is nonzero iff any mesh pipeline's
+output did not match its single-chip twin's — or if nothing was
 compared at all (a vacuous gate is a failing gate).
 """
 from __future__ import annotations
@@ -37,6 +37,8 @@ import argparse  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
 from typing import List, Optional, Tuple  # noqa: E402
+
+import numpy as np  # noqa: E402
 
 ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
@@ -71,6 +73,39 @@ BUILTIN = [
      "tensor_filter framework=jax model=zoo://toyscale "
      "custom=mesh:8x1x1 ! appsink name=out", True),
 ]
+
+
+# Across shardings XLA promises the same mathematics, not the same
+# bytes: a device that holds one row of the batch multiplies a [1, K]
+# row where the single chip multiplies [B, K], and the CPU backend sums
+# the K products of the two shapes in different orders (read: 3 float32
+# ulps on zoo://mlp?dtype=float32 at one row a device; byte-equal at
+# eight rows a device, and always for integer and elementwise outputs).
+# So float chunks may differ by this share of the single chip's largest
+# value: 84 float32 ulps, room for a sum of 128 products, and 800 times
+# tighter than one bfloat16 ulp, so a lower precision, a dropped or a
+# shifted row still fails. tests/test_mesh_filter.py
+# (test_mesh_filter_window_reports_mesh_devices) holds the same rule.
+SHARD_RTOL = 1e-5
+
+
+def _chunks_match(mesh_chunk: Tuple, chip_chunk: Tuple) -> bool:
+    """Two ``(dtype, shape, bytes)`` records of ``_capture_sinks``."""
+    if mesh_chunk == chip_chunk:
+        return True
+    dtype, shape, raw = chip_chunk
+    if mesh_chunk[:2] != (dtype, shape) or np.dtype(dtype).kind != "f":
+        return False
+    got, want = np.frombuffer(mesh_chunk[2], dtype), np.frombuffer(raw, dtype)
+    return bool(np.abs(got - want).max() <= SHARD_RTOL * np.abs(want).max())
+
+
+def _sinks_match(mesh_bufs: Optional[List[Tuple]],
+                 chip_bufs: List[Tuple]) -> bool:
+    return (mesh_bufs is not None and len(mesh_bufs) == len(chip_bufs)
+            and all(len(m) == len(c)
+                    and all(map(_chunks_match, m, c))
+                    for m, c in zip(mesh_bufs, chip_bufs)))
 
 
 def _mesh_filters(pipe) -> List:
@@ -160,15 +195,15 @@ def check_shard_parity(where: str, desc: str, fuse: bool = False,
     if fuse and not fused:
         return "FAIL", "fused-mesh case did not fuse in the live run"
     for sink in chip_out:
-        if mesh_out.get(sink) != chip_out[sink]:
+        if not _sinks_match(mesh_out.get(sink), chip_out[sink]):
             na, nb = len(mesh_out.get(sink, [])), len(chip_out[sink])
-            return "FAIL", (f"sink {sink!r}: sharded bytes differ from "
+            return "FAIL", (f"sink {sink!r}: sharded output differs from "
                             f"the single-chip path ({na} vs {nb} buffers)")
     nbuf = sum(len(v) for v in chip_out.values())
     return "mesh-ok", (f"{need} devices"
                        + (f", {len(fused)} fused segment(s)" if fused
                           else "")
-                       + f", {nbuf} buffers identical")
+                       + f", {nbuf} buffers match")
 
 
 def main(argv=None) -> int:
@@ -198,7 +233,7 @@ def main(argv=None) -> int:
             failures.append(f"{where}: {detail}\n    {desc}")
         if opts.verbose or status == "FAIL":
             print(f"[{status}] {where}: {detail}")
-    print(f"shard-parity: {counts['mesh-ok']} pipelines byte-identical "
+    print(f"shard-parity: {counts['mesh-ok']} pipelines match "
           f"sharded vs single-chip, {counts['no-mesh']} had no mesh, "
           f"{counts['skipped']} skipped, {counts['FAIL']} failures")
     if counts["mesh-ok"] == 0:
