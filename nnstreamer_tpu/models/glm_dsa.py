@@ -58,11 +58,14 @@ from .zoo import register_model
 LAYER_NORM_EPS = 1e-6     # the indexer's key norm
 # how the work is cut, not what is computed
 BLOCK_Q = 512             # queries an attention block
-# rows a turn of the grouped expert product: what one expert is routed of
-# a few thousand tokens fits one tile, so the turns follow the experts
-# served and hardly the routing, and a tile's products still hide under
-# the read of its weights
-EXPERT_TILE = 512
+# rows a turn of the grouped expert product (ops/grouped.py): the chip's
+# ridge, 256 rows x 2 FLOP over a weight's 2 bytes, so a tile's products
+# about hide under the read of its expert's weights. What one expert is
+# routed of a few thousand tokens is one tile or two, the last of them
+# taken at 128 rows where 128 hold it (read on the chip, PERF.md PR 31:
+# 512 rows multiply three rows of padding to each live one, 128 make a
+# third more turns)
+EXPERT_TILE = 256
 # the columns a head's rotation is cut out at: a multiple of the lane
 # width, so that cutting them out and putting them back shifts no lane
 ROPE_ALIGN = 128

@@ -7,13 +7,15 @@ choice of ``K`` experts each, of which any number may be held here
 serves is data, not a shape, so the dense forms either drop pairs over
 a capacity or multiply every token with every expert. This one does
 neither: the pairs are sorted by expert (:func:`group_by_expert`), each
-expert's run is cut into tiles of ``tile`` rows, and a device loop
-whose trip count is the number of tiles actually occupied runs one
-SwiGLU expert on one tile a turn (:func:`grouped_swiglu`). Exact, no
-capacity, no dropped pair; the cost follows the pairs served, padded to
-a tile an expert. (``jax.lax.ragged_dot`` states the same product, but
-needs a row buffer sized for the worst routing, ``T x K`` rows, where
-this needs one tile.)
+expert's run is cut into tiles of ``tile`` rows, and device loops whose
+trip counts are the tiles actually occupied run one SwiGLU expert on
+one tile a turn (:func:`grouped_swiglu`): one loop for the whole tiles
+and the last ones more than half full, one that takes an expert's last
+tile at half the rows where half hold it. Exact, no capacity, no
+dropped pair; the cost follows the pairs served, padded to half a tile
+an expert. (``jax.lax.ragged_dot`` states the same product, but needs a
+row buffer sized for the worst routing, ``T x K`` rows, where this
+needs one tile.)
 """
 from __future__ import annotations
 
@@ -42,24 +44,39 @@ def grouped_swiglu(x, order, counts, pair_weight, w1, w3, w2, *, tile: int):
     t, d = x.shape
     k = order.shape[0] // t
     weight = pair_weight.reshape(-1)
-    tiles = (counts + tile - 1) // tile          # tiles of each expert
-    tile_end = jnp.cumsum(tiles)
     row0 = jnp.cumsum(counts) - counts           # an expert's first row
+    half = tile // 2
+    rest = counts % tile
+    short = (rest > 0) & (rest <= half)          # last tile at most half full
 
-    def one_tile(i, acc):
-        g = jnp.sum(tile_end <= i, dtype=jnp.int32)      # this tile's expert
-        rows = (i - (tile_end[g] - tiles[g])) * tile + jnp.arange(tile)
-        live = rows < counts[g]
-        pair = order[jnp.where(live, row0[g] + rows, 0)]
-        tok = pair // k
-        xt = x[tok]
-        up = jnp.dot(xt, w3[g], preferred_element_type=jnp.float32)
-        gate = jnp.dot(xt, w1[g], preferred_element_type=jnp.float32)
-        y = jnp.dot((jax.nn.silu(gate) * up).astype(x.dtype), w2[g],
-                    preferred_element_type=jnp.float32)
-        y = y * jnp.where(live, weight[pair], 0.0)[:, None]
-        # a dead row's index lies past the end and is dropped
-        return acc.at[jnp.where(live, tok, t)].add(y, mode="drop")
+    def turns(acc, tiles, rows: int, first):
+        """``acc`` plus ``tiles[g]`` tiles of ``rows`` rows of each expert
+        ``g`` from its row ``first[g]`` on, a tile a turn of a device
+        loop: a row gather, three products on ``w3[g]``, ``w1[g]``,
+        ``w2[g]`` with ``g`` the loop's own scalar, a scatter-add."""
+        tile_end = jnp.cumsum(tiles)
 
-    return jax.lax.fori_loop(0, tile_end[-1], one_tile,
-                             jnp.zeros((t, d), jnp.float32))
+        def one_tile(i, acc):
+            g = jnp.sum(tile_end <= i, dtype=jnp.int32)  # this tile's expert
+            row = first[g] + (i - (tile_end[g] - tiles[g])) * rows \
+                + jnp.arange(rows)
+            live = row < counts[g]
+            pair = order[jnp.where(live, row0[g] + row, 0)]
+            tok = pair // k
+            xt = x[tok]
+            up = jnp.dot(xt, w3[g], preferred_element_type=jnp.float32)
+            gate = jnp.dot(xt, w1[g], preferred_element_type=jnp.float32)
+            y = jnp.dot((jax.nn.silu(gate) * up).astype(x.dtype), w2[g],
+                        preferred_element_type=jnp.float32)
+            y = y * jnp.where(live, weight[pair], 0.0)[:, None]
+            # a dead row's index lies past the end and is dropped
+            return acc.at[jnp.where(live, tok, t)].add(y, mode="drop")
+
+        return jax.lax.fori_loop(0, tile_end[-1], one_tile, acc)
+
+    acc = turns(jnp.zeros((t, d), jnp.float32),
+                (counts + tile - 1) // tile - short, tile,
+                jnp.zeros_like(counts))
+    if half:
+        acc = turns(acc, short.astype(jnp.int32), half, counts - rest)
+    return acc

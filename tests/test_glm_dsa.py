@@ -272,6 +272,93 @@ def test_grouped_swiglu_is_exact(tile, held_first, held):
                                for e in range(held_first, held_first + held)]
 
 
+def _choice(case, rng, t, k, tile, held_first, held, router):
+    """``[t, k]`` expert ids, a token's all different, for a held share
+    ``[held_first, held_first + held)`` of ``router`` experts."""
+    inside = np.arange(held_first, held_first + held)
+    outside = np.setdiff1d(np.arange(router), inside)
+    if case == "none_held":
+        return np.stack([rng.permutation(outside)[:k] for _ in range(t)])
+    if case == "one_expert":                   # k == 1: every pair on it
+        return np.full((t, k), held_first + 1)
+    if case == "half_full":
+        # k == 1; last tiles half full to the row, one row over, whole,
+        # and a single row: both sides of where the two loops part
+        half = tile // 2
+        pairs = [2 * tile + half, tile + half + 1, tile, 1]
+        ids = np.repeat(inside, pairs)
+        ids = np.concatenate([ids, np.full(t - len(ids), outside[0])])
+        return rng.permutation(ids)[:, None]
+    choice = np.stack([rng.permutation(router)[:k] for _ in range(t)])
+    if case == "several_tiles":
+        # the first held expert serves most tokens: several tiles of it
+        rows = rng.permutation(t)[:t * 5 // 6]
+        choice[rows] = np.where(choice[rows] == held_first,
+                                outside[0], choice[rows])
+        choice[rows, 0] = held_first
+    if case == "gap":
+        # the second held expert serves nobody, its neighbours do
+        choice = np.where(choice == held_first + 1, outside[0], choice)
+    return choice
+
+
+@pytest.mark.parametrize("case,t,k,tile,dtype,tol", [
+    ("several_tiles", 48, 2, 8, jnp.float32, 1e-5),
+    ("gap", 48, 2, 8, jnp.float32, 1e-5),
+    ("one_expert", 40, 1, 16, jnp.float32, 1e-5),
+    ("none_held", 24, 2, 8, jnp.float32, 0.0),
+    ("half_full", 64, 1, 8, jnp.float32, 1e-5),
+    # an odd tile's half is rounded down: 5 rows and 2
+    ("odd_tile", 48, 2, 5, jnp.float32, 1e-5),
+    # silu(gate) * up reaches the last product in bfloat16
+    ("bfloat16", 64, 2, 16, jnp.bfloat16, 2e-2),
+    ("bfloat16_half_full", 96, 1, 16, jnp.bfloat16, 2e-2),
+])
+def test_grouped_swiglu_serves_every_routing(case, t, k, tile, dtype, tol):
+    """Both loops of :func:`grouped_swiglu` (whole tiles, and last tiles
+    at half the rows) against each pair computed alone: an expert of
+    several tiles, one that serves nobody between two that do, every
+    pair on one expert, no pair of a held expert (exactly 0), last
+    tiles on both sides of half full, an odd tile, bfloat16."""
+    held_first, held, router, d, f = 2, 4, 9, 16, 24
+    rng = np.random.default_rng(len(case))
+    case = case.removeprefix("bfloat16_")
+    choice = _choice(case, rng, t, k, tile, held_first, held, router)
+    x = jnp.asarray(rng.standard_normal((t, d)), dtype)
+    weight = jnp.asarray(rng.random((t, k)), jnp.float32)
+    w1, w3 = (jnp.asarray(rng.standard_normal((held, d, f)) * d ** -0.5,
+                          dtype) for _ in range(2))
+    w2 = jnp.asarray(rng.standard_normal((held, f, d)) * f ** -0.5, dtype)
+    order, counts = group_by_expert(jnp.asarray(choice, jnp.int32),
+                                    held_first, held)
+    expected = {"several_tiles": counts[0] > 2 * tile,
+                "gap": (counts[1] == 0) & (counts[0] > 0) & (counts[2] > 0),
+                "one_expert": counts[1] == t * k,
+                "none_held": counts.sum() == 0,
+                "half_full": (counts % tile == jnp.asarray(
+                    [tile // 2, tile // 2 + 1, 0, 1])).all()}.get(case, True)
+    assert bool(expected), counts
+    got = jax.jit(lambda *a: grouped_swiglu(*a, tile=tile))(
+        x, order, counts, weight, w1, w3, w2)
+    assert got.shape == (t, d) and got.dtype == jnp.float32
+    want = np.zeros((t, d), np.float32)
+    for tok, slot in np.argwhere((choice >= held_first)
+                                 & (choice < held_first + held)):
+        e = choice[tok, slot] - held_first
+        gate, up = (jnp.dot(x[tok], w[e], preferred_element_type=jnp.float32)
+                    for w in (w1, w3))
+        want[tok] += weight[tok, slot] * np.asarray(jnp.dot(
+            (jax.nn.silu(gate) * up).astype(dtype), w2[e],
+            preferred_element_type=jnp.float32))
+    got = np.asarray(got)
+    if case == "none_held":
+        assert not got.any()
+    else:
+        assert np.abs(want).max() > 0.1
+    np.testing.assert_allclose(got, want, atol=tol * max(np.abs(want).max(),
+                                                         1.0))
+
+
 @pytest.mark.parametrize("block_q", [8, 32])
 def test_blocked_attention_honours_a_key_mask(block_q):
     rng = np.random.default_rng(1)
