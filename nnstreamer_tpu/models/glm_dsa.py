@@ -28,7 +28,9 @@ absorbed form belongs to a decode path and is not built here):
   of other chips would add is not stood in for.
 
 The first ``first_k_dense_replace`` layers have a dense SwiGLU MLP in the
-expert layer's place. Scope names: ``embed``, ``block/attn``,
+expert layer's place. The latent projections, the rotations, the SwiGLU
+and the attention half are ``models/latent.py``'s, which
+``models/longcat.py`` calls too. Scope names: ``embed``, ``block/attn``,
 ``block/indexer`` (``block/indexer/select`` inside), ``block/moe/route``,
 ``block/moe/shared``, ``block/moe/experts``, ``block/mlp``, ``lm_head``.
 
@@ -47,28 +49,16 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..ops.grouped import group_by_expert, grouped_swiglu
-from ..ops.sparse_attention import blocked_causal_attention, topk_mask
-from ..tensors.info import TensorsInfo
+from ..ops.sparse_attention import topk_mask
+from . import latent
+from .latent import (BLOCK_Q, EXPERT_TILE, _mm, causal_attention_out,
+                     mla_qkv, rope_interleaved, swiglu)
 from .transformer import rmsnorm
 from .zoo import register_model
 
 LAYER_NORM_EPS = 1e-6     # the indexer's key norm
-# how the work is cut, not what is computed
-BLOCK_Q = 512             # queries an attention block
-# rows a turn of the grouped expert product (ops/grouped.py): the chip's
-# ridge, 256 rows x 2 FLOP over a weight's 2 bytes, so a tile's products
-# about hide under the read of its expert's weights. What one expert is
-# routed of a few thousand tokens is one tile or two, the last of them
-# taken at 128 rows where 128 hold it (read on the chip, PERF.md PR 31:
-# 512 rows multiply three rows of padding to each live one, 128 make a
-# third more turns)
-EXPERT_TILE = 256
-# the columns a head's rotation is cut out at: a multiple of the lane
-# width, so that cutting them out and putting them back shifts no lane
-ROPE_ALIGN = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -187,93 +177,6 @@ def _init_params(cfg: GLMDSAConfig, key):
             "layers": layers}
 
 
-def rope_interleaved(x, positions, theta: float):
-    """Rotary embedding over the last dim, pairs ``(2i, 2i+1)`` rotated
-    together. ``x`` [S, ..., D], ``positions`` [S]."""
-    d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = positions.astype(jnp.float32)[:, None] * freqs       # [S, D/2]
-    ang = ang.reshape(ang.shape[:1] + (1,) * (x.ndim - 2) + ang.shape[1:])
-    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
-    a, b = pairs[..., 0], pairs[..., 1]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
-    return out.reshape(x.shape).astype(x.dtype)
-
-
-def rope_columns(x, positions, theta: float, nope: int):
-    """``x`` [..., S, D] with the columns from ``nope`` on rotated as
-    :func:`rope_interleaved` rotates them and the others as they are:
-    what ``concatenate([x[..., :nope], rope(x[..., nope:])])`` gives,
-    to the bit. A pair's partner comes from a product with a constant
-    0 / +-1 matrix (one term a sum: exact) and not from a shuffle of
-    lanes, and only the columns from the last multiple of
-    ``ROPE_ALIGN`` at or before ``nope`` are read and written back."""
-    d = x.shape[-1]
-    cut = nope // ROPE_ALIGN * ROPE_ALIGN
-    rp, off = d - nope, nope - cut
-    freqs = theta ** (-jnp.arange(0, rp, 2, dtype=jnp.float32) / rp)
-    ang = jnp.repeat(positions.astype(jnp.float32)[:, None] * freqs, 2, -1)
-    cos = jnp.pad(jnp.cos(ang), ((0, 0), (off, 0)), constant_values=1.0)
-    sin = jnp.pad(jnp.sin(ang), ((0, 0), (off, 0)))
-    # (a, b) -> (a cos - b sin, a sin + b cos): x cos + (x @ swap) sin
-    swap = np.zeros((d - cut, d - cut), np.float32)
-    for c in range(off, d - cut, 2):
-        swap[c + 1, c], swap[c, c + 1] = -1.0, 1.0
-    tail = x[..., cut:]
-    partner = jnp.einsum("...d,de->...e", tail, jnp.asarray(swap, x.dtype),
-                         precision=jax.lax.Precision.HIGHEST,
-                         preferred_element_type=jnp.float32)
-    tail = tail.astype(jnp.float32) * cos + partner * sin
-    return x.at[..., cut:].set(tail.astype(x.dtype))
-
-
-def _mm(x, w):
-    """Product accumulated in float32, handed on in the stream's dtype."""
-    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
-
-
-def swiglu(x, p):
-    """``(silu(x w1) * (x w3)) w2`` -> float32."""
-    gate = jnp.dot(x, p["w1"], preferred_element_type=jnp.float32)
-    up = jnp.dot(x, p["w3"], preferred_element_type=jnp.float32)
-    return jnp.dot((jax.nn.silu(gate) * up).astype(x.dtype), p["w2"],
-                   preferred_element_type=jnp.float32)
-
-
-def _mm_heads(x, w):
-    """``x`` [S, r] by ``w`` [r, H, d] -> [H, S, d], accumulated in
-    float32: head-major as the product writes it, which is how the
-    attention kernel reads a head (``ops/sparse_attention.py``)."""
-    return jnp.einsum("sr,rhd->hsd", x, w,
-                      preferred_element_type=jnp.float32).astype(x.dtype)
-
-
-def mla_qkv(x, a, positions, cfg: GLMDSAConfig):
-    """The latent projections of normed ``x`` [S, d]: ``c_q`` [S, r_q]
-    and per-head ``q`` [S, H, nope+rope], ``k`` [S, H, nope+rope] (the
-    one roped key part repeated to every head), ``v`` [S, H, v].
-
-    All three are views of head-major arrays, which no transpose or
-    concatenation makes: ``k`` and ``v`` are two products; the key
-    columns of ``wkv_b`` take ``rope`` columns of zeros a head, and the
-    roped part is added into the gap they leave (one operand of each
-    sum is zero: exact)."""
-    h, nope = cfg.num_attention_heads, cfg.qk_nope_head_dim
-    r, rp = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-    c_q = rmsnorm(_mm(x, a["wq_a"]), a["q_norm"], cfg.rms_norm_eps)
-    q = rope_columns(_mm_heads(c_q, a["wq_b"].reshape(-1, h, nope + rp)),
-                     positions, cfg.rope_theta, nope)
-    kv = _mm(x, a["wkv_a"])
-    c_kv = rmsnorm(kv[:, :r], a["kv_norm"], cfg.rms_norm_eps)
-    k_r = rope_interleaved(kv[:, r:], positions, cfg.rope_theta)
-    w = a["wkv_b"].reshape(r, h, -1)
-    k = _mm_heads(c_kv, jnp.pad(w[..., :nope], ((0, 0), (0, 0), (0, rp)))) \
-        + jnp.pad(k_r, ((0, 0), (nope, 0)))
-    v = _mm_heads(c_kv, w[..., nope:])
-    return (c_q,) + tuple(jnp.transpose(t, (1, 0, 2)) for t in (q, k, v))
-
-
 def indexer_qkw(x, c_q, ix, positions, cfg: GLMDSAConfig):
     """The indexer's projections: ``q_I`` [S, heads, dim], ``k_I`` [S,
     dim] (LayerNorm with bias), ``w`` float32 [S, heads], scaled."""
@@ -322,15 +225,9 @@ def attend(h, layer, cfg: GLMDSAConfig):
                 causal = jnp.arange(lo, hi)[:, None] >= jnp.arange(hi)[None]
                 return topk_mask(scores, cfg.index_topk, causal)
 
-    o = blocked_causal_attention(
-        q, k, v, scale=q.shape[-1] ** -0.5, block_q=BLOCK_Q,
-        key_mask=selected, scope="block/attn")
-    with jax.named_scope("block/attn"):
-        # over (head, v) as the attention wrote them: no [S, H * v] copy
-        wo = layer["attn"]["wo"].reshape(o.shape[1], o.shape[2], -1)
-        return h + jnp.einsum("shv,hvd->sd", o, wo,
-                              preferred_element_type=jnp.float32
-                              ).astype(h.dtype)
+    return h + causal_attention_out(
+        q, k, v, layer["attn"]["wo"], block_q=BLOCK_Q, key_mask=selected,
+        scope="block/attn")
 
 
 def route(x, moe, cfg: GLMDSAConfig):
@@ -384,30 +281,16 @@ def forward(params, tokens, cfg: GLMDSAConfig):
                 x = rmsnorm(flat, layer["ffn_norm"], cfg.rms_norm_eps)
                 flat = flat + swiglu(x, layer["mlp"]).astype(flat.dtype)
         h = flat.reshape(b, s, -1)
-    with jax.named_scope("lm_head"):
-        x = rmsnorm(h, params["norm_f"], cfg.rms_norm_eps)
-        logits = jnp.dot(x, params["head"],
-                         preferred_element_type=jnp.float32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nxt = jnp.take_along_axis(logp[:, :-1], tokens[:, 1:, None], axis=-1)
-        logprobs = jnp.pad(nxt[..., 0], ((0, 0), (0, 1)))
-    return logits[:, -1], logprobs, jnp.stack(loads)
+    return latent.score(h, params, tokens, cfg.rms_norm_eps) \
+        + (jnp.stack(loads),)
 
 
 def frame_model(cfg: GLMDSAConfig, seq: int):
     """``(apply_fn, in_info, out_info)`` for ``tensor_filter
     framework=jax``: one int32 ``[seq]`` token frame a buffer in, the
     three tensors of :func:`forward` out."""
-
-    def apply_fn(p, tokens):
-        last, logprobs, load = forward(p, tokens[None].astype(jnp.int32), cfg)
-        return last[0], logprobs[0], load
-
-    in_info = TensorsInfo.make("int32", str(seq))
-    out_info = TensorsInfo.make(
-        "float32,float32,int32",
-        f"{cfg.vocab_size},{seq},{cfg.held}:{cfg.n_moe_layers}")
-    return apply_fn, in_info, out_info
+    return latent.frame_model(forward, cfg, seq,
+                              f"{cfg.held}:{cfg.n_moe_layers}")
 
 
 @register_model("glm_dsa")
@@ -416,13 +299,7 @@ def _build_glm_dsa(seq: str = "64", seed: str = "0", dtype: str = "bfloat16",
     """``zoo://glm_dsa?seq=64&hidden_size=64&held_count=8&...``: any
     field of :class:`GLMDSAConfig` by its name; the defaults are a tiny
     model whose sparse regime is live at ``seq`` 64."""
-    kinds = {f.name: f.type for f in dataclasses.fields(GLMDSAConfig)}
-    unknown = sorted(set(sizes) - set(kinds))
-    if unknown:
-        raise ValueError(f"zoo://glm_dsa: unknown option(s) {unknown}")
-    cfg = GLMDSAConfig(dtype=jnp.dtype(dtype), **{
-        k: float(v) if kinds[k] == "float" else int(v)
-        for k, v in sizes.items()})
+    cfg = latent.config_from_options(GLMDSAConfig, "glm_dsa", dtype, sizes)
     params = init_params(cfg, jax.random.PRNGKey(int(seed)))
     apply_fn, in_info, out_info = frame_model(cfg, int(seq))
     return apply_fn, params, in_info, out_info

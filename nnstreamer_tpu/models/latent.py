@@ -1,0 +1,204 @@
+"""Parts the latent-attention decoders share (``models/glm_dsa.py``,
+``models/longcat.py``): rotary embedding on interleaved pairs, the
+latent (MLA) projections in the expanded form, causal attention over
+them with the output projection, a SwiGLU MLP, the scoring head, and
+the frame and zoo wrappers of a scoring pass. A decoder's own file
+holds what is its own: the layer's order, its router, an indexer.
+
+The configuration object a function takes is the caller's dataclass;
+only the fields named in the function's docstring are read, by the HF
+``config.json`` keys both architectures publish them under.
+"""
+from __future__ import annotations
+
+import dataclasses
+from typing import Callable, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..ops.sparse_attention import blocked_causal_attention
+from ..tensors.info import TensorsInfo
+from .transformer import rmsnorm
+
+# how the work is cut, not what is computed
+BLOCK_Q = 512             # queries an attention block
+# rows a turn of the grouped expert product (ops/grouped.py): the chip's
+# ridge, 256 rows x 2 FLOP over a weight's 2 bytes, so a tile's products
+# about hide under the read of its expert's weights. What one expert is
+# routed of a few thousand tokens is one tile or two, the last of them
+# taken at 128 rows where 128 hold it (read on the chip, PERF.md PR 31:
+# 512 rows multiply three rows of padding to each live one, 128 make a
+# third more turns)
+EXPERT_TILE = 256
+# the columns a head's rotation is cut out at: a multiple of the lane
+# width, so that cutting them out and putting them back shifts no lane
+ROPE_ALIGN = 128
+
+
+def rope_interleaved(x, positions, theta: float):
+    """Rotary embedding over the last dim, pairs ``(2i, 2i+1)`` rotated
+    together. ``x`` [S, ..., D], ``positions`` [S]."""
+    d = x.shape[-1]
+    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = positions.astype(jnp.float32)[:, None] * freqs       # [S, D/2]
+    ang = ang.reshape(ang.shape[:1] + (1,) * (x.ndim - 2) + ang.shape[1:])
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def rope_columns(x, positions, theta: float, nope: int):
+    """``x`` [..., S, D] with the columns from ``nope`` on rotated as
+    :func:`rope_interleaved` rotates them and the others as they are:
+    what ``concatenate([x[..., :nope], rope(x[..., nope:])])`` gives,
+    to the bit. A pair's partner comes from a product with a constant
+    0 / +-1 matrix (one term a sum: exact) and not from a shuffle of
+    lanes, and only the columns from the last multiple of
+    ``ROPE_ALIGN`` at or before ``nope`` are read and written back."""
+    d = x.shape[-1]
+    cut = nope // ROPE_ALIGN * ROPE_ALIGN
+    rp, off = d - nope, nope - cut
+    freqs = theta ** (-jnp.arange(0, rp, 2, dtype=jnp.float32) / rp)
+    ang = jnp.repeat(positions.astype(jnp.float32)[:, None] * freqs, 2, -1)
+    cos = jnp.pad(jnp.cos(ang), ((0, 0), (off, 0)), constant_values=1.0)
+    sin = jnp.pad(jnp.sin(ang), ((0, 0), (off, 0)))
+    # (a, b) -> (a cos - b sin, a sin + b cos): x cos + (x @ swap) sin
+    swap = np.zeros((d - cut, d - cut), np.float32)
+    for c in range(off, d - cut, 2):
+        swap[c + 1, c], swap[c, c + 1] = -1.0, 1.0
+    tail = x[..., cut:]
+    partner = jnp.einsum("...d,de->...e", tail, jnp.asarray(swap, x.dtype),
+                         precision=jax.lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)
+    tail = tail.astype(jnp.float32) * cos + partner * sin
+    return x.at[..., cut:].set(tail.astype(x.dtype))
+
+
+def _mm(x, w):
+    """Product accumulated in float32, handed on in the stream's dtype."""
+    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def swiglu(x, p):
+    """``(silu(x w1) * (x w3)) w2`` -> float32."""
+    gate = jnp.dot(x, p["w1"], preferred_element_type=jnp.float32)
+    up = jnp.dot(x, p["w3"], preferred_element_type=jnp.float32)
+    return jnp.dot((jax.nn.silu(gate) * up).astype(x.dtype), p["w2"],
+                   preferred_element_type=jnp.float32)
+
+
+def _mm_heads(x, w):
+    """``x`` [S, r] by ``w`` [r, H, d] -> [H, S, d], accumulated in
+    float32: head-major as the product writes it, which is how the
+    attention kernel reads a head (``ops/sparse_attention.py``)."""
+    return jnp.einsum("sr,rhd->hsd", x, w,
+                      preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def _scaled(x, scale: float):
+    """``x * scale`` with the factor kept in float32 (as a weak scalar
+    it would be rounded to ``x``'s dtype first); 1 changes nothing."""
+    if scale == 1.0:
+        return x
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
+def mla_qkv(x, a, positions, cfg, *, q_scale: float = 1.0,
+            kv_scale: float = 1.0):
+    """The latent projections of normed ``x`` [S, d]: ``c_q`` [S, r_q]
+    and per-head ``q`` [S, H, nope+rope], ``k`` [S, H, nope+rope] (the
+    one roped key part repeated to every head), ``v`` [S, H, v].
+    ``q_scale`` / ``kv_scale`` multiply the two latents after their
+    norms (``mla_scale_q_lora`` / ``mla_scale_kv_lora``; the roped key
+    part is not scaled). Reads ``cfg.num_attention_heads``,
+    ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``kv_lora_rank``,
+    ``rms_norm_eps`` and ``rope_theta``.
+
+    All three are views of head-major arrays, which no transpose or
+    concatenation makes: ``k`` and ``v`` are two products; the key
+    columns of ``wkv_b`` take ``rope`` columns of zeros a head, and the
+    roped part is added into the gap they leave (one operand of each
+    sum is zero: exact)."""
+    h, nope = cfg.num_attention_heads, cfg.qk_nope_head_dim
+    r, rp = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    c_q = _scaled(rmsnorm(_mm(x, a["wq_a"]), a["q_norm"], cfg.rms_norm_eps),
+                  q_scale)
+    q = rope_columns(_mm_heads(c_q, a["wq_b"].reshape(-1, h, nope + rp)),
+                     positions, cfg.rope_theta, nope)
+    kv = _mm(x, a["wkv_a"])
+    c_kv = _scaled(rmsnorm(kv[:, :r], a["kv_norm"], cfg.rms_norm_eps),
+                   kv_scale)
+    k_r = rope_interleaved(kv[:, r:], positions, cfg.rope_theta)
+    w = a["wkv_b"].reshape(r, h, -1)
+    k = _mm_heads(c_kv, jnp.pad(w[..., :nope], ((0, 0), (0, 0), (0, rp)))) \
+        + jnp.pad(k_r, ((0, 0), (nope, 0)))
+    v = _mm_heads(c_kv, w[..., nope:])
+    return (c_q,) + tuple(jnp.transpose(t, (1, 0, 2)) for t in (q, k, v))
+
+
+def causal_attention_out(
+        q, k, v, wo, *, block_q: int, scope: str,
+        key_mask: Optional[Callable[[int, int],
+                                    Optional[jax.Array]]] = None):
+    """Softmax attention of ``q`` over the keys at or before each query
+    (``key_mask`` may narrow them, ``ops/sparse_attention.py``), scores
+    over ``sqrt(nope + rope)``, then the output projection ``wo`` [H *
+    v, d]: -> [S, d] in ``q``'s dtype. ``scope`` names the operations
+    in a trace."""
+    o = blocked_causal_attention(
+        q, k, v, scale=q.shape[-1] ** -0.5, block_q=block_q,
+        key_mask=key_mask, scope=scope)
+    with jax.named_scope(scope):
+        # over (head, v) as the attention wrote them: no [S, H * v] copy
+        wo = wo.reshape(o.shape[1], o.shape[2], -1)
+        return jnp.einsum("shv,hvd->sd", o, wo,
+                          preferred_element_type=jnp.float32
+                          ).astype(q.dtype)
+
+
+def score(h, params, tokens, eps: float):
+    """The head of a scoring pass over ``h`` [B, S, d] -> ``(last_logits
+    float32 [B, V], logprobs float32 [B, S])``: the last position's
+    logits, and at position t the log-probability of token t+1 (0 at
+    S-1)."""
+    with jax.named_scope("lm_head"):
+        x = rmsnorm(h, params["norm_f"], eps)
+        logits = jnp.dot(x, params["head"],
+                         preferred_element_type=jnp.float32)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nxt = jnp.take_along_axis(logp[:, :-1], tokens[:, 1:, None], axis=-1)
+        logprobs = jnp.pad(nxt[..., 0], ((0, 0), (0, 1)))
+    return logits[:, -1], logprobs
+
+
+def frame_model(forward, cfg, seq: int, load_dims: str):
+    """``(apply_fn, in_info, out_info)`` for ``tensor_filter
+    framework=jax``: one int32 ``[seq]`` token frame a buffer in, the
+    three tensors of ``forward(params, tokens [1, seq], cfg)`` out, the
+    load's dimensions as the caps spell them (innermost first)."""
+
+    def apply_fn(p, tokens):
+        last, logprobs, load = forward(p, tokens[None].astype(jnp.int32), cfg)
+        return last[0], logprobs[0], load
+
+    in_info = TensorsInfo.make("int32", str(seq))
+    out_info = TensorsInfo.make("float32,float32,int32",
+                                f"{cfg.vocab_size},{seq},{load_dims}")
+    return apply_fn, in_info, out_info
+
+
+def config_from_options(cls, zoo_name: str, dtype: str, sizes: dict):
+    """A configuration dataclass from a zoo URI's options: any field of
+    ``cls`` by its name, converted by the field's annotation."""
+    kinds = {f.name: f.type for f in dataclasses.fields(cls)}
+    unknown = sorted(set(sizes) - set(kinds))
+    if unknown:
+        raise ValueError(f"zoo://{zoo_name}: unknown option(s) {unknown}")
+    convert = {"float": float, "int": int, "str": str,
+               "bool": lambda v: v.lower() in ("1", "true")}
+    return cls(dtype=jnp.dtype(dtype), **{
+        k: convert[kinds[k]](v) for k, v in sizes.items()})

@@ -393,13 +393,17 @@ def test_aot_estimate_smoke(topology):
         < sum(c for _, c in tool.fusion_cycles(loaded).values())
 
 
-@pytest.mark.parametrize("lo,hi,masked", [(3584, 4096, True),
-                                          (1536, 2048, False)],
-                         ids=["masked", "causal"])
-def test_mosaic_takes_the_masked_attention_kernel(topology, lo, hi, masked):
+@pytest.mark.parametrize("lo,hi,masked,dv", [(3584, 4096, True, 256),
+                                             (1536, 2048, False, 256),
+                                             (3584, 4096, False, 128)],
+                         ids=["masked", "causal", "causal_v128"])
+def test_mosaic_takes_the_masked_attention_kernel(topology, lo, hi, masked,
+                                                  dv):
     """What the interpreter cannot show: the TPU's compiler lays out
-    ``nns_masked_attention`` at the lm cell's widths (64 heads of 256,
-    S = 4096, the module's own tiles) within the VMEM it asks for."""
+    ``nns_masked_attention`` at the lm cells' widths (64 heads, S =
+    4096, the module's own tiles; keys of 256 lanes with values of 256,
+    GLM-5's, or of 128, LongCat's 192 | 128 padded) within the VMEM it
+    asks for."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     from nnstreamer_tpu.ops import sparse_attention as sa
@@ -414,14 +418,14 @@ def test_mosaic_takes_the_masked_attention_kernel(topology, lo, hi, masked):
                                 lo=lo, hi=hi, tq=sa.TILE_Q, tk=sa.TILE_K,
                                 scale=1 / 16, interpret=False)
 
-    heads = spec((64, 4096, 256))
+    heads, values = spec((64, 4096, 256)), spec((64, 4096, dv))
     jax.config.update("jax_enable_compilation_cache", False)
     try:
         # conftest pins float32 products for the CPU's sake; the chip
         # multiplies bfloat16 operands as they are
         with jax.default_matmul_precision("default"):
             text = jax.jit(block).lower(
-                heads, heads, heads, spec((hi - lo, hi), jnp.int8), heads
+                heads, heads, values, spec((hi - lo, hi), jnp.int8), values
             ).compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
