@@ -70,27 +70,30 @@ def infer_batch_dim(sel: TensorsInfo, model: TensorsInfo) -> Optional[int]:
 class TensorFilter(Element):
     """Runs a model on every buffer through a filter backend
     (``framework``).
-    ``framework=jax`` converts the model's parameters to their compute
-    dtype once per load, not once per buffer: a parameter leaf whose
-    every use in the traced program is a conversion to one narrower
-    floating dtype (flax's float32 kernels under a bfloat16 module) is
-    converted on the device by one program, ``jit_nns_filter_prepare``,
-    and the per-buffer program ``jit_nns_filter_<model>`` is built from
-    the same trace without those conversions (``filters/prepare.py``);
-    every input signature, mesh mode and a fused segment share the one
-    converted tree. It is redone when the parameters are replaced
-    (``reload_model()``, the resume after ``suspend``). The loaded tree
-    stays on the device as the source of both, so the copy costs the
-    converted leaves' bytes in the narrow dtype on top (ViT-H/14: 2.53 GB
-    of float32 + 1.26 GB of bfloat16). ``transfer_report()`` carries
-    ``prepared_leaves`` and ``prepared_bytes`` (0 where no leaf
-    qualifies: a model used in float32, a tree already in bfloat16, which
-    get ``jax.jit`` of their ``apply_fn`` and their own arrays as
-    before) and, read off the same trace, ``kernel_calls``: the Pallas
-    kernels the program calls, name -> call sites; the span ring and a
-    profiler trace hold one ``nns.filter.prepare`` span per load with
-    ``leaves``, ``bytes_in`` and ``bytes_out``. No property selects it: the values are rounded
-    the same way whenever it is done."""
+    ``framework=jax`` computes what the model's parameters alone
+    determine once per load, not once per buffer: an equation of the
+    traced program all of whose operands are parameter leaves, literals
+    or results of such equations (flax's float32 kernels converted to
+    the module's bfloat16, a decoder's projection weights cut, padded
+    and re-laid for the product that reads them) runs on the device in
+    one program, ``jit_nns_filter_prepare``, and the per-buffer program
+    ``jit_nns_filter_<model>`` is built from the same trace without
+    those equations (``filters/prepare.py``); every input signature,
+    mesh mode and a fused segment share the one set of results. It is
+    redone when the parameters are replaced (``reload_model()``, the
+    resume after ``suspend``). The loaded tree stays on the device as
+    the source, so the results' bytes come on top (ViT-H/14: 2.53 GB of
+    float32 + 1.26 GB of bfloat16). ``transfer_report()`` carries
+    ``prepared_equations`` (the equations moved to the load),
+    ``prepared_leaves`` and ``prepared_bytes`` (the leaves among them
+    held a second time in a narrower dtype; all 0 where the leaves alone
+    determine nothing: such a model gets ``jax.jit`` of its ``apply_fn``
+    and its own arrays as before) and, read off the same trace,
+    ``kernel_calls``: the Pallas kernels the program calls, name -> call
+    sites; the span ring and a profiler trace hold one
+    ``nns.filter.prepare`` span per load with ``leaves``, ``equations``,
+    ``bytes_in`` and ``bytes_out``. No property selects it: an equation
+    on constants gives the same bits whenever it is run."""
 
     SINK_TEMPLATES = {"sink": "other/tensors"}
     SRC_TEMPLATES = {"src": "other/tensors"}
@@ -765,8 +768,9 @@ class TensorFilter(Element):
 
     def transfer_report(self) -> dict:
         """Window occupancy / overlap stats for the pipeline report's
-        ``transfer`` block, with the backend's ``prepared_leaves`` /
-        ``prepared_bytes`` (filters/prepare.py: parameters held a
+        ``transfer`` block, with the backend's ``prepared_equations``
+        (filters/prepare.py: equations run once per load),
+        ``prepared_leaves`` / ``prepared_bytes`` (parameters held a
         second time in their compute dtype) and ``kernel_calls`` (the
         program's Pallas kernels by name, with their call sites); {}
         when running synchronously with nothing prepared and no
@@ -775,7 +779,7 @@ class TensorFilter(Element):
         prepared = getattr(self.fw, "prepared_report", None)
         if callable(prepared):
             held = prepared()
-            if rep or held["prepared_leaves"] or held["kernel_calls"]:
+            if rep or held["prepared_equations"] or held["kernel_calls"]:
                 rep = {**rep, **held}
         return rep
 

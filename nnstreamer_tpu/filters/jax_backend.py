@@ -96,16 +96,15 @@ class JaxFilter(FilterFramework):
         self._apply: Optional[Callable] = None
         # the tree as the model handed it over: what reload and suspend read
         self._params: Any = None
-        # filters/prepare.py: {leaf index: dtype} the first program
-        # traced after a load found convertible (None: none traced
-        # yet), and the tree holding those leaves converted, which
-        # every program that agrees on the set runs on
-        self._narrow: Optional[Dict[int, Any]] = None
-        self._prepared: Any = None
-        self._prepared_bytes = 0
+        # filters/prepare.py: how the first program traced after a
+        # load was cut (None: none traced yet), and what its step reads
+        # of the parameters: the leaves it kept and the load's results,
+        # which every program whose cut agrees runs on
+        self._cut: Optional[_prepare.Split] = None
+        self._prepared: Optional[List[Any]] = None
         # {kernel name: call sites} in that first program's trace
         self._kernel_calls: Dict[str, int] = {}
-        # jit-cache keys of the programs that take the converted tree
+        # jit-cache keys of the programs that take the prepared arrays
         self._on_prepared: set = set()
         self._in_info: Optional[TensorsInfo] = None
         self._out_info: Optional[TensorsInfo] = None
@@ -230,24 +229,29 @@ class JaxFilter(FilterFramework):
 
     def _drop_programs(self) -> None:
         """Forget what was built from the parameters that are being
-        replaced or unloaded: the programs, the converted leaves and
-        the set they were chosen by. The next program built redoes all
-        three from ``self._params`` as it then stands."""
+        replaced or unloaded: the programs, the load's results and the
+        cut they were made by. The next program built redoes all three
+        from ``self._params`` as it then stands."""
         self._jit_cache.clear()
         self._on_prepared.clear()
-        self._narrow = self._prepared = None
-        self._prepared_bytes = 0
+        self._cut = self._prepared = None
         self._kernel_calls = {}
 
     def prepared_report(self) -> Dict[str, Any]:
         """How many parameter leaves the loaded model holds a second
         time in their compute dtype, and that copy's bytes (0, 0 where
-        no leaf qualified), and ``kernel_calls``: the Pallas kernels of
+        no leaf qualified); ``prepared_equations``: the equations of
+        the model's trace that run once per load and not per buffer,
+        those conversions among them (0 where the leaves alone
+        determine nothing); and ``kernel_calls``: the Pallas kernels of
         the first program traced after the load, by name, with their
         call sites ({} for a model in plain XLA). The element's
         ``transfer_report()``."""
-        return {"prepared_leaves": len(self._narrow or ()),
-                "prepared_bytes": self._prepared_bytes,
+        narrowed = self._cut.narrowed if self._cut else {}
+        return {"prepared_leaves": len(narrowed),
+                "prepared_bytes": sum(narrowed.values()),
+                "prepared_equations":
+                    self._cut.equations if self._cut else 0,
                 "kernel_calls": dict(self._kernel_calls)}
 
     # -- info -------------------------------------------------------------
@@ -266,9 +270,10 @@ class JaxFilter(FilterFramework):
         cache key because donation changes the compiled program.
 
         The model is traced here, once per program, and the program is
-        built from that trace: over the converted leaves where the
-        trace agrees with the loaded model's set (filters/prepare.py),
-        else ``jax.jit`` of ``apply_fn`` itself on the loaded tree."""
+        built from that trace: without what the load computed, where
+        the trace is cut as the loaded model's first one was
+        (filters/prepare.py), else ``jax.jit`` of ``apply_fn`` itself
+        on the loaded tree."""
         sig = tuple((tuple(x.shape), str(x.dtype)) for x in xs)
         key = (sig, donate_idx) if donate_idx else sig
         exe = self._jit_cache.get(key)
@@ -283,11 +288,11 @@ class JaxFilter(FilterFramework):
                     if donate_idx else jax.jit(fn)
 
             exe = jit(self._apply)
-            closed, out_tree, narrow = _prepare.trace(exe, self._params, xs)
-            if self._narrow is None:
+            closed, out_tree, cut = _prepare.trace(exe, self._params, xs)
+            if self._cut is None:
                 self._kernel_calls = _prepare.kernel_calls(closed)
-            if self._converted(self._params, narrow) is not None:
-                exe = jit(_prepare.program(closed, out_tree, narrow))
+            if self._loaded(self._params, cut) is not None:
+                exe = jit(_prepare.program(closed, out_tree, cut))
                 self._on_prepared.add(key)
             self._jit_cache[key] = exe
             self.compile_count += 1
@@ -295,47 +300,42 @@ class JaxFilter(FilterFramework):
         return exe(self._prepared if key in self._on_prepared
                    else self._params, *xs)
 
-    def _converted(self, params: Any, narrow: Dict[int, Any]) -> Any:
-        """The tree a program may run on whose trace of ``params``
-        found ``narrow`` convertible (lock held): the loaded model's
-        converted tree, shared by every signature, or None where the
-        trace does not agree with it — nothing qualifies, another set
-        than the first program's (the leaves' uses are ``apply_fn``'s,
-        not the input shape's, so this is a guard), or ``params`` are no
-        longer the loaded ones (a fused segment planned before a
-        reload). The first trace after a load decides the set and
-        converts."""
+    def _loaded(self, params: Any, cut: _prepare.Split) -> Any:
+        """What a program's step may read whose trace of ``params`` was
+        cut into ``cut`` (lock held): the loaded model's kept leaves
+        and load results, shared by every signature, or None where the
+        trace does not agree with them: nothing goes to the load, the
+        cut is another than the first program's (what the leaves alone
+        determine is ``apply_fn``'s, not the input shape's, so this is
+        a guard), or ``params`` are no longer the loaded ones (a fused
+        segment planned before a reload). The first trace after a load
+        decides the cut and runs its load."""
         if params is not self._params:
             return None
-        if self._narrow is None:
-            self._narrow = narrow
-            if narrow:
-                self._prepared = self._convert(narrow)
+        if self._cut is None:
+            self._cut = cut
+            if cut:
+                self._prepared = self._load(cut)
         return self._prepared \
-            if narrow and narrow == self._narrow else None
+            if cut and cut.agrees == self._cut.agrees else None
 
-    def _convert(self, narrow: Dict[int, Any]) -> Any:
+    def _load(self, cut: _prepare.Split) -> List[Any]:
         import jax
-        leaves, treedef = jax.tree.flatten(self._params)
-        idx = sorted(narrow)
-        src = [leaves[i] for i in idx]
-        dtypes = [narrow[i] for i in idx]
-        self._prepared_bytes = sum(
-            x.size * d.itemsize for x, d in zip(src, dtypes))
-        # a fused segment reaches here inside ITS trace: the conversion
-        # must run now, not be staged into that program
+        leaves = jax.tree.leaves(self._params)
+        # a fused segment reaches here inside ITS trace: the load must
+        # run now, not be staged into that program
+        bytes_out = sum(v.aval.size * v.aval.dtype.itemsize
+                        for v in cut.load.outvars)
         with _obs_spans.region(
-                "nns.filter.prepare", "filter", leaves=len(idx),
-                bytes_in=sum(x.nbytes for x in src),
-                bytes_out=self._prepared_bytes), \
-                jax.ensure_compile_time_eval():
-            out = _prepare.convert(src, dtypes)
-        for i, x in zip(idx, out):
-            leaves[i] = x
-        logger.info("jax filter: %d parameter leaves (%d bytes) converted "
-                    "once for %s", len(idx), self._prepared_bytes,
-                    self._model_stem)
-        return treedef.unflatten(leaves)
+                "nns.filter.prepare", "filter", leaves=len(cut.sources),
+                equations=cut.equations,
+                bytes_in=sum(leaves[i].nbytes for i in cut.sources),
+                bytes_out=bytes_out), jax.ensure_compile_time_eval():
+            out = _prepare.load(cut, leaves)
+        logger.info("jax filter: %d equations on %d parameter leaves run "
+                    "once for %s, %d bytes held", cut.equations,
+                    len(cut.sources), self._model_stem, bytes_out)
+        return [leaves[i] for i in cut.kept] + out
 
     def _record_signature(self, sig: Tuple,
                           donate_idx: Tuple[int, ...]) -> None:
@@ -473,8 +473,8 @@ class JaxFilter(FilterFramework):
         jit program (fusion/segment.py). Params are captured by value:
         the closure stays valid across suspend/reload, it just keeps
         serving the params it was planned with. Traced while they are
-        still the loaded ones, it inlines the program over the converted
-        leaves, as ``_run`` builds it, from its one trace of the model.
+        still the loaded ones, it inlines the step that reads the load's
+        results, as ``_run`` builds it, from its one trace of the model.
 
         In mesh mode the closed-over params are mesh-committed
         jax.Arrays, so the fused program compiles over the mesh with
@@ -491,14 +491,14 @@ class JaxFilter(FilterFramework):
 
         def fn(*xs):
             import jax
-            closed, out_tree, narrow = _prepare.trace(
+            closed, out_tree, cut = _prepare.trace(
                 jax.jit(apply_fn), params,
                 [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in xs])
             with self._lock:
-                tree = self._converted(params, narrow)
-            if tree is None:
-                tree, narrow = params, {}
-            return _prepare.program(closed, out_tree, narrow)(tree, *xs)
+                held = self._loaded(params, cut)
+            if held is None:    # the trace as it is: every leaf an input
+                cut, held = _prepare.split(closed, 0), jax.tree.leaves(params)
+            return _prepare.program(closed, out_tree, cut)(held, *xs)
 
         return fn
 

@@ -226,7 +226,7 @@ def attend(h, layer, cfg: GLMDSAConfig):
                 return topk_mask(scores, cfg.index_topk, causal)
 
     return h + causal_attention_out(
-        q, k, v, layer["attn"]["wo"], block_q=BLOCK_Q, key_mask=selected,
+        q, k, v, layer["attn"]["wo"], cfg, block_q=BLOCK_Q, key_mask=selected,
         scope="block/attn")
 
 

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.sparse_attention import blocked_causal_attention
+from ..ops.sparse_attention import LANES, blocked_causal_attention
 from ..tensors.info import TensorsInfo
 from .transformer import rmsnorm
 
@@ -51,24 +51,27 @@ def rope_interleaved(x, positions, theta: float):
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def rope_columns(x, positions, theta: float, nope: int):
-    """``x`` [..., S, D] with the columns from ``nope`` on rotated as
-    :func:`rope_interleaved` rotates them and the others as they are:
-    what ``concatenate([x[..., :nope], rope(x[..., nope:])])`` gives,
-    to the bit. A pair's partner comes from a product with a constant
-    0 / +-1 matrix (one term a sum: exact) and not from a shuffle of
-    lanes, and only the columns from the last multiple of
-    ``ROPE_ALIGN`` at or before ``nope`` are read and written back."""
+def rope_columns(x, positions, theta: float, nope: int, rope: int):
+    """``x`` [..., S, D] with the ``rope`` columns from ``nope`` on
+    rotated as :func:`rope_interleaved` rotates them and the others as
+    they are (columns past ``nope + rope``, a head's padding, among
+    them): what ``concatenate([x[..., :nope], rope(x[..., nope:nope +
+    rope]), x[..., nope + rope:]])`` gives, to the bit. A pair's
+    partner comes from a product with a constant 0 / +-1 matrix (one
+    term a sum: exact) and not from a shuffle of lanes, and only the
+    columns from the last multiple of ``ROPE_ALIGN`` at or before
+    ``nope`` are read and written back; those of them outside the
+    rotation meet cos 1 and sin 0."""
     d = x.shape[-1]
     cut = nope // ROPE_ALIGN * ROPE_ALIGN
-    rp, off = d - nope, nope - cut
-    freqs = theta ** (-jnp.arange(0, rp, 2, dtype=jnp.float32) / rp)
+    off, after = nope - cut, d - nope - rope
+    freqs = theta ** (-jnp.arange(0, rope, 2, dtype=jnp.float32) / rope)
     ang = jnp.repeat(positions.astype(jnp.float32)[:, None] * freqs, 2, -1)
-    cos = jnp.pad(jnp.cos(ang), ((0, 0), (off, 0)), constant_values=1.0)
-    sin = jnp.pad(jnp.sin(ang), ((0, 0), (off, 0)))
+    cos = jnp.pad(jnp.cos(ang), ((0, 0), (off, after)), constant_values=1.0)
+    sin = jnp.pad(jnp.sin(ang), ((0, 0), (off, after)))
     # (a, b) -> (a cos - b sin, a sin + b cos): x cos + (x @ swap) sin
     swap = np.zeros((d - cut, d - cut), np.float32)
-    for c in range(off, d - cut, 2):
+    for c in range(off, off + rope, 2):
         swap[c + 1, c], swap[c, c + 1] = -1.0, 1.0
     tail = x[..., cut:]
     partner = jnp.einsum("...d,de->...e", tail, jnp.asarray(swap, x.dtype),
@@ -92,11 +95,41 @@ def swiglu(x, p):
 
 
 def _mm_heads(x, w):
-    """``x`` [S, r] by ``w`` [r, H, d] -> [H, S, d], accumulated in
+    """``x`` [S, r] by ``w`` [H, d, r] -> [H, S, d], accumulated in
     float32: head-major as the product writes it, which is how the
     attention kernel reads a head (``ops/sparse_attention.py``)."""
-    return jnp.einsum("sr,rhd->hsd", x, w,
+    return jnp.einsum("sr,hdr->hsd", x, w,
                       preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def mla_weights(a, cfg):
+    """What :func:`mla_qkv` multiplies the two latents by, from the
+    attention sublayer's leaves ``a`` alone: ``(wq [H, wide, r_q], wk
+    [H, wide, r], wv [H, v, r])``. ``wide`` is ``nope + rope`` rounded
+    up to the attention kernel's lane width: a head's query rows and
+    its key rows take zero rows up to it (the key's also where its
+    roped part goes), so that q and k leave their products as the
+    kernel reads them, with no pad between. The latent dimension is
+    last: the array the TPU compiler otherwise copies each weight into
+    for these products, and read on the chip the faster one (PERF.md,
+    PR 33). No input is in it, so the jax filter runs it once per load
+    (``filters/prepare.py``). Reads ``cfg.num_attention_heads``,
+    ``qk_nope_head_dim``, ``qk_rope_head_dim`` and ``kv_lora_rank``."""
+    h, nope = cfg.num_attention_heads, cfg.qk_nope_head_dim
+    d = nope + cfg.qk_rope_head_dim
+    wide = -(-d // LANES) * LANES
+
+    def laid(w, width=None):
+        # [r, H, n] -> [H, width, r]; lax.pad, which jnp.pad would wrap
+        # in a program of its own that the load does not look into
+        if width is not None and width > w.shape[2]:
+            w = jax.lax.pad(w, np.zeros((), w.dtype), [
+                (0, 0, 0), (0, 0, 0), (0, width - w.shape[2], 0)])
+        return jnp.transpose(w, (1, 2, 0))
+
+    w = a["wkv_b"].reshape(cfg.kv_lora_rank, h, -1)
+    return (laid(a["wq_b"].reshape(-1, h, d), wide),
+            laid(w[..., :nope], wide), laid(w[..., nope:]))
 
 
 def _scaled(x, scale: float):
@@ -110,8 +143,8 @@ def _scaled(x, scale: float):
 def mla_qkv(x, a, positions, cfg, *, q_scale: float = 1.0,
             kv_scale: float = 1.0):
     """The latent projections of normed ``x`` [S, d]: ``c_q`` [S, r_q]
-    and per-head ``q`` [S, H, nope+rope], ``k`` [S, H, nope+rope] (the
-    one roped key part repeated to every head), ``v`` [S, H, v].
+    and per-head ``q`` [S, H, wide], ``k`` [S, H, wide] (the one roped
+    key part repeated to every head), ``v`` [S, H, v].
     ``q_scale`` / ``kv_scale`` multiply the two latents after their
     norms (``mla_scale_q_lora`` / ``mla_scale_kv_lora``; the roped key
     part is not scaled). Reads ``cfg.num_attention_heads``,
@@ -119,39 +152,39 @@ def mla_qkv(x, a, positions, cfg, *, q_scale: float = 1.0,
     ``rms_norm_eps`` and ``rope_theta``.
 
     All three are views of head-major arrays, which no transpose or
-    concatenation makes: ``k`` and ``v`` are two products; the key
-    columns of ``wkv_b`` take ``rope`` columns of zeros a head, and the
-    roped part is added into the gap they leave (one operand of each
-    sum is zero: exact)."""
-    h, nope = cfg.num_attention_heads, cfg.qk_nope_head_dim
-    r, rp = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    concatenation makes, and ``q`` and ``k`` are ``wide`` columns a
+    head (:func:`mla_weights`), the ones past ``nope + rope`` zero:
+    ``k`` and ``v`` are two products, and the roped key part is added
+    into the gap the key's zero columns leave (one operand of each sum
+    is zero: exact)."""
+    nope, rp, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    wq, wk, wv = mla_weights(a, cfg)
     c_q = _scaled(rmsnorm(_mm(x, a["wq_a"]), a["q_norm"], cfg.rms_norm_eps),
                   q_scale)
-    q = rope_columns(_mm_heads(c_q, a["wq_b"].reshape(-1, h, nope + rp)),
-                     positions, cfg.rope_theta, nope)
+    q = rope_columns(_mm_heads(c_q, wq), positions, cfg.rope_theta, nope, rp)
     kv = _mm(x, a["wkv_a"])
     c_kv = _scaled(rmsnorm(kv[:, :r], a["kv_norm"], cfg.rms_norm_eps),
                    kv_scale)
     k_r = rope_interleaved(kv[:, r:], positions, cfg.rope_theta)
-    w = a["wkv_b"].reshape(r, h, -1)
-    k = _mm_heads(c_kv, jnp.pad(w[..., :nope], ((0, 0), (0, 0), (0, rp)))) \
-        + jnp.pad(k_r, ((0, 0), (nope, 0)))
-    v = _mm_heads(c_kv, w[..., nope:])
+    k = _mm_heads(c_kv, wk)
+    k = k + jnp.pad(k_r, ((0, 0), (nope, k.shape[-1] - nope - rp)))
+    v = _mm_heads(c_kv, wv)
     return (c_q,) + tuple(jnp.transpose(t, (1, 0, 2)) for t in (q, k, v))
 
 
 def causal_attention_out(
-        q, k, v, wo, *, block_q: int, scope: str,
+        q, k, v, wo, cfg, *, block_q: int, scope: str,
         key_mask: Optional[Callable[[int, int],
                                     Optional[jax.Array]]] = None):
     """Softmax attention of ``q`` over the keys at or before each query
     (``key_mask`` may narrow them, ``ops/sparse_attention.py``), scores
-    over ``sqrt(nope + rope)``, then the output projection ``wo`` [H *
-    v, d]: -> [S, d] in ``q``'s dtype. ``scope`` names the operations
-    in a trace."""
+    over ``sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)`` (not
+    ``q``'s width: :func:`mla_qkv` pads a head), then the output
+    projection ``wo`` [H * v, d]: -> [S, d] in ``q``'s dtype. ``scope``
+    names the operations in a trace."""
     o = blocked_causal_attention(
-        q, k, v, scale=q.shape[-1] ** -0.5, block_q=block_q,
-        key_mask=key_mask, scope=scope)
+        q, k, v, scale=(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5,
+        block_q=block_q, key_mask=key_mask, scope=scope)
     with jax.named_scope(scope):
         # over (head, v) as the attention wrote them: no [S, H * v] copy
         wo = wo.reshape(o.shape[1], o.shape[2], -1)

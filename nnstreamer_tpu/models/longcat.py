@@ -192,7 +192,7 @@ def attend(h, sub, cfg: LongCatConfig):
         x = rmsnorm(h, sub["attn_norm"], cfg.rms_norm_eps)
         _, q, k, v = mla_qkv(x, sub["attn"], positions, cfg,
                              q_scale=cfg.q_scale, kv_scale=cfg.kv_scale)
-    return h + causal_attention_out(q, k, v, sub["attn"]["wo"],
+    return h + causal_attention_out(q, k, v, sub["attn"]["wo"], cfg,
                                     block_q=BLOCK_Q, scope="block/attn")
 
 

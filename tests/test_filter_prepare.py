@@ -1,7 +1,9 @@
-"""The jax filter converts a parameter leaf to its compute dtype once
-per load when every use of it in the traced program is that conversion
-(filters/prepare.py): same bits out, one shared copy, redone whenever
-the parameters are replaced, and nothing at all where no leaf qualifies.
+"""The jax filter runs what the parameter leaves alone determine once
+per load (filters/prepare.py): the equations of the traced program
+whose operands are leaves, literals or results of such equations, a
+leaf's conversion to its compute dtype among them. Same bits out, one
+shared set of results, redone whenever the parameters are replaced, and
+nothing at all where no equation qualifies.
 """
 import textwrap
 
@@ -35,16 +37,52 @@ def _frames(batch, seed=0):
 
 @pytest.fixture
 def conversions(monkeypatch):
-    """Counts the runs of the converting program."""
+    """Counts the runs of the load's program, by their source leaves."""
     calls = []
-    real = prepare.convert
+    real = prepare.load
 
-    def convert(leaves, dtypes):
-        calls.append(len(leaves))
-        return real(leaves, dtypes)
+    def load(cut, leaves):
+        calls.append(len(cut.sources))
+        return real(cut, leaves)
 
-    monkeypatch.setattr(prepare, "convert", convert)
+    monkeypatch.setattr(prepare, "load", load)
     return calls
+
+
+def _nothing_to_the_load(monkeypatch):
+    real = prepare.split
+    monkeypatch.setattr(prepare, "split", lambda closed, n: real(closed, 0))
+
+
+def _old_narrowable(closed, n_leaves):
+    """PR 27's rule, kept as the oracle of the one-equation case:
+    ``{leaf index: dtype}`` of the leaves whose every use is a plain
+    ``convert_element_type`` to one narrower floating dtype."""
+    from jax.extend.core import Var
+    jaxpr = closed.jaxpr
+    index = {v: i for i, v in enumerate(jaxpr.invars[:n_leaves])}
+    target = {}
+    for eqn in jaxpr.eqns:
+        dtype = None
+        if eqn.primitive is jax.lax.convert_element_type_p \
+                and not eqn.params["weak_type"] \
+                and eqn.params["sharding"] is None:
+            dtype = eqn.params["new_dtype"]
+        for v in eqn.invars:
+            i = index.get(v) if isinstance(v, Var) else None
+            if i is not None:
+                target[i] = dtype if target.get(i, dtype) == dtype else None
+    for v in jaxpr.outvars:
+        if isinstance(v, Var) and v in index:
+            target[index[v]] = None
+    out = {}
+    for i, dtype in target.items():
+        src = jaxpr.invars[i].aval.dtype
+        if dtype is not None and jnp.issubdtype(src, jnp.floating) \
+                and jnp.issubdtype(dtype, jnp.floating) \
+                and jnp.dtype(dtype).itemsize < src.itemsize:
+            out[i] = jnp.dtype(dtype)
+    return out
 
 
 def _model_file(tmp_path, body):
@@ -54,7 +92,7 @@ def _model_file(tmp_path, body):
 
 
 # a float32 tree in which `k`'s every use is the conversion, and `w` is
-# also read in float32
+# also read in float32 together with an input
 TWO_USES = """
     import jax.numpy as jnp
     import numpy as np
@@ -68,7 +106,7 @@ TWO_USES = """
         def apply_fn(p, x):
             h = x.astype(jnp.bfloat16) @ p["k"].astype(jnp.bfloat16)
             h = h @ p["w"].astype(jnp.bfloat16)
-            return h.astype(jnp.float32) + p["w"].sum()
+            return h.astype(jnp.float32) @ p["w"]
 
         return (apply_fn, params, TensorsInfo.make("float32", "16"),
                 TensorsInfo.make("float32", "8"))
@@ -124,10 +162,12 @@ def test_pipeline_logits_bit_identical(monkeypatch, window):
     got, rep, kernels = run()
     assert rep["prepared_leaves"] > 0
     assert rep["prepared_bytes"] > 0.95 * kernels / 2
-    monkeypatch.setattr(prepare, "narrowable", lambda closed, n: {})
+    assert rep["prepared_equations"] == rep["prepared_leaves"]
+    _nothing_to_the_load(monkeypatch)
     want, rep0, _ = run()
     assert rep0.get("prepared_leaves", 0) == 0
     assert rep0.get("prepared_bytes", 0) == 0
+    assert rep0.get("prepared_equations", 0) == 0
     assert len(got) == 3 and got == want
 
 
@@ -146,6 +186,7 @@ def test_nothing_to_convert_same_program_same_arrays(conversions, model,
     fw.invoke([x])
     assert fw.prepared_report() == {"prepared_leaves": 0,
                                     "prepared_bytes": 0,
+                                    "prepared_equations": 0,
                                     "kernel_calls": {}}
     assert conversions == [] and fw._prepared is None
     exe, = fw._jit_cache.values()
@@ -184,29 +225,31 @@ def test_one_python_trace_of_the_model_per_program(model, custom):
     fw.close()
 
 
-def test_leaf_with_a_float32_use_is_not_converted(tmp_path):
-    """(c) one float32 use anywhere keeps the leaf as loaded."""
+def test_leaf_with_a_float32_use_stays_beside_its_conversion(tmp_path):
+    """(c) a leaf that is also read in float32 with an input stays what
+    the step reads, the very array that was loaded; its conversion runs
+    at the load all the same, and only ``k``, which the step no longer
+    reads, counts as held a second time."""
     fw = _open(_model_file(tmp_path, TWO_USES))
     x = np.random.default_rng(1).random((4, 16), np.float32)
     got = np.asarray(fw.invoke([x])[0])
-    leaves = jax.tree_util.tree_leaves_with_path(fw._params)
-    names = [jax.tree_util.keystr(p) for p, _ in leaves]
-    assert {names[i]: d for i, d in fw._narrow.items()} \
-        == {"['k']": jnp.dtype(jnp.bfloat16)}
-    assert fw._prepared["w"] is fw._params["w"]
-    assert fw._prepared["k"].dtype == jnp.bfloat16
+    cut = fw._cut
+    assert cut.sources == (0, 1) and cut.kept == (1,)      # k, w | w
+    assert cut.narrowed == {0: 16 * 8 * 2}
+    w, k16, w16 = fw._prepared
+    assert w is fw._params["w"]
+    assert k16.dtype == w16.dtype == jnp.bfloat16
+    assert k16.shape == (16, 8) and w16.shape == (8, 8)
     assert fw.prepared_report() == {"prepared_leaves": 1,
                                     "prepared_bytes": 16 * 8 * 2,
+                                    "prepared_equations": 2,
                                     "kernel_calls": {}}
     want = np.asarray(jax.jit(fw._apply)(fw._params, x))
     assert got.tobytes() == want.tobytes()
     fw.close()
 
 
-@pytest.mark.parametrize("use", ["returned", "sub_program", "two_dtypes",
-                                 "wider", "integer"])
-def test_narrowable_rejects(use):
-    """The rule itself, on programs that must keep their leaf."""
+def _rejects(use):
     def f(w, x):
         if use == "returned":
             return x @ w.astype(jnp.bfloat16), w
@@ -219,8 +262,105 @@ def test_narrowable_rejects(use):
             jnp.float32 if use == "wider" else jnp.int8)
 
     w = jnp.ones((4, 4), jnp.bfloat16 if use == "wider" else jnp.float32)
-    closed = jax.make_jaxpr(f)(w, jnp.ones((4, 4), jnp.bfloat16))
-    assert prepare.narrowable(closed, 1) == {}
+    return jax.make_jaxpr(f)(w, jnp.ones((4, 4), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("use,equations,kept,narrowed", [
+    ("returned", 1, (0,), {}),        # converted once, and still returned
+    ("sub_program", 0, (0,), {}),     # a jit's operand: not looked into
+    ("two_dtypes", 2, (), {0: 64}),   # both copies are held
+    ("wider", 1, (), {}),             # moved, but no narrower copy
+    ("integer", 1, (), {}),
+])
+def test_split_on_one_leaf(use, equations, kept, narrowed):
+    """The rule itself on PR 27's cases: what goes to the load, whether
+    the step still reads the leaf, and what counts as a leaf held a
+    second time in a narrower floating dtype."""
+    cut = prepare.split(_rejects(use), 1)
+    assert (cut.equations, cut.kept, cut.narrowed) \
+        == (equations, kept, narrowed)
+    assert bool(cut) == bool(equations)
+    if use != "two_dtypes":     # the oracle refuses what now holds both
+        assert set(cut.narrowed) == set(_old_narrowable(_rejects(use), 1))
+
+
+def _relaid(p, x):
+    """A toy whose leaves are cut, padded, transposed and reshaped
+    before use, as ``models/latent.py`` re-lays its projections."""
+    w = p["w"].reshape(16, 4, 6)                       # [r, h, n]
+    key = jax.lax.pad(w[..., :4], np.zeros((), w.dtype),
+                      [(0, 0, 0), (0, 0, 0), (0, 4, 0)])
+    key = jnp.transpose(key, (1, 2, 0)).reshape(32, 16)
+    val = jnp.transpose(w[..., 4:], (1, 2, 0))
+    h = jnp.einsum("sr,dr->sd", x, key) * p["scale"]   # a view's reader
+    v = jnp.einsum("sr,hdr->shd", x, val)
+
+    def body(c, row):                   # the leaf inside a loop's body
+        return c + row @ p["inside"].T, ()
+
+    looped, _ = jax.lax.scan(body, jnp.zeros((16,), x.dtype), x)
+    gate = jax.lax.cond(x[0, 0] > 0, lambda: p["branch"] * 2.0,
+                        lambda: p["branch"])
+    return h + (x @ p["mixed"]), v + gate, looped, p["returned"]
+
+
+def _relaid_params():
+    rng = np.random.default_rng(0)
+    shapes = {"w": (16, 24), "scale": (32,), "inside": (16, 16),
+              "branch": (2,), "mixed": (16, 32), "returned": (3,)}
+    return {k: rng.standard_normal(s).astype(np.float32)
+            for k, s in shapes.items()}
+
+
+def test_split_moves_what_the_leaves_alone_determine():
+    """Pads, slices, transposes and the reshapes between them go to the
+    load; a view at the end of a chain, a leaf read with an input, a
+    returned leaf and whatever a ``scan`` / ``cond`` body reads stay."""
+    params = _relaid_params()
+    x = np.random.default_rng(1).standard_normal((8, 16)).astype(np.float32)
+    jitted = jax.jit(_relaid)
+    closed, out_tree, cut = prepare.trace(jitted, params, [x])
+    names = sorted(params)          # the flat order of a dict's leaves
+    moved = [e.primitive.name for e in cut.load.eqns]
+    assert sorted(moved) == ["pad", "reshape", "slice", "slice",
+                             "transpose", "transpose"]
+    assert [names[i] for i in cut.sources] == ["w"]
+    assert [names[i] for i in cut.kept] == [
+        "branch", "inside", "mixed", "returned", "scale"]
+    assert cut.narrowed == {} and cut.equations == 6
+    # the step reads the two re-laid arrays, and re-views the first
+    assert [v.aval.shape for v in cut.load.outvars] == [(4, 8, 16),
+                                                        (4, 2, 16)]
+    left = {e.primitive.name for e in cut.step.eqns}
+    assert "pad" not in left and {"scan", "cond", "reshape"} <= left
+    assert len(cut.step.eqns) == len(closed.jaxpr.eqns) - 6
+    leaves = jax.tree.leaves(params)
+    held = [leaves[i] for i in cut.kept] + prepare.load(cut, leaves)
+    got = jax.jit(prepare.program(closed, out_tree, cut))(held, x)
+    want = jitted(params, x)
+    assert all(np.asarray(a).tobytes() == np.asarray(b).tobytes()
+               for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)))
+
+
+@pytest.mark.parametrize("batch", [1, 4])
+def test_vit_shaped_trace_moves_exactly_its_conversions(batch):
+    """For a flax module the pass finds what PR 27's rule found: the
+    leaves only ever read in bfloat16, their conversions and nothing
+    else (the reshape after a converted bias is a view and stays)."""
+    fw = _open(VIT)
+    closed, _, cut = prepare.trace(jax.jit(fw._apply), fw._params,
+                                   [_frames(batch)])
+    n = len(jax.tree.leaves(fw._params))
+    old = _old_narrowable(closed, n)
+    assert old and set(cut.narrowed) == set(old) == set(cut.sources)
+    leaves = jax.tree.leaves(fw._params)
+    assert cut.narrowed == {i: leaves[i].size * d.itemsize
+                            for i, d in old.items()}
+    assert cut.equations == len(old)
+    assert {e.primitive.name for e in cut.load.eqns} \
+        == {"convert_element_type"}
+    assert not set(cut.kept) & set(cut.sources)
+    fw.close()
 
 
 def test_signatures_share_one_converted_tree(conversions):
@@ -228,12 +368,12 @@ def test_signatures_share_one_converted_tree(conversions):
     fw = _open(VIT)
     fw.invoke([_frames(1)])
     tree = fw._prepared
-    held = jax.tree.leaves(tree)
+    held = list(tree)
     out4 = np.asarray(fw.invoke([_frames(4)])[0])
     assert len(fw._jit_cache) == 2
     assert fw._on_prepared == set(fw._jit_cache)
     assert fw._prepared is tree
-    assert all(a is b for a, b in zip(jax.tree.leaves(fw._prepared), held))
+    assert all(a is b for a, b in zip(fw._prepared, held))
     assert conversions == [fw.prepared_report()["prepared_leaves"]]
     want = np.asarray(jax.jit(fw._apply)(fw._params, _frames(4)))
     assert out4.tobytes() == want.tobytes()
@@ -242,18 +382,14 @@ def test_signatures_share_one_converted_tree(conversions):
 
 def test_signature_that_disagrees_runs_on_loaded_leaves(monkeypatch,
                                                         conversions):
-    """A later program whose trace finds another set must not be handed
-    leaves it would read in float32."""
+    """A later program whose trace is cut otherwise must not be handed
+    results it would not read, nor miss a leaf it reads in float32."""
     fw = _open(VIT)
     fw.invoke([_frames(1)])
-    real = prepare.narrowable
-
-    def fewer(closed, n):
-        out = real(closed, n)
-        out.pop(min(out))
-        return out
-
-    monkeypatch.setattr(prepare, "narrowable", fewer)
+    real = prepare.split
+    # the last leaf passes for an input: its conversion stays
+    monkeypatch.setattr(prepare, "split",
+                        lambda closed, n: real(closed, n - 1))
     got = np.asarray(fw.invoke([_frames(4)])[0])
     sig1, = fw._on_prepared
     assert len(fw._jit_cache) == 2 and sig1[0][0][0] == 1
@@ -289,7 +425,7 @@ def test_replaced_parameters_are_converted_again(conversions, how):
     assert fw._prepared is not old and len(conversions) == 2
     assert fw.prepared_report()["prepared_leaves"] == conversions[0]
     fw.close()
-    assert fw._prepared is None and fw._narrow is None
+    assert fw._prepared is None and fw._cut is None
 
 
 def test_mesh_converted_leaves_keep_their_sharding(tmp_path):
@@ -304,12 +440,13 @@ def test_mesh_converted_leaves_keep_their_sharding(tmp_path):
     out = fw.invoke([x])[0]
     assert len(out.sharding.device_set) == 8
     assert fw.prepared_report()["prepared_leaves"] == 2
-    for name in ("w1", "w2"):
-        src, conv = fw._params[name], fw._prepared[name]
-        assert conv.dtype == jnp.bfloat16
+    scale, *converted = fw._prepared          # the kept leaf, the results
+    for name, conv in zip(("w1", "w2"), converted):
+        src = fw._params[name]
+        assert conv.dtype == jnp.bfloat16 and conv.shape == src.shape
         assert conv.sharding == src.sharding
         assert not src.sharding.is_fully_replicated
-    assert fw._prepared["scale"] is fw._params["scale"]
+    assert scale is fw._params["scale"]
     np.testing.assert_allclose(np.asarray(out), want, rtol=2e-2, atol=2e-2)
     fw.close()
 
@@ -346,14 +483,57 @@ def test_fused_segment_closure_reads_the_converted_tree(conversions):
     fw.close()
 
 
-def test_prepare_span_is_recorded_once_per_load():
+def test_prepare_span_is_recorded_once_per_load(monkeypatch):
     from nnstreamer_tpu.obs import spans
+    said = []
+    real = spans.region
+
+    def region(prof, cat, *args, **meta):
+        said.append((prof, meta))
+        return real(prof, cat, *args, **meta)
+
+    monkeypatch.setattr(spans, "region", region)
     spans.clear()
     fw = _open(VIT)
     fw.invoke([_frames(1)])
     fw.invoke([_frames(4)])
     rows = [s for _, s in spans.snapshot() if s[0] == "nns.filter.prepare"]
     assert len(rows) == 1 and rows[0][1] == "filter"
+    rep = fw.prepared_report()
+    leaves = jax.tree.leaves(fw._params)
+    assert [m for p, m in said if p == "nns.filter.prepare"] == [{
+        "leaves": rep["prepared_leaves"],
+        "equations": rep["prepared_equations"],
+        "bytes_in": sum(leaves[i].nbytes for i in fw._cut.sources),
+        "bytes_out": rep["prepared_bytes"]}]
+    fw.close()
+
+
+def test_relaid_leaves_through_the_filter(tmp_path, conversions):
+    """The toy of ``test_split_moves_...`` as a model file: the same
+    bytes as ``jax.jit`` of its ``apply_fn``, the counters and the
+    span's ``equations=`` pinned, no leaf counted as narrowed."""
+    import inspect
+    body = inspect.getsource(_relaid) + inspect.getsource(_relaid_params)
+    model = tmp_path / "model.py"
+    model.write_text(
+        "import jax\nimport jax.numpy as jnp\nimport numpy as np\n"
+        "from nnstreamer_tpu.tensors.info import TensorsInfo\n" + body
+        + "def get_model():\n    return (_relaid, _relaid_params(), "
+        "TensorsInfo.make('float32', '16:8'), None)\n")
+    fw = _open(str(model))
+    x = np.random.default_rng(1).standard_normal((8, 16)).astype(np.float32)
+    got = fw.invoke([x])
+    assert fw.prepared_report() == {"prepared_leaves": 0,
+                                    "prepared_bytes": 0,
+                                    "prepared_equations": 6,
+                                    "kernel_calls": {}}
+    assert conversions == [1]
+    want = jax.jit(fw._apply)(fw._params, x)
+    assert [np.asarray(a).tobytes() for a in got] \
+        == [np.asarray(b).tobytes() for b in jax.tree.leaves(want)]
+    fw.invoke([x])
+    assert conversions == [1]
     fw.close()
 
 
@@ -369,9 +549,7 @@ def topology():
     return "v5e:2x2"
 
 
-def test_aot_estimate_smoke(topology):
-    """The TPU compiler takes the narrowed program, and it holds no
-    conversion of a float32 kernel that the program as loaded has."""
+def _tool():
     import importlib.util
     import pathlib
     path = pathlib.Path(__file__).resolve().parents[1] / "tools" \
@@ -379,6 +557,13 @@ def test_aot_estimate_smoke(topology):
     spec = importlib.util.spec_from_file_location("aot_estimate", path)
     tool = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(tool)
+    return tool
+
+
+def test_aot_estimate_smoke(topology):
+    """The TPU compiler takes the narrowed program, and it holds no
+    conversion of a float32 kernel that the program as loaded has."""
+    tool = _tool()
     model = "zoo://vit?size=32&patch=8&d_model=128&layers=2&heads=4&classes=10"
     jax.config.update("jax_enable_compilation_cache", False)
     try:
@@ -430,3 +615,62 @@ def test_mosaic_takes_the_masked_attention_kernel(topology, lo, hi, masked,
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
     assert "tpu_custom_call" in text and "nns_masked_attention" in text
+
+
+@pytest.mark.parametrize("model,calls", [
+    ("zoo://longcat?seq=128&v_head_dim=128&held_first=4&held_count=4", 4),
+    ("zoo://glm_dsa?seq=128&v_head_dim=128&held_first=8&held_count=8", 3),
+], ids=["longcat", "glm_dsa"])
+def test_aot_estimate_compiles_an_lm_models_kernel(topology, model, calls):
+    """For a TPU topology the tool hands the attention kernel to Mosaic
+    (this process' backend is the CPU, which the model would answer with
+    the interpreter's loops), and its list of stand-alone moves shows no
+    head-major activation padded or copied on its way to the kernel: q
+    and k leave their products 128 lanes wide."""
+    tool = _tool()
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        with jax.default_matmul_precision("default"):
+            text = tool.compile_text(model, 0, topology)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert text.count('custom_call_target="tpu_custom_call"') == calls
+    assert "nns_masked_attention" in text
+    moves = tool.plain_moves(text)
+    assert all(op in ("copy", "pad") and n > 0
+               for (op, _, _), n in moves.items())
+    assert not [m for m in moves if "[4,128,128]" in m[2]]
+
+
+def test_aot_estimate_lists_the_moves_that_stand_alone():
+    """``plain_moves`` counts a ``copy`` / ``pad`` of the entry or of a
+    loop's body with its operand's shape, and none inside a fusion."""
+    text = textwrap.dedent("""\
+        HloModule jit_nns_filter_toy
+
+        %fused_computation (param_0.1: bf16[8,16]) -> bf16[8,128] {
+          %param_0.1 = bf16[8,16]{1,0} parameter(0)
+          %constant.1 = bf16[] constant(0)
+          ROOT %pad.9 = bf16[8,128]{1,0} pad(%param_0.1, %constant.1), padding=0_0x0_112
+        }
+
+        %body (p: (bf16[8,16])) -> (bf16[8,16]) {
+          %p = (bf16[8,16]{1,0}) parameter(0)
+          %gte = bf16[8,16]{1,0} get-tuple-element(%p), index=0
+          %copy.3 = bf16[8,16]{0,1} copy(%gte)
+          ROOT %tuple = (bf16[8,16]{0,1}) tuple(%copy.3)
+        }
+
+        ENTRY %main (Arg_0.1: bf16[8,16], Arg_1.2: bf16[4,8,16]) -> bf16[8,128] {
+          %Arg_0.1 = bf16[8,16]{1,0} parameter(0)
+          %Arg_1.2 = bf16[4,8,16]{2,1,0} parameter(1)
+          %constant.2 = bf16[] constant(0)
+          %pad.1 = bf16[4,8,128]{2,1,0} pad(%Arg_1.2, %constant.2), padding=0_0x0_0x0_112
+          %pad.2 = bf16[4,8,128]{2,1,0} pad(%Arg_1.2, %constant.2), padding=0_0x0_0x0_112
+          %copy.1 = bf16[8,16]{0,1} copy(%Arg_0.1)
+          ROOT %fusion = bf16[8,128]{1,0} fusion(%Arg_0.1), kind=kLoop, calls=%fused_computation
+        }
+        """)
+    assert _tool().plain_moves(text) == {
+        ("copy", "bf16[8,16]{1,0}", "bf16[8,16]{0,1}"): 2,
+        ("pad", "bf16[4,8,16]{2,1,0}", "bf16[4,8,128]{2,1,0}"): 2}

@@ -176,7 +176,7 @@ def test_mla_is_per_head_attention_over_expanded_keys(block_q):
     for h in range(cfg.num_attention_heads):
         k_h = jnp.concatenate([c_kv @ wkv_b[:, h, :nope], k_r], -1)
         v_h = c_kv @ wkv_b[:, h, nope:]
-        scores = (q[:, h] @ k_h.T) * (nope + rp) ** -0.5
+        scores = (q[:, h, :nope + rp] @ k_h.T) * (nope + rp) ** -0.5
         probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), -1)
         np.testing.assert_allclose(got[:, h], probs @ v_h, atol=2e-5)
 
@@ -190,6 +190,30 @@ def test_rope_interleaved_rotates_pairs():
     np.testing.assert_allclose(
         out[1, 0], [np.cos(1), np.sin(1), -2 * np.sin(0.1),
                     2 * np.cos(0.1)], atol=1e-6)
+
+
+@pytest.mark.parametrize("d,nope,rope", [
+    (16, 12, 4),        # the toy head as published
+    (128, 12, 4),       # the toy head padded to the kernel's lanes
+    (256, 128, 64),     # LongCat's head, 192 wide, padded
+    (256, 192, 64),     # GLM-5's: nothing past the rotation
+])
+def test_rope_columns_rotates_the_roped_columns_alone(d, nope, rope):
+    """``rope`` columns from ``nope`` on are ``rope_interleaved``'s; the
+    ones before and the padding after come back bit for bit (cos 1, sin
+    0), so zero padding stays zero."""
+    from nnstreamer_tpu.models import latent
+    x = jax.random.normal(jax.random.PRNGKey(d + nope), (3, 8, d))
+    x = x.at[..., nope + rope:].set(0.0)
+    pos = jnp.arange(5, 13)
+    got = latent.rope_columns(x, pos, 1e4, nope, rope)
+    assert got.shape == x.shape and got.dtype == x.dtype
+    turned = latent.rope_interleaved(
+        jnp.swapaxes(x[..., nope:nope + rope], 0, 1), pos, 1e4)
+    np.testing.assert_allclose(got[..., nope:nope + rope],
+                               jnp.swapaxes(turned, 0, 1), atol=1e-6)
+    np.testing.assert_array_equal(got[..., :nope], x[..., :nope])
+    assert not np.asarray(got[..., nope + rope:]).any()
 
 
 def test_router_bias_moves_the_choice_not_the_weight():
@@ -503,16 +527,23 @@ def test_block_without_a_query_tile_raises():
         blocked_causal_attention(x, x, x, scale=1.0, block_q=12)
 
 
-@pytest.mark.parametrize("uri,dims,calls", [
-    # 3 layers x 4 blocks of 16 queries (the fixture's BLOCK_Q)
+@pytest.mark.parametrize("uri,dims,calls,equations", [
+    # 3 layers x 4 blocks of 16 queries (the fixture's BLOCK_Q); an
+    # attention's 2 reshapes, 2 slices, 2 pads and 3 transposes
+    # (latent.mla_weights), an expert layer's router bias to float32
     ("zoo://glm_dsa?seq=64&held_first=8&held_count=8",
-     ("int32", "64"), {"nns_masked_attention": 12}),
-    ("zoo://mlp", ("float32", "64:4"), {}),
-], ids=["glm_dsa", "plain_xla"])
-def test_backend_reports_the_kernels_it_calls(uri, dims, calls):
+     ("int32", "64"), {"nns_masked_attention": 12}, 3 * 9 + 2),
+    # in float32 the bias is used as it is loaded
+    ("zoo://glm_dsa?seq=64&held_first=8&held_count=8&dtype=float32",
+     ("int32", "64"), {"nns_masked_attention": 12}, 3 * 9),
+    ("zoo://mlp", ("float32", "64:4"), {}, None),
+], ids=["glm_dsa", "glm_dsa_float32", "plain_xla"])
+def test_backend_reports_the_kernels_it_calls(uri, dims, calls, equations):
     """``kernel_calls`` beside ``prepared_leaves``: the kernel's name
     with its call sites in the traced program, nothing for a model in
-    plain XLA."""
+    plain XLA; ``prepared_equations``: what the load took over of the
+    projections' weights (no leaf held narrower: ``prepared_leaves``
+    0), absent with the rest of the block where nothing is prepared."""
     caps = (f"other/tensors,format=static,num_tensors=1,types=(string)"
             f"{dims[0]},dimensions=(string){dims[1]},framerate=0/1")
     p = parse_launch(f'appsrc name=in caps="{caps}" ! tensor_filter name=f '
@@ -526,4 +557,5 @@ def test_backend_reports_the_kernels_it_calls(uri, dims, calls):
     report = p["f"].transfer_report()
     p.stop()
     assert report["kernel_calls"] == calls
-    assert report["prepared_leaves"] == 0
+    assert report["prepared_leaves"] == report["prepared_bytes"] == 0
+    assert report["prepared_equations"] == (equations or 0)

@@ -321,8 +321,161 @@ def test_pipeline_gives_the_direct_calls_three_tensors(window):
     # 2 layers x 2 attentions x 4 blocks of 16 queries (the fixture's)
     assert report["kernel_calls"] == {"nns_masked_attention": 16}
     assert report.get("prepared_leaves", 0) == 0
+    # an attention's 2 reshapes, 2 slices, 2 pads and 3 transposes
+    # (latent.mla_weights), and a router bias's conversion a layer
+    assert report["prepared_equations"] == 4 * 9 + 2
     assert len(got) == 5
     for g, w in zip(got, want):
         assert [x.dtype for x in g] == [np.float32, np.float32, np.int32]
         for a, b in zip(g, w):
             np.testing.assert_array_equal(a, np.asarray(b))
+
+
+# -- the projections' weights laid out for the kernel (PR 33) -------------
+
+def _glm_cfg(**over):
+    return glm_dsa.GLMDSAConfig(held_first=8, held_count=8,
+                                dtype=jnp.float32, **over)
+
+
+# a decoder's module, its toy configuration (a head 12 + 4 = 16 columns
+# wide: no multiple of the kernel's 128 lanes) and where its first
+# attention sublayer's leaves lie
+DECODERS = {
+    "longcat": (longcat, _cfg, lambda p: p["layers"][0]["sub"][0]["attn"]),
+    "glm_dsa": (glm_dsa, _glm_cfg, lambda p: p["layers"][0]["attn"]),
+}
+
+
+def _plain_mla_qkv(x, a, positions, cfg, *, q_scale=1.0, kv_scale=1.0):
+    """The latent projections the plainest way, a head's columns as
+    published: ``[nope | rope]`` concatenated and nothing padded, the
+    weights multiplied as they are loaded. What ``latent.mla_qkv``
+    returned before it padded a head."""
+    h, nope = cfg.num_attention_heads, cfg.qk_nope_head_dim
+    rp, r = cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    c_q = latent._scaled(latent.rmsnorm(
+        x @ a["wq_a"], a["q_norm"], cfg.rms_norm_eps), q_scale)
+    q = (c_q @ a["wq_b"]).reshape(-1, h, nope + rp)
+    q = jnp.concatenate([q[..., :nope], latent.rope_interleaved(
+        q[..., nope:], positions, cfg.rope_theta)], -1)
+    kv = x @ a["wkv_a"]
+    c_kv = latent._scaled(latent.rmsnorm(
+        kv[:, :r], a["kv_norm"], cfg.rms_norm_eps), kv_scale)
+    k_r = latent.rope_interleaved(kv[:, r:], positions, cfg.rope_theta)
+    kv_h = (c_kv @ a["wkv_b"]).reshape(-1, h, a["wkv_b"].shape[1] // h)
+    k = jnp.concatenate([kv_h[..., :nope], jnp.broadcast_to(
+        k_r[:, None], (x.shape[0], h, rp))], -1)
+    return c_q, q, k, kv_h[..., nope:]
+
+
+@pytest.mark.parametrize("name", sorted(DECODERS))
+def test_mla_qkv_pads_a_head_to_the_lane_width(name):
+    """q and k come out 128 wide, as the attention kernel reads a head:
+    the real 16 columns are the concatenated form's, the 112 past them
+    are zero (zero weight rows, cos 1 and sin 0 in the rotation, nothing
+    added by the roped key part); v is not widened."""
+    model, make, attn = DECODERS[name]
+    cfg = make()
+    a = attn(model.init_params(cfg, jax.random.PRNGKey(11)))
+    x, pos = _hidden(12, cfg), jnp.arange(SEQ)
+    scales = dict(q_scale=2 ** 0.5, kv_scale=2.0) if name == "longcat" else {}
+    got = latent.mla_qkv(x, a, pos, cfg, **scales)
+    want = _plain_mla_qkv(x, a, pos, cfg, **scales)
+    d, heads = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, \
+        cfg.num_attention_heads
+    assert [t.shape for t in got] == [
+        (SEQ, cfg.q_lora_rank), (SEQ, heads, 128), (SEQ, heads, 128),
+        (SEQ, heads, cfg.v_head_dim)]
+    np.testing.assert_allclose(got[0], want[0], atol=1e-6)
+    for mine, plain in zip(got[1:3], want[1:3]):
+        np.testing.assert_allclose(mine[..., :d], plain, atol=2e-6)
+        assert not np.asarray(mine[..., d:]).any()
+    np.testing.assert_allclose(got[3], want[3], atol=2e-6)
+
+
+def test_mla_weights_read_no_input_and_keep_the_published_tree():
+    """What ``mla_qkv`` multiplies by is a function of the attention
+    leaves alone (the jax filter runs it once per load), laid out with
+    the latent dimension last; the tree ``init_params`` makes, which
+    the benchmark's seeds and references share, holds the published
+    shapes."""
+    cfg = _cfg()
+    a = longcat.init_params(
+        cfg, jax.random.PRNGKey(0))["layers"][0]["sub"][0]["attn"]
+    assert a["wq_b"].shape == (32, 4 * 16)
+    assert a["wkv_b"].shape == (16, 4 * (12 + 16))
+    wq, wk, wv = latent.mla_weights(a, cfg)
+    assert (wq.shape, wk.shape, wv.shape) \
+        == ((4, 128, 32), (4, 128, 16), (4, 16, 16))
+    w = np.asarray(a["wkv_b"]).reshape(16, 4, 28)
+    np.testing.assert_array_equal(wq[:, :16], np.asarray(
+        a["wq_b"]).reshape(32, 4, 16).transpose(1, 2, 0))
+    np.testing.assert_array_equal(wk[:, :12], w[..., :12].transpose(1, 2, 0))
+    np.testing.assert_array_equal(wv, w[..., 12:].transpose(1, 2, 0))
+    assert not np.asarray(wq[:, 16:]).any()
+    assert not np.asarray(wk[:, 12:]).any()
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("name", sorted(DECODERS))
+def test_forward_is_the_unpadded_forward(monkeypatch, name, seed):
+    """The whole scoring pass with a head padded equals the pass with
+    the projections in their plain form (the program before PR 33) in
+    float32: zero columns add zero to a score, and the score's scale
+    is the configuration's ``nope + rope``, not the padded width."""
+    model, make, _ = DECODERS[name]
+    monkeypatch.setattr(model, "BLOCK_Q", 16)
+    cfg = make()
+    params = model.init_params(cfg, jax.random.PRNGKey(seed))
+    tokens = _tokens(20 + seed)
+
+    def run():
+        out = jax.jit(lambda p, t: model.forward(p, t[None], cfg))(
+            params, tokens)
+        return [np.asarray(t) for t in out]
+
+    got = run()
+    monkeypatch.setattr(model, "mla_qkv", _plain_mla_qkv)
+    want = run()
+    spread = float(np.ptp(want[0]))
+    np.testing.assert_allclose(got[0], want[0], atol=1e-6 * max(spread, 1))
+    np.testing.assert_allclose(got[1], want[1], atol=1e-6 * max(spread, 1))
+    np.testing.assert_array_equal(got[2], want[2])
+
+
+def _equations(jaxpr):
+    """Every equation of ``jaxpr``, sub-programs' included (``jnp.pad``
+    is a ``jit`` of its own), a Pallas kernel's body apart."""
+    from nnstreamer_tpu.filters import prepare
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in prepare.sub_jaxprs(eqn):
+                yield from _equations(sub)
+
+
+@pytest.mark.parametrize("name", sorted(DECODERS))
+def test_no_activation_is_padded_to_the_lane_width(monkeypatch, name):
+    """Read off the jaxpr, as ``kernel_calls`` is: with values a lane
+    multiple wide (as both cells have them) the traced program pads no
+    ``[H, S, D]`` array's columns on its way to the kernel; the plain
+    form pads q and k in every attention."""
+    model, make, _ = DECODERS[name]
+    cfg = make(v_head_dim=128)
+    shapes = jax.eval_shape(
+        lambda: model.init_params(cfg, jax.random.PRNGKey(0)))
+
+    def widened():
+        closed = jax.make_jaxpr(lambda p, t: model.forward(p, t[None], cfg))(
+            shapes, jax.ShapeDtypeStruct((SEQ,), jnp.int32))
+        return [e for e in _equations(closed.jaxpr)
+                if e.primitive.name == "pad"
+                and e.invars[0].aval.shape[:2] == (cfg.num_attention_heads,
+                                                   SEQ)
+                and e.outvars[0].aval.shape[2] > e.invars[0].aval.shape[2]]
+
+    assert widened() == []
+    monkeypatch.setattr(model, "mla_qkv", _plain_mla_qkv)
+    attentions = 4 if name == "longcat" else cfg.num_hidden_layers
+    assert len(widened()) == 2 * attentions
