@@ -18,7 +18,8 @@ absorbed form belongs to a decode path and is not built here):
   dim)``, float32; ``sel(t)`` = the ``index_topk`` largest ``I[t, s<=t]``,
   all of them while ``t < index_topk``, ties to the lower index
   (``ops/sparse_attention.py``).
-* **Router** (``noaux_tc``, one group). ``s = sigmoid(x W_g)`` float32
+* **Router** (``latent.sigmoid_route``: ``noaux_tc``, one group). ``s
+  = sigmoid(x W_g)`` float32
   over the whole router; the ``top`` largest of ``s + b`` are chosen;
   weights ``s_e / (sum_chosen s + 1e-20) x routed_scaling_factor``, the
   sum over every chosen expert, held or not. The layer's output is ``x
@@ -54,7 +55,7 @@ from ..ops.grouped import group_by_expert, grouped_swiglu
 from ..ops.sparse_attention import topk_mask
 from . import latent
 from .latent import (BLOCK_Q, EXPERT_TILE, _mm, causal_attention_out,
-                     mla_qkv, rope_interleaved, swiglu)
+                     mla_qkv, rope_interleaved, sigmoid_route, swiglu)
 from .transformer import rmsnorm
 from .zoo import register_model
 
@@ -226,22 +227,9 @@ def attend(h, layer, cfg: GLMDSAConfig):
                 return topk_mask(scores, cfg.index_topk, causal)
 
     return h + causal_attention_out(
-        q, k, v, layer["attn"]["wo"], cfg, block_q=BLOCK_Q, key_mask=selected,
+        q, k, v, layer["attn"]["wo"], block_q=BLOCK_Q, key_mask=selected,
+        scale=(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5,
         scope="block/attn")
-
-
-def route(x, moe, cfg: GLMDSAConfig):
-    """``x`` [T, d] -> ``(choice int32 [T, top], weight float32 [T,
-    top])`` over the whole router. The bias moves the choice and not
-    the weight."""
-    s = jax.nn.sigmoid(jnp.dot(x, moe["gate"],
-                               preferred_element_type=jnp.float32))
-    _, choice = jax.lax.top_k(s + moe["bias"].astype(jnp.float32),
-                              cfg.num_experts_per_tok)
-    picked = jnp.take_along_axis(s, choice, axis=-1)
-    weight = picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20) \
-        * cfg.routed_scaling_factor
-    return choice.astype(jnp.int32), weight
 
 
 def moe_ffn(h, layer, cfg: GLMDSAConfig):
@@ -251,7 +239,8 @@ def moe_ffn(h, layer, cfg: GLMDSAConfig):
     moe = layer["moe"]
     with jax.named_scope("block/moe/route"):
         x = rmsnorm(h, layer["ffn_norm"], cfg.rms_norm_eps)
-        choice, weight = route(x, moe, cfg)
+        choice, weight = sigmoid_route(x, moe, cfg.num_experts_per_tok,
+                                       cfg.routed_scaling_factor)
         order, load = group_by_expert(choice, cfg.held_first, cfg.held)
     with jax.named_scope("block/moe/shared"):
         out = swiglu(x, moe["shared"])

@@ -1,9 +1,10 @@
-"""Parts the latent-attention decoders share (``models/glm_dsa.py``,
-``models/longcat.py``): rotary embedding on interleaved pairs, the
-latent (MLA) projections in the expanded form, causal attention over
-them with the output projection, a SwiGLU MLP, the scoring head, and
-the frame and zoo wrappers of a scoring pass. A decoder's own file
-holds what is its own: the layer's order, its router, an indexer.
+"""Parts the scoring decoders share (``models/glm_dsa.py``,
+``models/longcat.py``, ``models/afmoe.py``): rotary embedding on
+interleaved pairs and the latent (MLA) projections in the expanded form
+(the two latent-attention decoders'), causal attention with the output
+projection, a SwiGLU MLP, the sigmoid router, the scoring head, and the
+frame and zoo wrappers of a scoring pass. A decoder's own file holds
+what is its own: the layer's order, its projections, an indexer.
 
 The configuration object a function takes is the caller's dataclass;
 only the fields named in the function's docstring are read, by the HF
@@ -173,24 +174,44 @@ def mla_qkv(x, a, positions, cfg, *, q_scale: float = 1.0,
 
 
 def causal_attention_out(
-        q, k, v, wo, cfg, *, block_q: int, scope: str,
+        q, k, v, wo, *, scale: float, block_q: int, scope: str,
         key_mask: Optional[Callable[[int, int],
-                                    Optional[jax.Array]]] = None):
-    """Softmax attention of ``q`` over the keys at or before each query
-    (``key_mask`` may narrow them, ``ops/sparse_attention.py``), scores
-    over ``sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)`` (not
-    ``q``'s width: :func:`mla_qkv` pads a head), then the output
-    projection ``wo`` [H * v, d]: -> [S, d] in ``q``'s dtype. ``scope``
-    names the operations in a trace."""
+                                    Optional[jax.Array]]] = None,
+        window: Optional[int] = None, gate=None):
+    """Softmax attention of ``q`` [S, H, Dk] over the keys at or before
+    each query (``key_mask`` or a ``window`` may narrow them, ``k`` and
+    ``v`` may have fewer heads: ``ops/sparse_attention.py``), scores
+    times ``scale`` (the caller's, from the head's published size: a
+    head may be padded), the result times ``sigmoid(gate)`` [S, H, Dv]
+    where a gate is given, then the output projection ``wo`` [H * v,
+    d]: -> [S, d] in ``q``'s dtype. ``scope`` names the operations in a
+    trace."""
     o = blocked_causal_attention(
-        q, k, v, scale=(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5,
-        block_q=block_q, key_mask=key_mask, scope=scope)
+        q, k, v, scale=scale, block_q=block_q, key_mask=key_mask,
+        window=window, scope=scope)
     with jax.named_scope(scope):
+        if gate is not None:
+            o = (o.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))).astype(o.dtype)
         # over (head, v) as the attention wrote them: no [S, H * v] copy
         wo = wo.reshape(o.shape[1], o.shape[2], -1)
         return jnp.einsum("shv,hvd->sd", o, wo,
                           preferred_element_type=jnp.float32
                           ).astype(q.dtype)
+
+
+def sigmoid_route(x, moe, top: int, scale: float):
+    """``x`` [T, d] -> ``(choice int32 [T, top], weight float32 [T,
+    top])`` over the whole router (``noaux_tc``, one group): ``s =
+    sigmoid(x W_g)`` float32; the ``top`` largest of ``s + b`` are
+    chosen (the bias moves the choice and not the weight); weights
+    ``s_e / (sum over the chosen s + 1e-20) * scale``."""
+    s = jax.nn.sigmoid(jnp.dot(x, moe["gate"],
+                               preferred_element_type=jnp.float32))
+    _, choice = jax.lax.top_k(s + moe["bias"].astype(jnp.float32), top)
+    picked = jnp.take_along_axis(s, choice, axis=-1)
+    weight = picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20) * scale
+    return choice.astype(jnp.int32), weight
 
 
 def score(h, params, tokens, eps: float):

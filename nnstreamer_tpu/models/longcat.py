@@ -192,8 +192,9 @@ def attend(h, sub, cfg: LongCatConfig):
         x = rmsnorm(h, sub["attn_norm"], cfg.rms_norm_eps)
         _, q, k, v = mla_qkv(x, sub["attn"], positions, cfg,
                              q_scale=cfg.q_scale, kv_scale=cfg.kv_scale)
-    return h + causal_attention_out(q, k, v, sub["attn"]["wo"], cfg,
-                                    block_q=BLOCK_Q, scope="block/attn")
+    return h + causal_attention_out(
+        q, k, v, sub["attn"]["wo"], block_q=BLOCK_Q, scope="block/attn",
+        scale=(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5)
 
 
 def route(x, moe, cfg: LongCatConfig):

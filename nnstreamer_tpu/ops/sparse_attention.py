@@ -18,7 +18,14 @@ not have:
   whole; ``ops/attention.py`` is non-causal and holds a whole score
   block in VMEM, so it stops at S ~ 1024). Key tiles that lie wholly
   after a query tile are not visited; inside the tile on the diagonal
-  the mask does the rest. ``key_mask(lo, hi)`` may narrow each query's
+  the mask does the rest. A ``window`` keeps keys ``t - window < s <=
+  t`` only: a query tile's walk then starts at the first key tile its
+  window reaches (the tiles wholly behind it are no grid step at all),
+  the tiles at the two edges are masked from iotas and those between
+  run the unmasked body. ``k`` and ``v`` may have fewer heads than
+  ``q`` (grouped queries): query head ``h`` reads key/value head ``h
+  // (H / H_kv)``, whose tiles the index map names, so nothing is
+  repeated in HBM. ``key_mask(lo, hi)`` may narrow each query's
   keys further (the indexer's selection), as one int8 tile set shared
   by all heads: a dropped pair inside a visited tile is computed and
   discarded, not gathered away - the gathered form is the long-context
@@ -73,15 +80,21 @@ def topk_mask(scores, k: int, valid):
 
 def reference_blocked_attention(
         q, k, v, *, scale: float, block_q: int,
-        key_mask: Optional[Callable[[int, int], Optional[jax.Array]]] = None):
+        key_mask: Optional[Callable[[int, int], Optional[jax.Array]]] = None,
+        window: Optional[int] = None):
     """:func:`blocked_causal_attention` in plain XLA, a block's
-    ``[H, block, hi]`` float32 scores and weights passing through HBM:
+    ``[H, block, hi]`` float32 scores and weights passing through HBM
+    and a shared key/value head repeated for each of its query heads:
     the parity oracle of the tests."""
     s = q.shape[0]
+    k, v = (jnp.repeat(x, q.shape[1] // x.shape[1], axis=1) for x in (k, v))
     outs = []
     for lo in range(0, s, block_q):
         hi = min(lo + block_q, s)
-        keep = jnp.arange(lo, hi)[:, None] >= jnp.arange(hi)[None, :]
+        ahead = jnp.arange(lo, hi)[:, None] - jnp.arange(hi)[None, :]
+        keep = ahead >= 0
+        if window is not None:
+            keep &= ahead < window
         extra = key_mask(lo, hi) if key_mask is not None else None
         if extra is not None:
             keep = keep & extra
@@ -114,11 +127,25 @@ LANES = 128
 DROPPED = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
+def _first_tile(row0, window: Optional[int], tk: int):
+    """The first key tile that a query tile starting at row ``row0``
+    walks: tile 0, or under a ``window`` the tile of the first key its
+    first query keeps. Python ints in, an int out; a traced row in the
+    kernel and the index maps."""
+    if window is None:
+        return 0
+    behind = row0 - window + 1
+    return (max(behind, 0) if isinstance(behind, int)
+            else jnp.maximum(behind, 0)) // tk
+
+
 def _attention_kernel(*refs, scale: float, lo: int, tq: int, tk: int,
-                      masked: bool):
+                      masked: bool, window: Optional[int]):
     """Grid ``(head group, query tile, key tile)``, the key tiles in
-    turn: one step of the running softmax of ``tq`` queries over ``tk``
-    keys, for each head of the group. The running maximum and sum are
+    turn from the query tile's first (:func:`_first_tile`): one step of
+    the running softmax of ``tq`` queries over ``tk`` keys, for each
+    head of the group (the heads of a group that share a key/value head
+    read the one tile held). The running maximum and sum are
     kept across all lanes (``[tq, LANES]``), so that taking them off a
     score tile repeats whole registers and broadcasts no lane."""
     from jax.experimental import pallas as pl
@@ -128,7 +155,11 @@ def _attention_kernel(*refs, scale: float, lo: int, tq: int, tk: int,
     o_ref, m_ref, l_ref, acc_ref = refs[-4:]
     i, j = pl.program_id(1), pl.program_id(2)
     row0 = lo + i * tq          # the tile's first query, as a key index
+    # ... and this step's first key: the walk starts where the window does
+    col0 = j * tk if window is None \
+        else (_first_tile(row0, window, tk) + j) * tk
     group, _, dv = acc_ref.shape
+    shared = group // k_ref.shape[0]    # query heads a key/value head
 
     @pl.when(j == 0)
     def _():
@@ -139,7 +170,7 @@ def _attention_kernel(*refs, scale: float, lo: int, tq: int, tk: int,
     def step(keep):
         for g in range(group):
             scores = jax.lax.dot_general(
-                q_ref[g], k_ref[g], (((1,), (1,)), ((), ())),
+                q_ref[g], k_ref[g // shared], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
             if keep is not None:
                 scores = jnp.where(keep, scores, DROPPED)
@@ -150,24 +181,30 @@ def _attention_kernel(*refs, scale: float, lo: int, tq: int, tk: int,
             m_ref[g] = m_next
             l_ref[g] = alpha * l_ref[g] + jnp.sum(weights, -1, keepdims=True)
             acc_ref[g] = jnp.tile(alpha, (1, dv // LANES)) * acc_ref[g] \
-                + jnp.dot(weights.astype(v_ref.dtype), v_ref[g],
+                + jnp.dot(weights.astype(v_ref.dtype), v_ref[g // shared],
                           preferred_element_type=jnp.float32)
 
     # a key tile wholly after the tile's last query is not visited
-    visited = j * tk <= row0 + tq - 1
+    visited = col0 <= row0 + tq - 1
     if masked:
         pl.when(visited)(lambda: step(keep_ref[...] != 0))
     else:
-        # only a tile that reaches past the first query needs the mask
-        diagonal = (j + 1) * tk - 1 > row0
+        # only a tile that reaches past the first query needs the mask,
+        # or one that starts behind the last query's window
+        edge = col0 + tk - 1 > row0
+        if window is not None:
+            edge |= col0 <= row0 + tq - 1 - window
 
-        @pl.when(visited & diagonal)
+        @pl.when(visited & edge)
         def _():
             rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
-            cols = j * tk + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
-            step(rows >= cols)
+            cols = col0 + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
+            keep = rows >= cols
+            if window is not None:
+                keep &= rows - cols < window
+            step(keep)
 
-        pl.when(visited & jnp.logical_not(diagonal))(lambda: step(None))
+        pl.when(visited & jnp.logical_not(edge))(lambda: step(None))
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _():
@@ -178,29 +215,45 @@ def _attention_kernel(*refs, scale: float, lo: int, tq: int, tk: int,
 
 
 def _attend_block(q, k, v, keep, out, *, lo: int, hi: int, tq: int, tk: int,
-                  scale: float, interpret: bool):
+                  scale: float, interpret: bool,
+                  window: Optional[int] = None):
     """Queries ``[lo, hi)`` of ``q`` [H, S, Dk] over keys ``[0, hi)`` of
-    ``k`` / ``v`` [H, S, D], written into rows ``[lo, hi)`` of ``out``
-    [H, S, Dv] in place; ``out`` None makes the array. ``keep`` int8
-    ``[nq*tq, nk*tk]`` or None for a causal block."""
+    ``k`` / ``v`` [H_kv, S, D] (under a ``window`` over the last
+    ``window`` of them a query), written into rows ``[lo, hi)`` of
+    ``out`` [H, S, Dv] in place; ``out`` None makes the array. ``keep``
+    int8 ``[nq*tq, nk*tk]`` or None for a causal block."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     heads, rows, dk = q.shape
     dv = v.shape[2]
-    group = max(g for g in range(1, HEADS_PER_STEP + 1) if heads % g == 0)
+    shared = heads // k.shape[0]        # query heads a key/value head
+    # the heads of a grid step lie inside one key/value head's group,
+    # or each has its own
+    group = max(g for g in range(1, HEADS_PER_STEP + 1) if heads % g == 0
+                and (shared == 1 or shared % g == 0))
+    kv_group = group if shared == 1 else 1
     nq, nk, first = -(-(hi - lo) // tq), -(-hi // tk), lo // tq
+    if window is not None:
+        # key tiles a query tile walks, from its window's first to its
+        # diagonal: the grid holds the longest walk and no step before it
+        nk = max((lo + (i + 1) * tq - 1) // tk
+                 - _first_tile(lo + i * tq, window, tk) + 1
+                 for i in range(nq))
 
     def query_tile(h, i, j):
         return h, first + i, 0
 
     def key_tile(h, i, j):
         # an unvisited step names the tile it already holds: no copy
-        return h, jnp.minimum(j, (lo + (i + 1) * tq - 1) // tk), 0
+        if window is not None:
+            j = j + _first_tile(lo + i * tq, window, tk)
+        return (h if shared == 1 else h * group // shared,
+                jnp.minimum(j, (lo + (i + 1) * tq - 1) // tk), 0)
 
     in_specs = [pl.BlockSpec((group, tq, dk), query_tile),
-                pl.BlockSpec((group, tk, dk), key_tile),
-                pl.BlockSpec((group, tk, dv), key_tile)]
+                pl.BlockSpec((kv_group, tk, dk), key_tile),
+                pl.BlockSpec((kv_group, tk, dv), key_tile)]
     operands = [q, k, v]
     if keep is not None:
         in_specs.append(pl.BlockSpec(
@@ -213,7 +266,7 @@ def _attend_block(q, k, v, keep, out, *, lo: int, hi: int, tq: int, tk: int,
         operands.append(out)
     return pl.pallas_call(
         functools.partial(_attention_kernel, scale=scale, lo=lo, tq=tq,
-                          tk=tk, masked=keep is not None),
+                          tk=tk, masked=keep is not None, window=window),
         out_shape=jax.ShapeDtypeStruct((heads, rows, dv), v.dtype),
         grid=(heads // group, nq, nk),
         in_specs=in_specs,
@@ -232,10 +285,13 @@ def _attend_block(q, k, v, keep, out, *, lo: int, hi: int, tq: int, tk: int,
 def blocked_causal_attention(
         q, k, v, *, scale: float, block_q: int,
         key_mask: Optional[Callable[[int, int], Optional[jax.Array]]] = None,
-        scope: Optional[str] = None):
-    """``q`` [S, H, Dk], ``k`` [S, H, Dk], ``v`` [S, H, Dv] -> [S, H, Dv].
+        window: Optional[int] = None, scope: Optional[str] = None):
+    """``q`` [S, H, Dk], ``k`` [S, H_kv, Dk], ``v`` [S, H_kv, Dv] ->
+    [S, H, Dv]; ``H_kv`` divides ``H`` and query head ``h`` reads
+    key/value head ``h // (H / H_kv)``.
 
-    Query block ``[lo, hi)`` attends keys ``[0, hi)`` with ``s <= t``;
+    Query block ``[lo, hi)`` attends keys ``[0, hi)`` with ``s <= t``,
+    and under a ``window`` with ``t - window < s``;
     ``key_mask(lo, hi)`` returns bool ``[hi - lo, hi]`` (True = keep) or
     None for a block it leaves causal; every query must keep at least
     one key. Scores and softmax statistics in float32, the weights go to
@@ -252,6 +308,11 @@ def blocked_causal_attention(
     width. A ``block_q`` with no such divisor raises, as Mosaic does
     for a tile it cannot lay out."""
     s = q.shape[0]
+    if q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]:
+        raise ValueError(f"{q.shape[1]} query heads over {k.shape[1]} key "
+                         f"and {v.shape[1]} value heads")
+    if window is not None and window < 1:
+        raise ValueError(f"window={window} keeps no key")
     tq = max((t for t in range(8, min(block_q, TILE_Q) + 1, 8)
               if block_q % t == 0), default=0)
     if not tq:
@@ -279,10 +340,14 @@ def blocked_causal_attention(
             if keep is not None:
                 # the selection and the causal rule as one tile set
                 keep &= jnp.arange(lo, hi)[:, None] >= jnp.arange(hi)[None]
+                if window is not None:
+                    keep &= jnp.arange(lo, hi)[:, None] \
+                        - jnp.arange(hi)[None] < window
                 keep = jnp.pad(keep.astype(jnp.int8), (
                     (0, _round_up(hi - lo, tq) - (hi - lo)),
                     (0, _round_up(hi, tk) - hi)))
             out = _attend_block(qh, kh, vh, keep, out, lo=lo, hi=hi, tq=tq,
-                                tk=tk, scale=scale, interpret=interpret)
+                                tk=tk, scale=scale, interpret=interpret,
+                                window=window)
     with named():
         return jnp.transpose(out, (1, 0, 2))[:s, :, :v.shape[2]]

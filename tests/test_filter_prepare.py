@@ -617,10 +617,54 @@ def test_mosaic_takes_the_masked_attention_kernel(topology, lo, hi, masked,
     assert "tpu_custom_call" in text and "nns_masked_attention" in text
 
 
+@pytest.mark.parametrize("lo,hi,window,tiles", [(3584, 4096, 2048, 5),
+                                                (2048, 2560, 2048, 5),
+                                                (3584, 4096, None, 8)],
+                         ids=["window_last", "window_first_behind", "full"])
+def test_mosaic_takes_the_window_walk_over_shared_heads(topology, lo, hi,
+                                                        window, tiles):
+    """The TPU's compiler lays out ``nns_masked_attention`` at the
+    Trinity-Mini cell's widths: 32 query heads of 128 on 4 key/value
+    heads (``[4, S, 128]`` operands, nothing repeated), S = 4096, the
+    module's own tiles, a window of 2048: the grid holds the key tiles
+    a query tile walks and none behind its window (5 where the full
+    layer's last block walks 8)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from nnstreamer_tpu.ops import sparse_attention as sa
+    dev = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name=topology).devices[0])
+
+    def spec(heads):
+        return jax.ShapeDtypeStruct((heads, 4096, 128), jnp.bfloat16,
+                                    sharding=dev)
+
+    def block(q, k, v, out):
+        return sa._attend_block(q, k, v, None, out, lo=lo, hi=hi,
+                                tq=sa.TILE_Q, tk=sa.TILE_K, scale=128 ** -0.5,
+                                interpret=False, window=window)
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        with jax.default_matmul_precision("default"):
+            lowered = jax.jit(block).lower(spec(32), spec(4), spec(4),
+                                           spec(32))
+            text = lowered.compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert "tpu_custom_call" in text and "nns_masked_attention" in text
+    shapes = [jax.ShapeDtypeStruct((h, 4096, 128), jnp.bfloat16)
+              for h in (32, 4, 4, 32)]
+    call, = [e for e in jax.make_jaxpr(block)(*shapes).eqns
+             if e.primitive.name == "pallas_call"]
+    assert tuple(call.params["grid_mapping"].grid) == (16, 1, tiles)
+
+
 @pytest.mark.parametrize("model,calls", [
     ("zoo://longcat?seq=128&v_head_dim=128&held_first=4&held_count=4", 4),
     ("zoo://glm_dsa?seq=128&v_head_dim=128&held_first=8&held_count=8", 3),
-], ids=["longcat", "glm_dsa"])
+    ("zoo://afmoe?seq=128&head_dim=128&held_first=4&held_count=4", 4),
+], ids=["longcat", "glm_dsa", "afmoe"])
 def test_aot_estimate_compiles_an_lm_models_kernel(topology, model, calls):
     """For a TPU topology the tool hands the attention kernel to Mosaic
     (this process' backend is the CPU, which the model would answer with

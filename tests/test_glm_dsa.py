@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from nnstreamer_tpu import Buffer, parse_launch
-from nnstreamer_tpu.models import glm_dsa, zoo
+from nnstreamer_tpu.models import glm_dsa, latent, zoo
 from nnstreamer_tpu.ops import sparse_attention
 from nnstreamer_tpu.ops.grouped import group_by_expert, grouped_swiglu
 from nnstreamer_tpu.ops.sparse_attention import (
@@ -202,7 +202,6 @@ def test_rope_columns_rotates_the_roped_columns_alone(d, nope, rope):
     """``rope`` columns from ``nope`` on are ``rope_interleaved``'s; the
     ones before and the padding after come back bit for bit (cos 1, sin
     0), so zero padding stays zero."""
-    from nnstreamer_tpu.models import latent
     x = jax.random.normal(jax.random.PRNGKey(d + nope), (3, 8, d))
     x = x.at[..., nope + rope:].set(0.0)
     pos = jnp.arange(5, 13)
@@ -222,20 +221,18 @@ def test_router_bias_moves_the_choice_not_the_weight():
     weights 0.9 / 1.7 x 2.5 and 0.8 / 1.7 x 2.5. A bias of 0.15 on
     expert 2 lifts it over expert 1 (0.85 > 0.8); the weights are still
     of the plain scores: 0.9 / 1.6 x 2.5 and 0.7 / 1.6 x 2.5."""
-    cfg = glm_dsa.GLMDSAConfig(hidden_size=4, n_routed_experts=4,
-                               num_experts_per_tok=2)
     s = np.asarray([0.9, 0.8, 0.7, 0.1])
     gate = np.zeros((4, 4), np.float32)
     gate[0] = np.log(s / (1 - s))
     x = jnp.asarray([[1.0, 0, 0, 0]], jnp.float32)
-    choice, weight = glm_dsa.route(
-        x, {"gate": jnp.asarray(gate), "bias": jnp.zeros(4)}, cfg)
+    choice, weight = latent.sigmoid_route(
+        x, {"gate": jnp.asarray(gate), "bias": jnp.zeros(4)}, 2, 2.5)
     assert choice.tolist() == [[0, 1]]
     np.testing.assert_allclose(weight, [[0.9 / 1.7 * 2.5, 0.8 / 1.7 * 2.5]],
                                rtol=1e-6)
     bias = jnp.asarray([0.0, 0.0, 0.15, 0.0])
-    choice, weight = glm_dsa.route(
-        x, {"gate": jnp.asarray(gate), "bias": bias}, cfg)
+    choice, weight = latent.sigmoid_route(
+        x, {"gate": jnp.asarray(gate), "bias": bias}, 2, 2.5)
     assert choice.tolist() == [[0, 2]]
     np.testing.assert_allclose(weight, [[0.9 / 1.6 * 2.5, 0.7 / 1.6 * 2.5]],
                                rtol=1e-6)
@@ -294,6 +291,44 @@ def test_grouped_swiglu_is_exact(tile, held_first, held):
     np.testing.assert_allclose(got, want, atol=1e-3, rtol=1e-4)
     assert counts.tolist() == [(choice == e).sum()
                                for e in range(held_first, held_first + held)]
+
+
+@pytest.mark.parametrize("whole", [True, False], ids=["sorted", "loops"])
+@pytest.mark.parametrize("tile,dtype,tol", [(8, jnp.float32, 1e-3),
+                                            (32, jnp.float32, 1e-3),
+                                            (16, jnp.bfloat16, 0.15)])
+def test_grouped_swiglu_holds_the_whole_router(tile, dtype, tol, whole):
+    """``held_count`` equal to the router's width: every one of the ``T
+    x K`` pairs is served here, none goes to the tail, and the result is
+    the dense form's (every expert over every token, weighted by the
+    token's weight for it or 0), from the tile loops and from the
+    sorted form (``whole``: the rows gathered once, ``ragged_dot``,
+    gathered back) alike; an expert nobody chose breaks neither."""
+    rng = np.random.default_rng(tile)
+    t, d, f, k, router = 96, 16, 24, 4, 12
+    x = rng.standard_normal((t, d)).astype(np.float32)
+    choice = np.stack([rng.permutation(router)[:k] for _ in range(t)]
+                      ).astype(np.int32)
+    weight = rng.random((t, k)).astype(np.float32)
+    w1, w3 = (rng.standard_normal((router, d, f)).astype(np.float32)
+              for _ in range(2))
+    w2 = rng.standard_normal((router, f, d)).astype(np.float32)
+    choice = np.where(choice == 5, 11, choice)     # expert 5 serves nobody
+    order, counts = group_by_expert(jnp.asarray(choice), 0, router)
+    assert int(counts.sum()) == t * k and int(counts[5]) == 0
+    assert sorted(np.asarray(order).tolist()) == list(range(t * k))
+    got = jax.jit(lambda *a: grouped_swiglu(*a, tile=tile, whole=whole))(
+        *(jnp.asarray(a, dtype) for a in (x,)), order, counts, weight,
+        *(jnp.asarray(a, dtype) for a in (w1, w3, w2)))
+    assert got.dtype == jnp.float32
+    dense = np.zeros((t, router), np.float32)
+    np.add.at(dense, (np.arange(t)[:, None], choice), weight)
+    every = jnp.einsum("tef,efd->ted", jax.nn.silu(
+        jnp.einsum("td,edf->tef", x, w1)) * jnp.einsum("td,edf->tef", x, w3),
+        w2)
+    want = jnp.einsum("ted,te->td", every, dense)
+    np.testing.assert_allclose(got, want, atol=tol * (1 + 9 * (
+        dtype == jnp.bfloat16)), rtol=tol)
 
 
 def _choice(case, rng, t, k, tile, held_first, held, router):
@@ -519,6 +554,70 @@ def test_masked_attention_kernel_is_the_plain_block(monkeypatch, case, s,
             *(x.astype(jnp.float32) for x in (q, k, v)), scale=0.3,
             block_q=block_q, key_mask=mask)
         np.testing.assert_allclose(got, exact, atol=3e-2)
+
+
+@pytest.mark.parametrize("window", [None, 40, 200, 1000],
+                         ids=["causal", "under_a_tile", "across_tiles",
+                              "over_s"])
+@pytest.mark.parametrize("shared", [1, 2, 8])
+def test_attention_kernel_walks_a_window_over_shared_heads(monkeypatch,
+                                                           shared, window):
+    """``nns_masked_attention`` with ``H / H_kv`` query heads a
+    key/value head and a ``window``, against the block in plain XLA
+    with the key/value heads repeated: no window, one smaller than a
+    key tile (both edges in one tile), one that is no multiple of a
+    tile (whole tiles behind it are no grid step, the first visited is
+    masked, those between are not), one longer than the sequence; S a
+    multiple of neither tile."""
+    monkeypatch.setattr(sparse_attention, "TILE_Q", 32)
+    monkeypatch.setattr(sparse_attention, "TILE_K", 128)
+    s, heads, block_q = 420, 8, 96
+    rng = np.random.default_rng(shared + (window or 0))
+    q = jnp.asarray(rng.standard_normal((s, heads, 24)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((s, heads // shared, 24)),
+                    jnp.float32)
+    v = jnp.asarray(rng.standard_normal((s, heads // shared, 16)),
+                    jnp.float32)
+    got = blocked_causal_attention(q, k, v, scale=0.3, block_q=block_q,
+                                   window=window)
+    want = reference_blocked_attention(q, k, v, scale=0.3, block_q=block_q,
+                                       window=window)
+    assert got.shape == want.shape == (s, heads, 16)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    if window is not None and window < s:
+        # and the window is live: the causal block differs
+        causal = reference_blocked_attention(q, k, v, scale=0.3,
+                                             block_q=block_q)
+        assert float(jnp.abs(causal - want).max()) > 1e-2
+
+
+def test_attention_window_narrows_a_selection_and_odd_groups_raise():
+    """A ``key_mask`` under a ``window`` keeps the pairs both keep (one
+    int8 tile set, the walk still starts at the window's first tile);
+    key/value heads that do not divide the query heads, and a window
+    that keeps nothing, raise."""
+    s, block_q, window = 300, 64, 150
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.standard_normal((s, 6, 24)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((s, 2, 24)), jnp.float32)
+            for _ in range(2))
+    keep = (rng.random((s, s)) < 0.5) | np.eye(s, dtype=bool)
+
+    def key_mask(lo, hi):
+        return None if lo == 0 else jnp.asarray(keep[lo:hi, :hi])
+
+    got = blocked_causal_attention(q, k, v, scale=0.3, block_q=block_q,
+                                   key_mask=key_mask, window=window)
+    want = reference_blocked_attention(q, k, v, scale=0.3, block_q=block_q,
+                                       key_mask=key_mask, window=window)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    with pytest.raises(ValueError, match="query heads"):
+        blocked_causal_attention(q, k[:, :1].repeat(4, 1), v, scale=1.0,
+                                 block_q=block_q)
+    with pytest.raises(ValueError, match="window=0"):
+        blocked_causal_attention(q, k, v, scale=1.0, block_q=block_q,
+                                 window=0)
 
 
 def test_block_without_a_query_tile_raises():
