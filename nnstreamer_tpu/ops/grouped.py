@@ -19,14 +19,31 @@ of the rows sorted by expert, which must be sized for the worst routing,
 served when a chip holds a sixteenth of the router. **When every expert
 of the router is held** (``whole``), ``T x K`` is exact: every pair is
 served here and none belongs to another chip. Then the rows are
-gathered once in expert order, three ``ragged_dot`` s run over the
-whole buffer (the TPU compiler has a grouped-product kernel of its own
-for them), and the results go back to pair order by one more gather,
-where a token's ``K`` rows lie side by side and are weighted and
-summed: no scatter-add, no loop turn. Read on the chip at 128 experts
-of 2048 x 1024 and 32,768 pairs a layer (PERF.md, PR 34): the loops'
-scatter-add into the ``[4096, 2048]`` float32 sum alone took 9.4 ms a
-layer, their whole 16.8; the sorted form 13.
+gathered once in expert order, one Pallas TPU kernel,
+``nns_grouped_swiglu`` (:func:`_sorted_swiglu`), runs the three
+products and ``silu(gate) * up`` between them over the whole buffer,
+and the results go back to pair order by one more gather, where a
+token's ``K`` rows lie side by side and are weighted and summed: no
+scatter-add, no loop turn. The kernel walks the buffer in tiles of
+``tile`` rows, a grid step an (expert, row tile) pair that share rows
+(:func:`_walk`, prefetched scalars): a tile that straddles experts is
+visited once for each with the others' rows masked at the store, an
+expert nobody chose is never visited, and consecutive tiles of one
+expert keep its three matrices in VMEM, so each is read from HBM once.
+``gate``, ``up`` and their product never exist in HBM. Compiled by
+Mosaic on a TPU, through the Pallas interpreter elsewhere (how the CPU
+tests run it). Read on the chip at 128 experts of 2048 x 1024 and
+32,768 pairs a layer (PERF.md, PR 34 and PR 35), ms a layer: the
+loops' whole 16.8 (their scatter-add into the ``[4096, 2048]`` float32
+sum alone 9.4); three ``lax.ragged_dot`` s and the fusion between them
+(the TPU compiler's own grouped-product kernel, at 24 % of the MXU's
+peak) 9.07 alone and 9.4 in the program; JAX's bundled ``megablox.gmm``
+three times 6.25 alone at its best tiling, 52 at its default; this
+kernel 4.64 alone and 4.5-4.6 in the program, at 256-row tiles and at
+128 alike (fewer masked rows at a lower MXU rate), 6.5 at 512; as two
+kernels inside the default 16 MB of VMEM (``h`` through HBM) 5.1 alone
+and 2.2 a layer more in the program. The three ``ragged_dot`` s'
+result is this kernel's to the bit.
 """
 from __future__ import annotations
 
@@ -47,25 +64,113 @@ def group_by_expert(choice, held_first: int, held_count: int):
     return jnp.argsort(key, stable=True), counts
 
 
+def _walk(counts, rows: int, tile: int):
+    """The kernel's grid, a step an (expert, row tile) pair that share
+    rows of the buffer sorted by expert (``rows`` of them, a multiple of
+    ``tile``): int32 ``[rows // tile + G - 1]`` each of the step's
+    expert, its row tile, and the tile's first and one-past-last row
+    that the expert serves. Experts in turn and an expert's tiles in
+    turn, so an expert's steps are consecutive and so are a tile's; an
+    expert that serves nobody has no step. The steps past the last one
+    (there are fewer than the bound unless every expert starts inside a
+    tile) repeat it with no row served: nothing is fetched for them."""
+    steps = rows // tile + counts.shape[0] - 1
+    end = jnp.cumsum(counts)
+    start = end - counts
+    first = start // tile                        # an expert's first tile
+    tiles = jnp.where(counts > 0, (end + tile - 1) // tile - first, 0)
+    before = jnp.cumsum(tiles) - tiles           # steps before an expert's
+    step = jnp.arange(steps, dtype=jnp.int32)
+    at = jnp.minimum(step, jnp.sum(tiles) - 1)   # the last live step
+    expert = jnp.repeat(jnp.arange(counts.shape[0], dtype=jnp.int32), tiles,
+                        total_repeat_length=steps)[at]
+    row_tile = first[expert] + at - before[expert]
+    lo = jnp.clip(start[expert] - row_tile * tile, 0, tile)
+    hi = jnp.where(step == at, jnp.clip(end[expert] - row_tile * tile, 0,
+                                        tile), lo)
+    return tuple(a.astype(jnp.int32) for a in (expert, row_tile, lo, hi))
+
+
+def _swiglu_kernel(expert_ref, tile_ref, lo_ref, hi_ref, x_ref, w1_ref,
+                   w3_ref, w2_ref, o_ref):
+    """One grid step (:func:`_walk`): the step's expert over the rows of
+    its tile, stored where the expert serves them; the tile's other
+    rows keep what their own experts' steps store."""
+    from jax.experimental import pallas as pl
+
+    i = pl.program_id(0)
+    lo, hi = lo_ref[i], hi_ref[i]
+
+    @pl.when(hi > lo)
+    def _():
+        x = x_ref[...]
+        gate = jnp.dot(x, w1_ref[...], preferred_element_type=jnp.float32)
+        up = jnp.dot(x, w3_ref[...], preferred_element_type=jnp.float32)
+        y = jnp.dot((jax.nn.silu(gate) * up).astype(x.dtype), w2_ref[...],
+                    preferred_element_type=jnp.float32)
+        rows = jax.lax.broadcasted_iota(jnp.int32, y.shape, 0)
+        o_ref[...] = jnp.where((rows >= lo) & (rows < hi), y, o_ref[...])
+
+
+def _sorted_swiglu(xs, counts, w1, w3, w2, *, tile: int):
+    """``xs`` [R, d] sorted by expert, expert ``g``'s ``counts[g]`` rows
+    after expert ``g - 1``'s and ``sum(counts) == R`` -> float32 [R, d]:
+    each row's ``(silu(x w1[g]) * (x w3[g])) w2[g]`` on its own
+    expert's matrices, bfloat16 operands as they come, float32
+    accumulation, ``silu(gate) * up`` in float32 rounded once to ``xs``'
+    dtype. One ``nns_grouped_swiglu`` call (module docstring). It holds
+    an expert's three matrices twice over (one set read while the last
+    is multiplied), so it asks for the VMEM that takes."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, d = xs.shape
+    f = w1.shape[2]
+    padded = -(-rows // tile) * tile
+    if padded != rows:
+        xs = jnp.pad(xs, ((0, padded - rows), (0, 0)))
+    walk = _walk(counts, padded, tile)
+
+    def row_tile(i, expert, tile_of, lo, hi):
+        return tile_of[i], 0
+
+    def matrices(i, expert, tile_of, lo, hi):
+        return expert[i], 0, 0
+
+    blocks = (3 * d * f + tile * d) * xs.dtype.itemsize + tile * d * 4
+    out = pl.pallas_call(
+        _swiglu_kernel,
+        out_shape=jax.ShapeDtypeStruct((padded, d), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(walk), grid=walk[0].shape,
+            in_specs=[pl.BlockSpec((tile, d), row_tile),
+                      pl.BlockSpec((None, d, f), matrices),
+                      pl.BlockSpec((None, d, f), matrices),
+                      pl.BlockSpec((None, f, d), matrices)],
+            out_specs=pl.BlockSpec((tile, d), row_tile)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=2 * blocks + (16 << 20)),
+        interpret=jax.default_backend() != "tpu",
+        name="nns_grouped_swiglu",
+    )(*walk, xs, w1, w3, w2)
+    return out[:rows]
+
+
 def grouped_swiglu(x, order, counts, pair_weight, w1, w3, w2, *, tile: int,
                    whole: bool = False):
     """``x`` [T, d]; ``order``, ``counts`` from :func:`group_by_expert`;
     ``pair_weight`` [T, K] float32; ``w1``, ``w3`` [G, d, f], ``w2``
     [G, f, d] -> float32 [T, d]: for each token the sum over its pairs
     with a held expert of ``weight * (silu(x w1) * (x w3)) w2``.
+    ``tile``: the rows a loop's turn or the kernel's grid step takes.
     ``whole``: the ``G`` experts are the whole router, so every one of
     the ``T x K`` pairs is served here (module docstring)."""
     t, d = x.shape
     k = order.shape[0] // t
     if whole:
         # the rows sorted by expert, gathered once: T x K of them, exact
-        xs = x[order // k]
-        up = jax.lax.ragged_dot(xs, w3, counts,
-                                preferred_element_type=jnp.float32)
-        gate = jax.lax.ragged_dot(xs, w1, counts,
-                                  preferred_element_type=jnp.float32)
-        y = jax.lax.ragged_dot((jax.nn.silu(gate) * up).astype(x.dtype), w2,
-                               counts, preferred_element_type=jnp.float32)
+        y = _sorted_swiglu(x[order // k], counts, w1, w3, w2, tile=tile)
         # back in pair order: a token's K rows side by side, weighted, summed
         y = y[jnp.argsort(order)].reshape(t, k, d)
         return jnp.sum(y * pair_weight[:, :, None], axis=1)

@@ -201,6 +201,33 @@ def test_config_reads_the_published_keys():
         zoo.build("afmoe", hidden="64")
 
 
+@pytest.mark.parametrize("share,grouped", [
+    ("", 3), ("&held_first=0&held_count=16", 3),
+    ("&held_first=4&held_count=4", 0), ("&held_first=0&held_count=15", 0)],
+    ids=["default", "all_sixteen", "a_quarter", "all_but_one"])
+def test_whole_router_runs_the_grouped_kernel(share, grouped):
+    """``kernel_calls``: ``nns_grouped_swiglu`` once an expert layer
+    where the held experts are the whole router (what the layer
+    observes, no option), and not at all where any expert lives
+    elsewhere: those keep the tile loops."""
+    caps = ("other/tensors,format=static,num_tensors=1,types=(string)int32,"
+            "dimensions=(string)64,framerate=0/1")
+    p = parse_launch(f'appsrc name=in caps="{caps}" ! tensor_filter name=f '
+                     f'framework=jax model=zoo://afmoe?seq=64&seed=3{share} '
+                     f'! appsink name=out')
+    p.start()
+    p["in"].push_buffer(Buffer.from_arrays([_tokens(0)]))
+    p["in"].end_stream()
+    assert p.wait_eos(timeout=120)
+    report = p["f"].transfer_report()
+    p.stop()
+    # 4 layers x 4 blocks of 16 queries; 3 of the 4 are expert layers
+    calls = {"nns_masked_attention": 16}
+    if grouped:
+        calls["nns_grouped_swiglu"] = grouped
+    assert report["kernel_calls"] == calls
+
+
 @pytest.mark.parametrize("window", ["", "in-flight=4 prefetch-host=true"],
                          ids=["window1", "window4"])
 def test_pipeline_gives_the_direct_calls_three_tensors(window):

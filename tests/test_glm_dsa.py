@@ -293,29 +293,61 @@ def test_grouped_swiglu_is_exact(tile, held_first, held):
                                for e in range(held_first, held_first + held)]
 
 
+def _pairs_per_expert(routing, rng, pairs, tile, router):
+    """How many of ``pairs`` pairs each of ``router`` experts serves,
+    for the routings a walk over row tiles can get wrong."""
+    if routing == "one_expert":
+        return np.bincount([3], minlength=router) * pairs
+    counts = rng.multinomial(pairs, np.full(router, 1 / router))
+    gaps = {"gap_first": [0], "gap_last": [router - 1],
+            "gap_middle": [5, 6]}.get(routing, [])
+    if routing == "many_tiles":
+        # expert 2 serves more than three tiles, from inside a tile on
+        counts = np.full(router, (pairs - 3 * tile - 5) // (router - 1))
+        counts[2] = 3 * tile + 5
+    if routing == "off_tile":
+        # no expert's first row is a tile's first but expert 0's
+        ends = np.arange(1, router) * (pairs // router)
+        ends += ends % tile == 0
+        counts = np.diff(np.concatenate([[0], ends, [pairs]]))
+        assert (np.cumsum(counts)[:-1] % tile).all()
+    counts[gaps] = 0
+    counts[1] += pairs - counts.sum()
+    assert counts.sum() == pairs and (counts >= 0).all()
+    return counts
+
+
 @pytest.mark.parametrize("whole", [True, False], ids=["sorted", "loops"])
 @pytest.mark.parametrize("tile,dtype,tol", [(8, jnp.float32, 1e-3),
                                             (32, jnp.float32, 1e-3),
                                             (16, jnp.bfloat16, 0.15)])
-def test_grouped_swiglu_holds_the_whole_router(tile, dtype, tol, whole):
+@pytest.mark.parametrize("routing", [
+    "gap_middle", "gap_first", "gap_last", "many_tiles", "off_tile",
+    "one_expert", "ragged_end"])
+def test_grouped_swiglu_holds_the_whole_router(routing, tile, dtype, tol,
+                                               whole):
     """``held_count`` equal to the router's width: every one of the ``T
     x K`` pairs is served here, none goes to the tail, and the result is
     the dense form's (every expert over every token, weighted by the
     token's weight for it or 0), from the tile loops and from the
-    sorted form (``whole``: the rows gathered once, ``ragged_dot``,
-    gathered back) alike; an expert nobody chose breaks neither."""
+    sorted form (``whole``: the rows gathered once, the kernel
+    ``nns_grouped_swiglu`` through the interpreter, gathered back)
+    alike. The routings are those a walk over row tiles can get wrong:
+    experts that serve nobody first, last and two in the middle, an
+    expert over more than three tiles, every boundary inside a tile,
+    all pairs on one expert, ``T x K`` no multiple of the tile."""
     rng = np.random.default_rng(tile)
-    t, d, f, k, router = 96, 16, 24, 4, 12
+    t, d, f, k, router = 97 if routing == "ragged_end" else 96, 16, 24, 4, 12
     x = rng.standard_normal((t, d)).astype(np.float32)
-    choice = np.stack([rng.permutation(router)[:k] for _ in range(t)]
-                      ).astype(np.int32)
+    counts_want = _pairs_per_expert(routing, rng, t * k, tile, router)
+    choice = rng.permutation(np.repeat(np.arange(router), counts_want)
+                             ).reshape(t, k).astype(np.int32)
     weight = rng.random((t, k)).astype(np.float32)
     w1, w3 = (rng.standard_normal((router, d, f)).astype(np.float32)
               for _ in range(2))
     w2 = rng.standard_normal((router, f, d)).astype(np.float32)
-    choice = np.where(choice == 5, 11, choice)     # expert 5 serves nobody
     order, counts = group_by_expert(jnp.asarray(choice), 0, router)
-    assert int(counts.sum()) == t * k and int(counts[5]) == 0
+    assert counts.tolist() == counts_want.tolist()
     assert sorted(np.asarray(order).tolist()) == list(range(t * k))
     got = jax.jit(lambda *a: grouped_swiglu(*a, tile=tile, whole=whole))(
         *(jnp.asarray(a, dtype) for a in (x,)), order, counts, weight,
@@ -329,6 +361,27 @@ def test_grouped_swiglu_holds_the_whole_router(tile, dtype, tol, whole):
     want = jnp.einsum("ted,te->td", every, dense)
     np.testing.assert_allclose(got, want, atol=tol * (1 + 9 * (
         dtype == jnp.bfloat16)), rtol=tol)
+
+
+def test_grouped_kernel_walks_each_expert_and_tile_once():
+    """The kernel's grid (``_walk``): an expert's steps in turn, a
+    shared tile once for each expert with a row in it, nobody's expert
+    no step, the steps past the last one dead (no row) and on the last
+    one's blocks, so that nothing is fetched for them."""
+    from nnstreamer_tpu.ops.grouped import _walk
+    counts = jnp.asarray([0, 5, 3, 0, 0, 30, 1, 1, 0, 8, 0], jnp.int32)
+    expert, row_tile, lo, hi = (a.tolist() for a in _walk(counts, 48, 8))
+    assert len(expert) == 48 // 8 + 11 - 1
+    live = [(e, r, a, b) for e, r, a, b in zip(expert, row_tile, lo, hi)
+            if b > a]
+    assert live == [(1, 0, 0, 5), (2, 0, 5, 8), (5, 1, 0, 8), (5, 2, 0, 8),
+                    (5, 3, 0, 8), (5, 4, 0, 6), (6, 4, 6, 7), (7, 4, 7, 8),
+                    (9, 5, 0, 8)]
+    dead = len(live)
+    assert set(zip(expert[dead:], row_tile[dead:])) == {(9, 5)}
+    # each row served once
+    served = sorted(r * 8 + i for _, r, a, b in live for i in range(a, b))
+    assert served == list(range(48))
 
 
 def _choice(case, rng, t, k, tile, held_first, held, router):
@@ -636,7 +689,12 @@ def test_block_without_a_query_tile_raises():
     ("zoo://glm_dsa?seq=64&held_first=8&held_count=8&dtype=float32",
      ("int32", "64"), {"nns_masked_attention": 12}, 3 * 9),
     ("zoo://mlp", ("float32", "64:4"), {}, None),
-], ids=["glm_dsa", "glm_dsa_float32", "plain_xla"])
+    # every expert of the router held: the tile loops all the same (a
+    # sixteenth of a router is what this block's chips hold), no grouped
+    # kernel
+    ("zoo://glm_dsa?seq=64", ("int32", "64"), {"nns_masked_attention": 12},
+     3 * 9 + 2),
+], ids=["glm_dsa", "glm_dsa_float32", "plain_xla", "glm_dsa_whole_router"])
 def test_backend_reports_the_kernels_it_calls(uri, dims, calls, equations):
     """``kernel_calls`` beside ``prepared_leaves``: the kernel's name
     with its call sites in the traced program, nothing for a model in

@@ -462,12 +462,19 @@ class TestDrainSettlement:
         for i in range(sent):
             client["in"].push_buffer(Buffer.from_arrays(
                 [np.full(4, float(i), np.float32)]))
-        # let some requests genuinely be in flight before pulling the plug
+        # let the requests genuinely be in flight before pulling the plug:
+        # every one handed to the wire (entry[2] is the connection it
+        # went out on, -1 before). One the client's chain thread has yet
+        # to send when the server stops meets a closed socket, not a
+        # drain, and on a loaded host that thread can be eleven behind
         deadline = time.monotonic() + 10
         while time.monotonic() < deadline:
             with client["qc"]._plock:
-                if client["qc"]._pending:
-                    break
+                entries = list(client["qc"]._pending)
+            if (len(entries) + len(client["out"].buffers)
+                    + client["qc"].stats["shed"] >= sent
+                    and all(e[2] != -1 for e in entries)):
+                break
             time.sleep(0.005)
         ok = server.drain(deadline=30)
         # every correlation must have settled BEFORE the server closed:

@@ -1081,6 +1081,13 @@ class TestSessionHandshake:
             kind, meta, _ = recv_msg(sub)
             assert kind == MsgKind.CAPS_ACK
             assert "session" not in meta  # strict v1 on this link
+            # the link joins the broadcast set after its ack goes out: a
+            # frame pushed in between is not this subscriber's to see
+            sink = next(e for e in pub.elements.values()
+                        if hasattr(e, "_subs"))
+            deadline = time.monotonic() + 10
+            while not sink._subs and time.monotonic() < deadline:
+                time.sleep(0.005)
             pub["in"].push_buffer(Buffer.from_arrays(
                 [np.zeros(4, np.float32)]))
             sub.settimeout(10)
