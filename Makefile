@@ -13,7 +13,7 @@ EXAMPLES := $(BUILD)/custom_passthrough.so $(BUILD)/custom_scaler.so
 	jit-stability chaos \
 	chaos-zeroloss \
 	chaos-fleet chaos-preempt chaos-llm chaos-elastic fuse-parity async-parity \
-	shard-parity delta-parity obs-overhead package
+	shard-parity delta-parity package
 
 native: $(LIB) $(EXAMPLES)
 
@@ -34,7 +34,6 @@ check: native lint racecheck flowcheck jitcheck
 	$(MAKE) chaos-preempt
 	$(MAKE) chaos-llm
 	$(MAKE) chaos-elastic
-	$(MAKE) obs-overhead
 
 # `make fuse-parity` = the fusion compiler's byte-parity oracle: every
 # fusible pipeline in the corpus (plus a built-in representative suite)
@@ -116,13 +115,6 @@ chaos-llm:
 # cold control arm proving the gap is real).
 chaos-elastic:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_fleet.py -q -m slow
-
-# `make obs-overhead` = the observability cost gate: a devres-shaped
-# pipeline run with frame tracing on (NNS_TPU_OBS=1) vs hard-off, in
-# subprocesses, best-of-3 each — fails if the traced arm's fps is more
-# than 3% below the control (tools/obs_overhead.py).
-obs-overhead:
-	python tools/obs_overhead.py
 
 # `make tier1` = the exact ROADMAP.md tier-1 verify gate, verbatim
 # (timeout, log tee, pass-dot count and all).

@@ -12,6 +12,7 @@ The hot path is one cached-executable dispatch (SURVEY.md §3.2 analog).
 from __future__ import annotations
 
 import collections
+import contextlib
 import threading
 import time
 from typing import Any, List, Optional
@@ -32,6 +33,7 @@ from ..tensors.info import TensorInfo, TensorsConfig, TensorsInfo
 from ..tensors.types import TensorFormat
 from ..obs import context as _obs_ctx
 from ..obs import events as _obs_events
+from ..obs import spans as _obs_spans
 from ..pipeline.element import Element, TransferError
 from ..pipeline.events import Event, QosEvent
 from ..pipeline.pad import Pad
@@ -93,7 +95,13 @@ class TensorFilter(Element):
     sites; the span ring and a profiler trace hold one
     ``nns.filter.prepare`` span per load with ``leaves``, ``equations``,
     ``bytes_in`` and ``bytes_out``. No property selects it: an equation
-    on constants gives the same bits whenever it is run."""
+    on constants gives the same bits whenever it is run.
+    The load accounts for itself (``obs/load.py``): ``nns.load.*`` spans
+    from ``start()`` to the first buffer, every compile event charged to
+    the program that caused it, and ``transfer_report()["load"]``
+    (``load_report()``) with the seconds by phase and one record per
+    program built; a program built after the first buffer is a
+    recompile on the frame path and goes out as a ``recompile`` event."""
 
     SINK_TEMPLATES = {"sink": "other/tensors"}
     SRC_TEMPLATES = {"src": "other/tensors"}
@@ -186,6 +194,12 @@ class TensorFilter(Element):
         self._stats_lock = threading.Lock()
         self._overlap = None               # OverlapExecutor when K > 1
         self._start_time = None
+        # the load as the element sees it (load_report()): its
+        # nns.load.start spans, the first buffer's entry into do_chain
+        # (wall clock, ns) and how long it took to complete
+        self._load_spans: List[Any] = []
+        self._first_chain_ns: Optional[int] = None
+        self._first_buffer_ns: Optional[int] = None
         self._watchdog: Optional[Watchdog] = None
         self._in_combi: Optional[List[int]] = None
         self._out_combi: Optional[List[str]] = None
@@ -202,6 +216,17 @@ class TensorFilter(Element):
                            "breaker_opened": 0})
 
     # -- framework lifecycle ---------------------------------------------
+    @contextlib.contextmanager
+    def _loading(self):
+        """``nns.load.start``: ``start()`` whole, and before it the
+        backend's ``open()`` where something asks for the framework
+        earlier (the fusion planner, ``plan_out_caps``); the load counts
+        from the first of them."""
+        with _obs_spans.region("nns.load.start", "load", element=self.name,
+                               framework=self.framework) as span:
+            yield
+        self._load_spans.append(span)
+
     def _open_fw(self) -> None:
         if self.fw is not None:
             return
@@ -238,7 +263,11 @@ class TensorFilter(Element):
             self._fw_owned = False
         if fw is None:
             fw = find_filter(fw_name)()
-            fw.open(props)
+            # from start(): inside its span; asked for earlier (the
+            # fusion planner, plan_out_caps): the load begins here
+            with contextlib.nullcontext() if self._started \
+                    else self._loading():
+                fw.open(props)
             if props.shared_key:
                 fw = shared_model_insert(props.shared_key, fw)
         self.fw = fw
@@ -259,46 +288,50 @@ class TensorFilter(Element):
     RESTART_SAFE = True  # stop/start re-opens the framework cleanly
 
     def start(self) -> None:
-        super().start()
-        self._open_fw()
-        if self._fw_restore is not None:
-            state, snap_dir = self._fw_restore
-            if hasattr(self.fw, "restore_state"):
-                self.fw.restore_state(state, snap_dir)
-            self._fw_restore = None
-        self._start_time = time.monotonic()
-        if int(self.breaker_threshold) > 0:
-            from ..fault.breaker import CircuitBreaker
-            self._breaker = CircuitBreaker(
-                threshold=int(self.breaker_threshold),
-                reset_s=float(self.breaker_reset_ms) / 1e3,
-                name=self.name, on_transition=self._on_breaker_transition)
-        else:
-            self._breaker = None
-        self._overlap = None
-        window = int(self.in_flight)
-        if window > 1:
-            if self.invoke_async:
-                logger.info("%s: in-flight=%d ignored — invoke-async "
-                            "backends manage their own in-flight frames",
-                            self.name, window)
-            elif not getattr(self.fw, "SUPPORTS_DISPATCH", False):
-                logger.info("%s: in-flight=%d ignored — framework %s has "
-                            "no async dispatch; staying synchronous",
-                            self.name, window, self.fw.NAME)
-            else:
-                from .overlap import OverlapExecutor
-                mesh = getattr(self.fw, "mesh", None)
-                devices = len(mesh.devices.ravel()) if mesh is not None else 1
-                self._overlap = OverlapExecutor(
-                    window,
-                    complete_cb=self._complete_frame,
-                    error_cb=self._complete_error,
-                    push_cb=self.push,
+        with self._loading():
+            super().start()
+            self._open_fw()
+            if self._fw_restore is not None:
+                state, snap_dir = self._fw_restore
+                if hasattr(self.fw, "restore_state"):
+                    self.fw.restore_state(state, snap_dir)
+                self._fw_restore = None
+            self._start_time = time.monotonic()
+            if int(self.breaker_threshold) > 0:
+                from ..fault.breaker import CircuitBreaker
+                self._breaker = CircuitBreaker(
+                    threshold=int(self.breaker_threshold),
+                    reset_s=float(self.breaker_reset_ms) / 1e3,
                     name=self.name,
-                    reorder=bool(self.reorder),
-                    reorder_deadline_s=float(self.reorder_deadline_ms) / 1e3,
-                    devices=devices)
+                    on_transition=self._on_breaker_transition)
+            else:
+                self._breaker = None
+            self._overlap = None
+            window = int(self.in_flight)
+            if window > 1:
+                if self.invoke_async:
+                    logger.info("%s: in-flight=%d ignored — invoke-async "
+                                "backends manage their own in-flight frames",
+                                self.name, window)
+                elif not getattr(self.fw, "SUPPORTS_DISPATCH", False):
+                    logger.info("%s: in-flight=%d ignored — framework %s "
+                                "has no async dispatch; staying synchronous",
+                                self.name, window, self.fw.NAME)
+                else:
+                    from .overlap import OverlapExecutor
+                    mesh = getattr(self.fw, "mesh", None)
+                    devices = len(mesh.devices.ravel()) \
+                        if mesh is not None else 1
+                    self._overlap = OverlapExecutor(
+                        window,
+                        complete_cb=self._complete_frame,
+                        error_cb=self._complete_error,
+                        push_cb=self.push,
+                        name=self.name,
+                        reorder=bool(self.reorder),
+                        reorder_deadline_s=float(
+                            self.reorder_deadline_ms) / 1e3,
+                        devices=devices)
 
     def drain(self) -> None:
         """During a deliberate drain the filter may sit idle for longer
@@ -347,6 +380,8 @@ class TensorFilter(Element):
             elif self._fw_owned:
                 self.fw.close()
             self.fw = None
+        self._load_spans = []
+        self._first_chain_ns = self._first_buffer_ns = None
 
     # -- negotiation ------------------------------------------------------
     def _infer_batch(self, sel: TensorsInfo) -> Optional[int]:
@@ -585,6 +620,8 @@ class TensorFilter(Element):
             # fast) and tell upstream/clients when to come back
             self._shed_frame(buf)
             return
+        if self._first_chain_ns is None:
+            self._first_chain_ns = time.time_ns()
         inputs = [c.raw for c in buf.chunks]
         if self._in_combi:
             inputs = [inputs[i] for i in self._in_combi]
@@ -633,6 +670,8 @@ class TensorFilter(Element):
             # next one.
             outputs = submit_fetch(outputs)
         out_chunks = self._combine_outputs(buf, outputs)
+        if self._first_buffer_ns is None:
+            self._first_buffer_done()
         self.push(buf.with_chunks(out_chunks))
 
     # -- in-flight window (overlapped execution) ---------------------------
@@ -688,7 +727,28 @@ class TensorFilter(Element):
         outputs = self._trim_padded_rows(buf, outputs)
         if self.prefetch_host:
             outputs = submit_fetch(outputs)
-        return buf.with_chunks(self._combine_outputs(buf, outputs))
+        out = buf.with_chunks(self._combine_outputs(buf, outputs))
+        if self._first_buffer_ns is None:
+            self._first_buffer_done()
+        return out
+
+    def _first_buffer_done(self) -> None:
+        """The element's first buffer is complete and about to go
+        downstream (chain thread, or the completer under a window): the
+        load ends here. Stamped once: ``nns.load.first_buffer`` from
+        the buffer's entry into ``do_chain``, recorded after the fact
+        as a wait is, under ``nns.load.start``'s span; the backend is
+        told, so that a program it builds from now on counts as a
+        recompile on the frame path."""
+        with self._stats_lock:
+            self._first_buffer_ns = time.time_ns() - self._first_chain_ns
+        done = getattr(self.fw, "first_buffer_done", None)
+        if callable(done):
+            done(self.name)
+        _obs_spans.record_span(
+            "nns.load.first_buffer", "load", self._first_chain_ns,
+            self._first_buffer_ns, parent=self._load_spans[-1].sid,
+            prof="nns.load.first_buffer", element=self.name)
 
     def _complete_error(self, entry, exc: BaseException) -> None:
         """A frame that failed at completion: same per-frame accounting
@@ -772,16 +832,48 @@ class TensorFilter(Element):
         (filters/prepare.py: equations run once per load),
         ``prepared_leaves`` / ``prepared_bytes`` (parameters held a
         second time in their compute dtype) and ``kernel_calls`` (the
-        program's Pallas kernels by name, with their call sites); {}
-        when running synchronously with nothing prepared and no
-        kernel."""
+        program's Pallas kernels by name, with their call sites), and
+        under ``load`` what :meth:`load_report` gives; {} when running
+        synchronously with nothing prepared, no kernel and a backend
+        that keeps no account of its load."""
         rep = self._overlap.report() if self._overlap is not None else {}
         prepared = getattr(self.fw, "prepared_report", None)
         if callable(prepared):
             held = prepared()
             if rep or held["prepared_equations"] or held["kernel_calls"]:
                 rep = {**rep, **held}
+        load = self.load_report()
+        if load is not None:
+            rep = {**rep, "load": load}
         return rep
+
+    def load_report(self) -> Optional[dict]:
+        """Where the seconds between ``start()`` and the first buffer
+        went, from the load's own spans (``obs/load.py``):
+        ``start_s`` (the ``nns.load.start`` spans: ``start()`` whole
+        and an earlier opening of the framework), the backend's
+        ``model_s`` and ``place_s`` (and ``model_jit``: what a model file
+        compiled for itself inside ``model_s``), ``first_buffer_s`` (the
+        first buffer's entry into ``do_chain`` to its completion), ``total_s``
+        (the first span's begin to that completion; both None until it
+        is there) and ``programs``, one record per program the backend
+        built. None for a backend that keeps no such account, and with
+        recording off (``NNS_TPU_OBS=0``)."""
+        report = getattr(self.fw, "load_report", None)
+        block = report() if callable(report) else None
+        spans = [s for s in self._load_spans if s.dur_ns]
+        if block is None or not spans:
+            return None
+        first = self._first_buffer_ns
+        done = first is not None
+        programs = block.pop("programs")
+        return {
+            "start_s": sum(s.dur_ns for s in spans) / 1e9,
+            **block,        # model_s, place_s, model_jit where there is one
+            "first_buffer_s": first / 1e9 if done else None,
+            "total_s": (self._first_chain_ns + first - spans[0].t0) / 1e9
+            if done else None,
+            "programs": programs}
 
     # -- circuit breaker ---------------------------------------------------
     def _shed_frame(self, buf: Buffer) -> None:

@@ -37,6 +37,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs import load as _obs_load
 from ..obs import spans as _obs_spans
 from ..tensors.info import TensorsInfo
 from ..utils.log import logger
@@ -125,15 +126,24 @@ class JaxFilter(FilterFramework):
         self._cache_key = ""
         # names the jitted program ``jit_nns_filter_<stem>`` in a trace
         self._model_stem = "model"
+        # obs/load.py: the load's spans in seconds and one record per
+        # program built, what ``load_report()`` gives
+        self._load_log = _obs_load.LoadLog()
 
     # -- lifecycle --------------------------------------------------------
     def open(self, props: FilterProperties) -> None:
         import jax
         ensure_compile_cache()  # before _load_model: zoo init compiles
+        if _obs_spans.ENABLED:
+            _obs_load.install()
         self._props = props
         opts = _parse_custom(props.custom_properties)
         model = props.model_files[0] if props.model_files else ""
-        self._load_model(model, props)
+        with self._load_log.phase("model", model=model) as span:
+            self._load_model(model, props)
+            leaves = jax.tree.leaves(self._params)
+            held = sum(getattr(x, "nbytes", 0) for x in leaves)
+            span.note(leaves=len(leaves), bytes=held)
         if "mesh" in opts:
             from ..parallel.mesh import mesh_from_spec
             from ..parallel.sharding import named_sharding_tree, rules_by_name
@@ -141,19 +151,26 @@ class JaxFilter(FilterFramework):
             rules = rules_by_name(opts.get("rules", ""))
             self._param_sharding = named_sharding_tree(
                 self._params, rules, self._mesh)
-            if self._params is not None:
-                self._params = jax.device_put(self._params,
-                                              self._param_sharding)
+            self._place(self._param_sharding, held, self._mesh.devices.size)
             logger.info("jax filter opened model=%s on mesh %s", model,
                         dict(self._mesh.shape))
         else:
             self._device = _device_for(props.accelerators)
-            if self._params is not None:
-                self._params = jax.device_put(self._params, self._device)
+            self._place(self._device, held, 1)
             logger.info("jax filter opened model=%s on %s", model,
                         self._device)
         self._cache_key = f"{model}|mesh={opts.get('mesh', '')}"
         self._prewarm_from_cache()
+
+    def _place(self, where: Any, held: int, devices: int) -> None:
+        """The loaded tree onto its device or mesh. ``device_put`` may
+        return before the copies are done: the span times this thread,
+        as ``nns.transfer.upload`` does."""
+        if self._params is None:
+            return
+        import jax
+        with self._load_log.phase("place", bytes=held, devices=devices):
+            self._params = jax.device_put(self._params, where)
 
     def _prewarm_from_cache(self) -> None:
         """Replay every signature this model compiled in previous lives
@@ -254,6 +271,22 @@ class JaxFilter(FilterFramework):
                     self._cut.equations if self._cut else 0,
                 "kernel_calls": dict(self._kernel_calls)}
 
+    def load_report(self) -> Optional[Dict[str, Any]]:
+        """What the load cost, from its own spans: ``model_s`` (the model
+        file's ``get_model()`` / ``zoo.build``), ``place_s`` (the tree's
+        ``device_put``) and ``programs``, one record per program built
+        (``obs/load.py``: name, signature, ``at`` ``load`` or ``frame``,
+        wall, trace, lower and compile seconds, the cache's verdict);
+        None with recording off. The element adds its ``start_s``,
+        ``first_buffer_s`` and ``total_s`` and hands the block out as
+        ``transfer_report()["load"]``."""
+        return self._load_log.report()
+
+    def first_buffer_done(self, element: str) -> None:
+        """``element``'s first buffer is through: a program built from
+        now on is a recompile on its frame path."""
+        self._load_log.serving = element
+
     # -- info -------------------------------------------------------------
     def get_model_info(self):
         return self._in_info, self._out_info
@@ -278,27 +311,43 @@ class JaxFilter(FilterFramework):
         key = (sig, donate_idx) if donate_idx else sig
         exe = self._jit_cache.get(key)
         if exe is None:
-            import jax
-
-            def jit(fn):
-                # a stable program name for the trace's XLA Modules line
-                fn = _obs_spans.named_program(
-                    "nns_filter_" + self._model_stem, fn)
-                return jax.jit(fn, donate_argnums=donate_idx) \
-                    if donate_idx else jax.jit(fn)
-
-            exe = jit(self._apply)
-            closed, out_tree, cut = _prepare.trace(exe, self._params, xs)
-            if self._cut is None:
-                self._kernel_calls = _prepare.kernel_calls(closed)
-            if self._loaded(self._params, cut) is not None:
-                exe = jit(_prepare.program(closed, out_tree, cut))
-                self._on_prepared.add(key)
-            self._jit_cache[key] = exe
-            self.compile_count += 1
-            self._record_signature(sig, donate_idx)
+            # the span ends with the first call's return: lowering and
+            # the backend compile (or the cache's retrieval) happen there
+            with self._load_log.program(
+                    "jit_" + _obs_spans.identifier(
+                        "nns_filter_" + self._model_stem), sig, donate_idx):
+                exe = self._build(key, sig, xs, donate_idx)
+                return exe(self._prepared if key in self._on_prepared
+                           else self._params, *xs)
         return exe(self._prepared if key in self._on_prepared
                    else self._params, *xs)
+
+    def _build(self, key: Tuple, sig: Tuple, xs: Sequence[Any],
+               donate_idx: Tuple[int, ...]) -> Any:
+        """The program for a signature the jit cache missed (lock
+        held), traced, cut and cached; compiled by its first call."""
+        import jax
+
+        def jit(fn):
+            # a stable program name for the trace's XLA Modules line
+            fn = _obs_spans.named_program(
+                "nns_filter_" + self._model_stem, fn)
+            return jax.jit(fn, donate_argnums=donate_idx) \
+                if donate_idx else jax.jit(fn)
+
+        exe = jit(self._apply)
+        with self._load_log.trace() as span:
+            closed, out_tree, cut = _prepare.trace(exe, self._params, xs)
+            span.note(equations=len(closed.jaxpr.eqns))
+        if self._cut is None:
+            self._kernel_calls = _prepare.kernel_calls(closed)
+        if self._loaded(self._params, cut) is not None:
+            exe = jit(_prepare.program(closed, out_tree, cut))
+            self._on_prepared.add(key)
+        self._jit_cache[key] = exe
+        self.compile_count += 1
+        self._record_signature(sig, donate_idx)
+        return exe
 
     def _loaded(self, params: Any, cut: _prepare.Split) -> Any:
         """What a program's step may read whose trace of ``params`` was
@@ -326,9 +375,8 @@ class JaxFilter(FilterFramework):
         # run now, not be staged into that program
         bytes_out = sum(v.aval.size * v.aval.dtype.itemsize
                         for v in cut.load.outvars)
-        with _obs_spans.region(
-                "nns.filter.prepare", "filter", leaves=len(cut.sources),
-                equations=cut.equations,
+        with self._load_log.prepare(
+                leaves=len(cut.sources), equations=cut.equations,
                 bytes_in=sum(leaves[i].nbytes for i in cut.sources),
                 bytes_out=bytes_out), jax.ensure_compile_time_eval():
             out = _prepare.load(cut, leaves)

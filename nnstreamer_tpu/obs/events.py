@@ -9,7 +9,8 @@ warning — so the recorder, the log, and the bus can never drift apart.
 Kinds in use: ``breaker`` (open/close flips), ``shed`` (admission /
 deadline / backpressure drops), ``failover`` (router re-dispatch after
 a replica death), ``drain``, ``preempt``, ``resume`` (session RESUME
-replay), ``abort``.
+replay), ``abort``, ``recompile`` (a jax filter built a program on the
+frame path: ``program``, ``signature``; obs/load.py).
 """
 from __future__ import annotations
 

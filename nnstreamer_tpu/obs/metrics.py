@@ -16,6 +16,8 @@ One scrape renders, in the standard ``name{labels} value`` text format:
   it aggregates becomes a scrapeable series;
 * each local device's memory in use, peak and limit, where the backend
   reports them;
+* ``nns_load_seconds{pipeline,element,phase}``: where each jax filter's
+  seconds from ``start()`` to its first buffer went (``obs/load.py``);
 * flight-recorder structured-event counts by kind.
 
 Pipelines register at ``start()`` and unregister at ``stop()``
@@ -334,6 +336,34 @@ def render() -> str:
                     lines.append(
                         f"nns_jit_recompiles_total"
                         f"{_labels(pipeline=pname, element=e.name)} {n}")
+
+    # 2b) where each filter's seconds from start() to its first buffer
+    # went, by phase (obs/load.py); absent with recording off and for a
+    # backend that keeps no such account
+    from .load import phase_seconds
+    load_lines: List[str] = []
+    for p in pipelines:
+        pname = getattr(p, "name", "") or ""
+        for e in getattr(p, "elements", {}).values():
+            report = getattr(e, "load_report", None)
+            if not callable(report):
+                continue
+            try:
+                block = report()
+            except Exception:  # noqa: BLE001 — a scrape never takes the runtime down
+                continue
+            if block is None:
+                continue
+            for phase, seconds in phase_seconds(block).items():
+                load_lines.append(
+                    f"nns_load_seconds"
+                    f"{_labels(pipeline=pname, element=e.name, phase=phase)}"
+                    f" {seconds}")
+    if load_lines:
+        lines.append("# HELP nns_load_seconds a filter's load, start() to "
+                     "its first buffer, by phase")
+        lines.append("# TYPE nns_load_seconds gauge")
+        lines.extend(load_lines)
 
     # 3) serve schedulers: live occupancy gauges + reservoir quantiles
     from ..serve.scheduler import SERVE_TABLE, _TABLE_LOCK

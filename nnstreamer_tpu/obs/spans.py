@@ -12,7 +12,7 @@ A span is the tuple::
 
 with wall-clock (epoch) timestamps so spans recorded in different
 processes align in one Chrome trace. ``NNS_TPU_OBS=0`` turns the whole
-layer off (the obs-overhead gate's control arm).
+layer off.
 
 The bridge to ``jax.profiler``: a profiler trace counts from its
 session's start on the device's clock, so the rings' epoch stamps
@@ -151,13 +151,16 @@ def record_span(name: str, cat: str, ts_ns: int, dur_ns: int,
 
 class _Region:
     """One open :func:`region`: ring tuple on exit, profiler annotation
-    for the whole extent."""
+    for the whole extent. ``account`` is what the thread's compile
+    events are charged to while the region is the innermost one that
+    has any (``obs/load.py``); None on every other region."""
 
     __slots__ = ("name", "cat", "ctx", "ann", "sid", "trace_id", "parent",
-                 "t0", "dur_ns")
+                 "t0", "dur_ns", "account")
 
     def __init__(self, name, cat, ctx, prof, meta):
         self.name, self.cat, self.ctx = name, cat, ctx
+        self.account = None
         self.sid = _BASE | (next(_IDS) & 0xFFFFFF)
         try:
             stack = _tls.open
@@ -166,7 +169,7 @@ class _Region:
         if ctx is not None:
             self.trace_id, self.parent = ctx.trace_id, ctx.span_id
         elif stack:
-            self.trace_id, self.parent = stack[-1]
+            self.trace_id, self.parent = stack[-1].trace_id, stack[-1].sid
         else:
             self.trace_id = self.parent = 0
         self.ann = (_annotation or _bind_annotation())(
@@ -174,7 +177,7 @@ class _Region:
             **meta)
 
     def __enter__(self):
-        _tls.open.append((self.trace_id, self.sid))
+        _tls.open.append(self)
         self.t0 = time.time_ns()
         self.ann.__enter__()
         return self
@@ -193,13 +196,33 @@ class _Region:
             self.ctx.span_id = self.sid
         return False
 
+    def note(self, **meta) -> None:
+        """Metadata known only once the work is under way (a loaded
+        tree's bytes, a trace's equations), added to the open
+        annotation; the ring keeps no metadata."""
+        self.ann.set_metadata(**meta)
+
+    def charge(self, account) -> "_Region":
+        """Make ``account`` what :func:`open_account` answers while this
+        region is the innermost one that has any."""
+        self.account = account
+        return self
+
 
 class _Off:
     """``region()`` with recording off: nothing built, nothing kept."""
 
     dur_ns = 0       # what callers add to a context's accumulators
+    account = None   # a caller's account is never charged
+    sid = t0 = 0
 
     def __enter__(self):
+        return self
+
+    def note(self, **meta) -> None:
+        pass
+
+    def charge(self, account) -> "_Off":
         return self
 
     def __exit__(self, *exc):
@@ -221,6 +244,20 @@ def region(prof: str, cat: str, ctx: Optional[TraceContext] = None,
     return _Region(name or prof, cat, ctx, prof, meta)
 
 
+def open_account():
+    """The ``account`` of the innermost open region of this thread that
+    has one, or None: whom a compile event that fires now is charged
+    to."""
+    try:
+        stack = _tls.open
+    except AttributeError:
+        return None
+    for r in reversed(stack):
+        if r.account is not None:
+            return r.account
+    return None
+
+
 def record_root(name: str, ctx: TraceContext) -> int:
     """The source-stamp root span (zero duration, no parent): children
     recorded downstream always find their parent in the dump."""
@@ -232,6 +269,11 @@ def record_root(name: str, ctx: TraceContext) -> int:
     return sid
 
 
+def identifier(name: str) -> str:
+    """``name`` as the identifier :func:`named_program` makes of it."""
+    return re.sub(r"\W", "_", name)
+
+
 def named_program(name: str, fn):
     """``fn`` under the stable name its jitted program carries in a
     profiler trace: ``jax.jit`` names the module ``jit_<__name__>``, so
@@ -241,7 +283,7 @@ def named_program(name: str, fn):
     left alone: module-level functions are shared."""
     def program(*args, **kwargs):
         return fn(*args, **kwargs)
-    program.__name__ = program.__qualname__ = re.sub(r"\W", "_", name)
+    program.__name__ = program.__qualname__ = identifier(name)
     return program
 
 
@@ -263,8 +305,7 @@ def chain_span(element, ctx: TraceContext, ts_ns: int, dur_ns: int) -> None:
     settle the frame's end-to-end histogram. ``ctx`` is what
     ``ensure_ctx`` gave ``chain()`` on entry. ``record_span`` is
     inlined: this is the single hottest call in the whole obs plane
-    (once per element per frame) and the obs-overhead gate prices every
-    function call made here."""
+    (once per element per frame)."""
     pipeline = element.pipeline
     if pipeline is not None and pipeline.tracer is not None:
         pipeline.tracer.arrive(element.name, ctx, ts_ns)
