@@ -20,32 +20,58 @@ served when a chip holds a sixteenth of the router. **When every expert
 of the router is held** (``whole``), ``T x K`` is exact: every pair is
 served here and none belongs to another chip. Then the rows are
 gathered once in expert order, one Pallas TPU kernel,
-``nns_grouped_swiglu`` (:func:`_sorted_swiglu`), runs the three
-products and ``silu(gate) * up`` between them over the whole buffer,
-and the results go back to pair order by one more gather, where a
-token's ``K`` rows lie side by side and are weighted and summed: no
-scatter-add, no loop turn. The kernel walks the buffer in tiles of
-``tile`` rows, a grid step an (expert, row tile) pair that share rows
-(:func:`_walk`, prefetched scalars): a tile that straddles experts is
-visited once for each with the others' rows masked at the store, an
-expert nobody chose is never visited, and consecutive tiles of one
-expert keep its three matrices in VMEM, so each is read from HBM once.
-``gate``, ``up`` and their product never exist in HBM. Compiled by
-Mosaic on a TPU, through the Pallas interpreter elsewhere (how the CPU
-tests run it). Read on the chip at 128 experts of 2048 x 1024 and
-32,768 pairs a layer (PERF.md, PR 34 and PR 35), ms a layer: the
-loops' whole 16.8 (their scatter-add into the ``[4096, 2048]`` float32
-sum alone 9.4); three ``lax.ragged_dot`` s and the fusion between them
-(the TPU compiler's own grouped-product kernel, at 24 % of the MXU's
-peak) 9.07 alone and 9.4 in the program; JAX's bundled ``megablox.gmm``
-three times 6.25 alone at its best tiling, 52 at its default; this
-kernel 4.64 alone and 4.5-4.6 in the program, at 256-row tiles and at
-128 alike (fewer masked rows at a lower MXU rate), 6.5 at 512; as two
-kernels inside the default 16 MB of VMEM (``h`` through HBM) 5.1 alone
-and 2.2 a layer more in the program. The three ``ragged_dot`` s'
-result is this kernel's to the bit.
+``nns_grouped_swiglu`` (:func:`_aligned_swiglu`), runs the three
+products and ``silu(gate) * up`` between them, and the results go back
+to pair order by one more gather, where a token's ``K`` rows lie side
+by side and are weighted and summed: no scatter-add, no loop turn.
+**Every expert starts on a tile of its own** (PR 37): a grid step is a
+tile and a tile has one expert (:func:`_walk`, prefetched scalars), so
+a routing costs ``sum(ceil(count / tile))`` steps
+(:func:`tiles_walked`), each stored whole: no mask, no read of the
+output block. A step multiplies whatever lies behind its expert's last
+row (the next experts' rows, on the wrong matrices) and stores it where
+nobody reads. An expert nobody chose has no step, and consecutive tiles
+of one expert keep its three matrices in VMEM, so each is read from HBM
+once. The layouts (:func:`_spread`; sorts and comparisons against the
+experts, no gather of scalars): **on the way in every expert's run
+starts on a multiple of the dtype's sublane tile** (16 rows of
+bfloat16: ``T x K + 15 G`` rows and a tile of slack, 34,944 at the
+cell's shapes) and a step's block is ``tile`` rows from an *element*
+offset (``pl.Element``), which Mosaic takes from HBM when it can prove
+the offset a multiple of that tile and at no other row; **on the way
+out every expert's run starts on a tile**, ``ceil(T K / tile) + G - 1``
+tiles of which the live ones are written, and the pairs' gather reads
+them there. ``gate``, ``up`` and their product never exist in HBM.
+Compiled by Mosaic on a TPU, through the Pallas interpreter elsewhere
+(how the CPU tests run it). Read on the chip at 128 experts of 2048 x
+1024 and 32,768 pairs a layer (PERF.md, PR 34, PR 35 and PR 37), ms a
+layer: the loops' whole 16.8 (their scatter-add into the ``[4096,
+2048]`` float32 sum alone 9.4); three ``lax.ragged_dot`` s and the
+fusion between them (the TPU compiler's own grouped-product kernel, at
+24 % of the MXU's peak) 9.07 alone and 9.4 in the program; JAX's
+bundled ``megablox.gmm`` three times 6.25 alone at its best tiling, 52
+at its default; PR 35's kernel over tiles of the sorted buffer, a tile
+shared by several experts a masked step for each (252-254 steps of 256
+rows for 128 tiles), 4.56 alone and 4.55 in the program, at 128-row
+tiles the same, 6.5 at 512; this one **4.26 alone and 4.21 in the
+program** (206 steps of 256 rows; 4.32 and 4.29 at 128 rows, 328 steps:
+deleted). A step that changes expert is bound by the memory, not the
+MXU: 12.6 MB of matrices, a tile in and a float32 tile out are 15.6 MB,
+20.5 us at 760 GB/s, against 18.3 us of products; so fewer steps gave
+0.30 ms a layer where 0.78 were sized. The ways in that were tried and
+deleted, ms a sequence of four layers in the program against PR 35's
+48.05: this kernel on rows gathered in the way out's own layout (65,280
+rows a layer) with the slots' pairs gathered as 32-bit scalars 51.13
+(the three scalar gathers 3.7), the same with sorts for them 47.71 (the
+rows' gather 0.41 a layer for 0.21), a kernel that copies its tile from
+the sorted buffer itself at any row: refused by Mosaic, and from
+multiples of 16 rows by its own double-buffered ``make_async_copy``:
+the ``pl.Element`` block's time with forty lines more; kept, **46.98**.
+The three ``ragged_dot`` s' result is this kernel's to the bit.
 """
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -64,97 +90,121 @@ def group_by_expert(choice, held_first: int, held_count: int):
     return jnp.argsort(key, stable=True), counts
 
 
-def _walk(counts, rows: int, tile: int):
-    """The kernel's grid, a step an (expert, row tile) pair that share
-    rows of the buffer sorted by expert (``rows`` of them, a multiple of
-    ``tile``): int32 ``[rows // tile + G - 1]`` each of the step's
-    expert, its row tile, and the tile's first and one-past-last row
-    that the expert serves. Experts in turn and an expert's tiles in
-    turn, so an expert's steps are consecutive and so are a tile's; an
-    expert that serves nobody has no step. The steps past the last one
-    (there are fewer than the bound unless every expert starts inside a
-    tile) repeat it with no row served: nothing is fetched for them."""
-    steps = rows // tile + counts.shape[0] - 1
-    end = jnp.cumsum(counts)
-    start = end - counts
-    first = start // tile                        # an expert's first tile
-    tiles = jnp.where(counts > 0, (end + tile - 1) // tile - first, 0)
-    before = jnp.cumsum(tiles) - tiles           # steps before an expert's
-    step = jnp.arange(steps, dtype=jnp.int32)
-    at = jnp.minimum(step, jnp.sum(tiles) - 1)   # the last live step
-    expert = jnp.repeat(jnp.arange(counts.shape[0], dtype=jnp.int32), tiles,
-                        total_repeat_length=steps)[at]
-    row_tile = first[expert] + at - before[expert]
-    lo = jnp.clip(start[expert] - row_tile * tile, 0, tile)
-    hi = jnp.where(step == at, jnp.clip(end[expert] - row_tile * tile, 0,
-                                        tile), lo)
-    return tuple(a.astype(jnp.int32) for a in (expert, row_tile, lo, hi))
+def tiles_walked(counts, tile: int):
+    """How many ``tile``-row tiles the aligned walk costs a routing:
+    ``counts`` [..., G] pairs an expert (any integer array, numpy's or
+    jax's) -> the sum over the experts of ``ceil(count / tile)``. The
+    kernel multiplies that many tiles whole (:func:`_walk`'s live
+    steps); the rows served over ``tile`` times it is the share of its
+    multiplications that somebody reads."""
+    return (-(-counts // tile)).sum(-1)
 
 
-def _swiglu_kernel(expert_ref, tile_ref, lo_ref, hi_ref, x_ref, w1_ref,
-                   w3_ref, w2_ref, o_ref):
-    """One grid step (:func:`_walk`): the step's expert over the rows of
-    its tile, stored where the expert serves them; the tile's other
-    rows keep what their own experts' steps store."""
+def _spread(counts, rows: int, align: int, slots: int):
+    """``rows`` rows sorted by expert, expert ``g``'s ``counts[g]`` in
+    turn, laid out over ``slots`` slots so that every expert's run
+    starts on a multiple of ``align``, the experts still in turn ->
+    ``(to, gaps)``, int32: the slot of sorted row ``s`` ``[rows]``
+    (``s`` plus the slots left free behind the experts that end at or
+    before it), and the free slots in turn ``[slots - rows]`` (the
+    ``i``-th lies ``i`` behind the rows of every expert whose own free
+    slots begin at or before it). Comparisons against the experts and
+    sums, no gather: a gather of 32-bit scalars costs the chip 7 ns an
+    element (PERF.md, PR 37), each of these 0.015 ms a layer."""
+    def behind(at, edges, sizes):
+        return at + jnp.sum(jnp.where(at[:, None] >= edges, sizes, 0),
+                            axis=1, dtype=jnp.int32)
+
+    free = -counts % align
+    return (behind(jnp.arange(rows, dtype=jnp.int32), jnp.cumsum(counts),
+                   free),
+            behind(jnp.arange(slots - rows, dtype=jnp.int32),
+                   jnp.cumsum(free) - free, counts))
+
+
+def _walk(counts, tiles: int, tile: int, align: int):
+    """The kernel's grid, a step a tile and a tile one expert's:
+    ``(expert, live, row)``, int32: a step's expert ``[tiles]``, the
+    experts in turn and an expert's ``ceil(counts[g] / tile)`` tiles in
+    turn (one nobody chose has none); :func:`tiles_walked` ``[1]``, the
+    steps that hold a row; and the row of the buffer laid out at
+    ``align`` (:func:`_spread`) at which a step's ``tile`` rows begin
+    ``[tiles]``, the expert's run from its first row on. The steps past
+    the live ones repeat the last (nothing is fetched for them)."""
+    live = tiles_walked(counts, tile)
+    at = jnp.minimum(jnp.arange(tiles), live - 1)
+    of = -(-counts // tile)
+    expert = jnp.repeat(jnp.arange(counts.shape[0]), of,
+                        total_repeat_length=tiles)[at]
+    held = -(-counts // align) * align
+    row = (jnp.cumsum(held) - held)[expert] \
+        + (at - (jnp.cumsum(of) - of)[expert]) * tile
+    return tuple(a.astype(jnp.int32) for a in (expert, live.reshape(1), row))
+
+
+def _swiglu_kernel(expert_ref, live_ref, row_ref, x_ref, w1_ref, w3_ref,
+                   w2_ref, o_ref):
+    """One grid step (:func:`_walk`): a tile's rows through its
+    expert's three matrices, stored whole."""
     from jax.experimental import pallas as pl
 
-    i = pl.program_id(0)
-    lo, hi = lo_ref[i], hi_ref[i]
-
-    @pl.when(hi > lo)
+    @pl.when(pl.program_id(0) < live_ref[0])
     def _():
         x = x_ref[...]
         gate = jnp.dot(x, w1_ref[...], preferred_element_type=jnp.float32)
         up = jnp.dot(x, w3_ref[...], preferred_element_type=jnp.float32)
-        y = jnp.dot((jax.nn.silu(gate) * up).astype(x.dtype), w2_ref[...],
-                    preferred_element_type=jnp.float32)
-        rows = jax.lax.broadcasted_iota(jnp.int32, y.shape, 0)
-        o_ref[...] = jnp.where((rows >= lo) & (rows < hi), y, o_ref[...])
+        o_ref[...] = jnp.dot((jax.nn.silu(gate) * up).astype(x.dtype),
+                             w2_ref[...], preferred_element_type=jnp.float32)
 
 
-def _sorted_swiglu(xs, counts, w1, w3, w2, *, tile: int):
-    """``xs`` [R, d] sorted by expert, expert ``g``'s ``counts[g]`` rows
-    after expert ``g - 1``'s and ``sum(counts) == R`` -> float32 [R, d]:
-    each row's ``(silu(x w1[g]) * (x w3[g])) w2[g]`` on its own
-    expert's matrices, bfloat16 operands as they come, float32
-    accumulation, ``silu(gate) * up`` in float32 rounded once to ``xs``'
-    dtype. One ``nns_grouped_swiglu`` call (module docstring). It holds
-    an expert's three matrices twice over (one set read while the last
-    is multiplied), so it asks for the VMEM that takes."""
+def _aligned_swiglu(xs, expert, live, row, w1, w3, w2, *, tile: int,
+                    align: int):
+    """``xs`` [rows, d], each expert's run from a multiple of ``align``
+    rows on (:func:`_spread`); ``expert``, ``live``, ``row`` from
+    :func:`_walk` -> float32 ``[steps * tile, d]``: step ``i``'s tile
+    holds the ``tile`` rows of ``xs`` from ``row[i]`` on, each as
+    ``(silu(x w1[g]) * (x w3[g])) w2[g]`` on the step's expert's
+    matrices, bfloat16 operands as they come, float32 accumulation,
+    ``silu(gate) * up`` in float32 rounded once to ``xs``' dtype; the
+    tiles from ``live[0]`` on are left as they are. One
+    ``nns_grouped_swiglu`` call (module docstring). It holds an expert's
+    three matrices twice over (one set read while the last is
+    multiplied), so it asks for the VMEM that takes."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    rows, d = xs.shape
-    f = w1.shape[2]
-    padded = -(-rows // tile) * tile
-    if padded != rows:
-        xs = jnp.pad(xs, ((0, padded - rows), (0, 0)))
-    walk = _walk(counts, padded, tile)
+    d, f = w1.shape[1:]
 
-    def row_tile(i, expert, tile_of, lo, hi):
-        return tile_of[i], 0
+    def rows_in(i, expert, live, row):
+        # an element offset, not a block's: Mosaic takes a row of HBM that
+        # it can prove a multiple of the sublane tile, and no other
+        return pl.multiple_of(row[i], align), 0
 
-    def matrices(i, expert, tile_of, lo, hi):
+    def tile_out(i, expert, live, row):
+        return jnp.minimum(i, live[0] - 1), 0
+
+    def matrices(i, expert, live, row):
         return expert[i], 0, 0
 
     blocks = (3 * d * f + tile * d) * xs.dtype.itemsize + tile * d * 4
-    out = pl.pallas_call(
+    return pl.pallas_call(
         _swiglu_kernel,
-        out_shape=jax.ShapeDtypeStruct((padded, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((expert.shape[0] * tile, d),
+                                       jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(walk), grid=walk[0].shape,
-            in_specs=[pl.BlockSpec((tile, d), row_tile),
+            num_scalar_prefetch=3, grid=expert.shape,
+            in_specs=[pl.BlockSpec((pl.Element(tile), pl.Element(d)),
+                                   rows_in),
                       pl.BlockSpec((None, d, f), matrices),
                       pl.BlockSpec((None, d, f), matrices),
                       pl.BlockSpec((None, f, d), matrices)],
-            out_specs=pl.BlockSpec((tile, d), row_tile)),
+            out_specs=pl.BlockSpec((tile, d), tile_out)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=2 * blocks + (16 << 20)),
         interpret=jax.default_backend() != "tpu",
         name="nns_grouped_swiglu",
-    )(*walk, xs, w1, w3, w2)
-    return out[:rows]
+    )(expert, live, row, xs, w1, w3, w2)
 
 
 def grouped_swiglu(x, order, counts, pair_weight, w1, w3, w2, *, tile: int,
@@ -169,11 +219,26 @@ def grouped_swiglu(x, order, counts, pair_weight, w1, w3, w2, *, tile: int,
     t, d = x.shape
     k = order.shape[0] // t
     if whole:
-        # the rows sorted by expert, gathered once: T x K of them, exact
-        y = _sorted_swiglu(x[order // k], counts, w1, w3, w2, tile=tile)
-        # back in pair order: a token's K rows side by side, weighted, summed
-        y = y[jnp.argsort(order)].reshape(t, k, d)
-        return jnp.sum(y * pair_weight[:, :, None], axis=1)
+        pairs, experts = t * k, counts.shape[0]
+        tiles = -(-pairs // tile) + experts - 1     # the most a routing takes
+        # the way in: every expert's run from a multiple of the dtype's
+        # sublane tile on (of a smaller ``tile``'s, which only the
+        # interpreter takes), a tile of slack behind the last; the pair
+        # in each slot by one sort of the slots (a free slot holds pair
+        # 0: a real row, multiplied at most and read by nobody)
+        align = math.gcd(tile, 32 // x.dtype.itemsize)
+        slots = pairs + experts * (align - 1) + tile
+        to, gaps = _spread(counts, pairs, align, slots)
+        _, pair = jax.lax.sort((jnp.concatenate([to, gaps]), jnp.concatenate(
+            [order.astype(jnp.int32), jnp.zeros_like(gaps)])), num_keys=1)
+        y = _aligned_swiglu(x[pair // k], *_walk(counts, tiles, tile, align),
+                            w1, w3, w2, tile=tile, align=align)
+        # the way out: every expert's run from a tile of its own on; back
+        # in pair order, a token's K rows side by side, weighted, summed
+        to, _ = _spread(counts, pairs, tile, tiles * tile)
+        _, slot = jax.lax.sort((order.astype(jnp.int32), to), num_keys=1)
+        return jnp.sum(y[slot].reshape(t, k, d) * pair_weight[:, :, None],
+                       axis=1)
     weight = pair_weight.reshape(-1)
     row0 = jnp.cumsum(counts) - counts           # an expert's first row
     half = tile // 2

@@ -363,25 +363,134 @@ def test_grouped_swiglu_holds_the_whole_router(routing, tile, dtype, tol,
         dtype == jnp.bfloat16)), rtol=tol)
 
 
-def test_grouped_kernel_walks_each_expert_and_tile_once():
-    """The kernel's grid (``_walk``): an expert's steps in turn, a
-    shared tile once for each expert with a row in it, nobody's expert
-    no step, the steps past the last one dead (no row) and on the last
-    one's blocks, so that nothing is fetched for them."""
-    from nnstreamer_tpu.ops.grouped import _walk
-    counts = jnp.asarray([0, 5, 3, 0, 0, 30, 1, 1, 0, 8, 0], jnp.int32)
-    expert, row_tile, lo, hi = (a.tolist() for a in _walk(counts, 48, 8))
-    assert len(expert) == 48 // 8 + 11 - 1
-    live = [(e, r, a, b) for e, r, a, b in zip(expert, row_tile, lo, hi)
-            if b > a]
-    assert live == [(1, 0, 0, 5), (2, 0, 5, 8), (5, 1, 0, 8), (5, 2, 0, 8),
-                    (5, 3, 0, 8), (5, 4, 0, 6), (6, 4, 6, 7), (7, 4, 7, 8),
-                    (9, 5, 0, 8)]
-    dead = len(live)
-    assert set(zip(expert[dead:], row_tile[dead:])) == {(9, 5)}
-    # each row served once
-    served = sorted(r * 8 + i for _, r, a, b in live for i in range(a, b))
-    assert served == list(range(48))
+@pytest.mark.parametrize("counts", [
+    [0, 5, 3, 9, 1, 14],            # nobody chose the first expert
+    [5, 3, 0, 0, 30, 1, 1, 0, 8],   # nor two in the middle, then one
+    [7, 17, 4, 0],                  # nor the last
+    [8, 9, 16, 1],                  # exactly a tile, a row more, two tiles
+    [3, 1, 7, 2, 5, 6],             # every expert under a tile
+    [0, 0, 41, 0],                  # all pairs on one expert
+    [8, 8, 8],                      # every boundary a tile's already
+    [0, 5, 3, 0, 0, 30, 1, 1, 0, 8, 0],
+], ids=["gap_first", "gap_middle", "gap_last", "tile_and_one_more",
+        "all_under_a_tile", "one_expert", "aligned_already", "pr35s"])
+def test_grouped_kernel_walks_an_expert_a_tile(counts):
+    """The kernel's grid (``_walk``) over the two layouts
+    (``_spread``): a step is a tile and a tile has one expert; an
+    expert's tiles are consecutive, ``ceil(count / tile)`` of them,
+    nobody's expert has none; ``tiles_walked`` is the count of live
+    steps; the steps past them repeat the last live one (its expert,
+    its rows in, and its block out, ``min(i, live - 1)``), so nothing
+    is fetched for them. On the way in every expert's run starts on a
+    multiple of ``align`` rows and a step reads ``tile`` rows from its
+    expert's ``j``-th tile on, inside the buffer; on the way out every
+    sorted row sits once, at row ``j`` of its expert's first tile on;
+    the free slots are all the others."""
+    from nnstreamer_tpu.ops.grouped import _spread, _walk, tiles_walked
+    tile, align, rows, g = 8, 2, sum(counts), len(counts)
+    tiles = -(-rows // tile) + g - 1
+    expert, live, row = (np.asarray(a) for a in _walk(
+        jnp.asarray(counts, jnp.int32), tiles, tile, align))
+    assert expert.shape == row.shape == (tiles,)
+    of = [-(-c // tile) for c in counts]
+    assert live.tolist() == [sum(of)] == [tiles_walked(np.asarray(counts),
+                                                       tile)]
+    assert int(tiles_walked(jnp.asarray(counts), tile)) == sum(of) <= tiles
+    live = int(live[0])
+    assert expert[:live].tolist() == np.repeat(np.arange(g), of).tolist()
+    assert set(expert[live:].tolist()) <= {int(expert[live - 1])}
+    assert set(row[live:].tolist()) <= {int(row[live - 1])}
+    # the way in: runs from multiples of ``align``, a tile of slack
+    slots = rows + g * (align - 1) + tile
+    to, gaps = (np.asarray(a) for a in _spread(
+        jnp.asarray(counts, jnp.int32), rows, align, slots))
+    held = [-(-c // align) * align for c in counts]
+    run = np.cumsum(held) - held
+    assert to.tolist() == [run[e] + j for e, c in enumerate(counts)
+                           for j in range(c)]
+    assert gaps.tolist() == sorted(set(range(slots)) - set(to.tolist()))
+    assert row[:live].tolist() == [run[e] + j * tile
+                                   for e, n in enumerate(of)
+                                   for j in range(n)]
+    assert (row % align == 0).all() and row.max() + tile <= slots
+    # the way out: every pair served once, on a tile of its own expert
+    to, gaps = (np.asarray(a) for a in _spread(
+        jnp.asarray(counts, jnp.int32), rows, tile, tiles * tile))
+    first = np.cumsum(of) - of
+    assert to.tolist() == [first[e] * tile + j for e, c in enumerate(counts)
+                           for j in range(c)]
+    assert expert[to // tile].tolist() == np.repeat(np.arange(g),
+                                                    counts).tolist()
+    assert gaps.tolist() == sorted(set(range(tiles * tile)) - set(to.tolist()))
+
+
+def _whole_router_case(rng, dtype, t=48, k=2, router=6, d=16, f=24):
+    """Operands on which every order of accumulation gives the same
+    bits: ``x``, ``w1``, ``w3`` small integers (gate and up are exact),
+    each column of an expert's ``w2`` one signed power of two (the last
+    product's sums have one term), so two statements of one product
+    differ only where they serve a row otherwise."""
+    x = rng.integers(-2, 3, (t, d)).astype(np.float32)
+    choice = np.stack([rng.permutation(router)[:k] for _ in range(t)]
+                      ).astype(np.int32)
+    weight = rng.random((t, k)).astype(np.float32)
+    w1, w3 = (rng.integers(-2, 3, (router, d, f)).astype(np.float32)
+              for _ in range(2))
+    w2 = np.zeros((router, f, d), np.float32)
+    for e in range(router):
+        w2[e, rng.integers(0, f, d), np.arange(d)] = \
+            rng.choice([-2., -1., -.5, .5, 1., 2.], d)
+    order, counts = group_by_expert(jnp.asarray(choice), 0, router)
+    return (jnp.asarray(x, dtype), order, counts, jnp.asarray(weight),
+            *(jnp.asarray(w, dtype) for w in (w1, w3, w2)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_grouped_kernel_is_three_ragged_dots_to_the_bit(dtype):
+    """The module's docstring: the ``whole`` form's result is that of
+    three ``jax.lax.ragged_dot`` s on the buffer sorted by expert, with
+    ``silu(gate) * up`` rounded once between them, bit for bit (on
+    operands that leave the order of accumulation no say, which the
+    CPU's two products do not share; on the chip it was read on the
+    cell's own, PERF.md)."""
+    args = _whole_router_case(np.random.default_rng(5), dtype)
+    t, k = args[3].shape
+
+    def ragged(x, order, counts, weight, w1, w3, w2):
+        xs = x[order // k]
+        gate, up = (jax.lax.ragged_dot(
+            xs, w, counts, preferred_element_type=jnp.float32)
+            for w in (w1, w3))
+        y = jax.lax.ragged_dot((jax.nn.silu(gate) * up).astype(x.dtype), w2,
+                               counts, preferred_element_type=jnp.float32)
+        y = y[jnp.argsort(order)].reshape(t, k, -1)
+        return jnp.sum(y * weight[:, :, None], axis=1)
+
+    got = jax.jit(lambda *a: grouped_swiglu(*a, tile=8, whole=True))(*args)
+    want = np.asarray(jax.jit(ragged)(*args))
+    assert np.abs(want).max() > 1
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_grouped_kernel_reads_no_row_of_a_tiles_tail():
+    """A step multiplies a whole tile: past its expert's rows the
+    slots left free by the alignment (they hold pair 0's row, a real
+    one) and the next experts' rows, on the wrong matrices, stored
+    where nobody reads. With pair 0's token poisoned (NaN), every other
+    token's result is what it was, bit for bit, though the tiles hold
+    the NaN row many times over."""
+    from nnstreamer_tpu.ops.grouped import tiles_walked
+    x, order, counts, *rest = _whole_router_case(np.random.default_rng(9),
+                                                 jnp.float32)
+    t, k, tile = x.shape[0], order.shape[0] // x.shape[0], 8
+    # more than a tile's worth of tail rows in the live tiles
+    assert int(tiles_walked(counts, tile)) * tile - t * k >= 8
+    run = jax.jit(lambda *a: grouped_swiglu(*a, tile=tile, whole=True))
+    clean = np.asarray(run(x, order, counts, *rest))
+    dirty = np.asarray(run(x.at[0].set(jnp.nan), order, counts, *rest))
+    assert np.isnan(dirty[0]).all() and np.isfinite(clean).all()
+    np.testing.assert_array_equal(dirty[1:], clean[1:])
 
 
 def _choice(case, rng, t, k, tile, held_first, held, router):
