@@ -199,10 +199,8 @@ def head_weights(a, cfg: AfmoeConfig):
     the chip the faster one), so that their products write q, k, v and
     the gate head-major, as the kernel reads a head. No input is in it:
     the jax filter runs it once per load (``filters/prepare.py``)."""
-    def laid(w):
-        return jnp.transpose(w.reshape(w.shape[0], -1, cfg.head_dim),
-                             (1, 2, 0))
-    return tuple(laid(a[n]) for n in ("wq", "wk", "wv", "wg"))
+    return tuple(latent.head_major(a[n], cfg.head_dim)
+                 for n in ("wq", "wk", "wv", "wg"))
 
 
 def attend(h, layer, kind: str, cfg: AfmoeConfig):
@@ -247,7 +245,7 @@ def moe(x, m, cfg: AfmoeConfig):
         e = m["experts"]
         routed = grouped_swiglu(x, order, load, weight, e["w1"], e["w3"],
                                 e["w2"], tile=EXPERT_TILE,
-                                whole=cfg.held == cfg.num_experts)
+                                router=cfg.num_experts)
         return out + routed, load
 
 

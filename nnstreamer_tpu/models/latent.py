@@ -1,7 +1,7 @@
 """Parts the scoring decoders share (``models/glm_dsa.py``,
-``models/longcat.py``, ``models/afmoe.py``): rotary embedding on
-interleaved pairs and the latent (MLA) projections in the expanded form
-(the two latent-attention decoders'), causal attention with the output
+``models/longcat.py``, ``models/afmoe.py``, ``models/kimi_linear.py``):
+rotary embedding on interleaved pairs and the latent (MLA) projections
+in the expanded form (the latent-attention decoders'), causal attention with the output
 projection, a SwiGLU MLP, the sigmoid router, the scoring head, and the
 frame and zoo wrappers of a scoring pass. A decoder's own file holds
 what is its own: the layer's order, its projections, an indexer.
@@ -103,6 +103,14 @@ def _mm_heads(x, w):
                       preferred_element_type=jnp.float32).astype(x.dtype)
 
 
+def head_major(w, head_dim: int):
+    """A projection ``w`` [d, heads x head_dim] a head at a time with
+    the contracted dimension last, ``[heads, head_dim, d]``
+    (:func:`mla_weights`' layout, read on the chip the faster one), so
+    that :func:`_mm_heads` writes its product head-major."""
+    return jnp.transpose(w.reshape(w.shape[0], -1, head_dim), (1, 2, 0))
+
+
 def mla_weights(a, cfg):
     """What :func:`mla_qkv` multiplies the two latents by, from the
     attention sublayer's leaves ``a`` alone: ``(wq [H, wide, r_q], wk
@@ -114,8 +122,11 @@ def mla_weights(a, cfg):
     last: the array the TPU compiler otherwise copies each weight into
     for these products, and read on the chip the faster one (PERF.md,
     PR 33). No input is in it, so the jax filter runs it once per load
-    (``filters/prepare.py``). Reads ``cfg.num_attention_heads``,
-    ``qk_nope_head_dim``, ``qk_rope_head_dim`` and ``kv_lora_rank``."""
+    (``filters/prepare.py``). A sublayer without a query latent
+    (``q_lora_rank`` null: no ``wq_a``) has its one query projection
+    under ``wq``, and ``r_q`` is the stream's width. Reads
+    ``cfg.num_attention_heads``, ``qk_nope_head_dim``,
+    ``qk_rope_head_dim`` and ``kv_lora_rank``."""
     h, nope = cfg.num_attention_heads, cfg.qk_nope_head_dim
     d = nope + cfg.qk_rope_head_dim
     wide = -(-d // LANES) * LANES
@@ -129,7 +140,8 @@ def mla_weights(a, cfg):
         return jnp.transpose(w, (1, 2, 0))
 
     w = a["wkv_b"].reshape(cfg.kv_lora_rank, h, -1)
-    return (laid(a["wq_b"].reshape(-1, h, d), wide),
+    wq = a["wq_b"] if "wq_a" in a else a["wq"]
+    return (laid(wq.reshape(-1, h, d), wide),
             laid(w[..., :nope], wide), laid(w[..., nope:]))
 
 
@@ -142,14 +154,18 @@ def _scaled(x, scale: float):
 
 
 def mla_qkv(x, a, positions, cfg, *, q_scale: float = 1.0,
-            kv_scale: float = 1.0):
+            kv_scale: float = 1.0, rope: bool = True):
     """The latent projections of normed ``x`` [S, d]: ``c_q`` [S, r_q]
     and per-head ``q`` [S, H, wide], ``k`` [S, H, wide] (the one roped
     key part repeated to every head), ``v`` [S, H, v].
     ``q_scale`` / ``kv_scale`` multiply the two latents after their
     norms (``mla_scale_q_lora`` / ``mla_scale_kv_lora``; the roped key
-    part is not scaled). Reads ``cfg.num_attention_heads``,
-    ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``kv_lora_rank``,
+    part is not scaled). A sublayer without ``wq_a`` / ``q_norm`` has no
+    query latent: its queries are projected from ``x`` itself, which is
+    then what comes back as ``c_q``. ``rope`` False encodes no position
+    (``mla_use_nope``): the key part every head shares and the queries'
+    columns beside it go on as they are. Reads
+    ``cfg.num_attention_heads``, ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``kv_lora_rank``,
     ``rms_norm_eps`` and ``rope_theta``.
 
     All three are views of head-major arrays, which no transpose or
@@ -160,13 +176,19 @@ def mla_qkv(x, a, positions, cfg, *, q_scale: float = 1.0,
     is zero: exact)."""
     nope, rp, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
     wq, wk, wv = mla_weights(a, cfg)
-    c_q = _scaled(rmsnorm(_mm(x, a["wq_a"]), a["q_norm"], cfg.rms_norm_eps),
-                  q_scale)
-    q = rope_columns(_mm_heads(c_q, wq), positions, cfg.rope_theta, nope, rp)
+    c_q = x
+    if "wq_a" in a:
+        c_q = _scaled(rmsnorm(_mm(x, a["wq_a"]), a["q_norm"],
+                              cfg.rms_norm_eps), q_scale)
+    q = _mm_heads(c_q, wq)
+    if rope:
+        q = rope_columns(q, positions, cfg.rope_theta, nope, rp)
     kv = _mm(x, a["wkv_a"])
     c_kv = _scaled(rmsnorm(kv[:, :r], a["kv_norm"], cfg.rms_norm_eps),
                    kv_scale)
-    k_r = rope_interleaved(kv[:, r:], positions, cfg.rope_theta)
+    k_r = kv[:, r:]
+    if rope:
+        k_r = rope_interleaved(k_r, positions, cfg.rope_theta)
     k = _mm_heads(c_kv, wk)
     k = k + jnp.pad(k_r, ((0, 0), (nope, k.shape[-1] - nope - rp)))
     v = _mm_heads(c_kv, wv)

@@ -16,9 +16,21 @@ dropped pair; the cost follows the pairs served, padded to half a tile
 an expert. ``jax.lax.ragged_dot`` states the same product on a buffer
 of the rows sorted by expert, which must be sized for the worst routing,
 ``T x K`` rows, where the loops need one tile: sixteen times the rows
-served when a chip holds a sixteenth of the router. **When every expert
-of the router is held** (``whole``), ``T x K`` is exact: every pair is
-served here and none belongs to another chip. Then the rows are
+served when a chip holds a sixteenth of the router. **Which form a
+layer takes follows from the share it holds** (:func:`takes_kernel`;
+what the layer observes of itself, no option): **at least half the
+router** (``2 G >= router``: the buffer is then at most twice the rows
+an even routing serves; the whole router, where ``T x K`` is exact,
+among them) takes the sorted buffer and the kernel below, any smaller
+share the loops. Read on the chip at a half, 128 of a router of 256 at
+8192 tokens, 2304 x 1024 experts and a skewed routing (26,936 of the
+65,536 pairs here, one expert 3,643): the kernel's path **9.41 ms a
+layer, the loops 12.07** (PERF.md, PR 38); at a sixteenth the loops
+stay (GLM-5's and LongCat's 16 held experts: nothing of theirs
+changed). The pairs of experts held elsewhere are sorted last
+(:func:`group_by_expert`), so their rows lie behind the last held
+expert's run, where no grid step begins, and on the way out they count
+as nothing. On that path the rows are
 gathered once in expert order, one Pallas TPU kernel,
 ``nns_grouped_swiglu`` (:func:`_aligned_swiglu`), runs the three
 products and ``silu(gate) * up`` between them, and the results go back
@@ -132,7 +144,7 @@ def _walk(counts, tiles: int, tile: int, align: int):
     ``[tiles]``, the expert's run from its first row on. The steps past
     the live ones repeat the last (nothing is fetched for them)."""
     live = tiles_walked(counts, tile)
-    at = jnp.minimum(jnp.arange(tiles), live - 1)
+    at = jnp.clip(jnp.arange(tiles), 0, jnp.maximum(live - 1, 0))
     of = -(-counts // tile)
     expert = jnp.repeat(jnp.arange(counts.shape[0]), of,
                         total_repeat_length=tiles)[at]
@@ -181,7 +193,7 @@ def _aligned_swiglu(xs, expert, live, row, w1, w3, w2, *, tile: int,
         return pl.multiple_of(row[i], align), 0
 
     def tile_out(i, expert, live, row):
-        return jnp.minimum(i, live[0] - 1), 0
+        return jnp.clip(i, 0, jnp.maximum(live[0] - 1, 0)), 0
 
     def matrices(i, expert, live, row):
         return expert[i], 0, 0
@@ -207,25 +219,37 @@ def _aligned_swiglu(xs, expert, live, row, w1, w3, w2, *, tile: int,
     )(expert, live, row, xs, w1, w3, w2)
 
 
+def takes_kernel(held: int, router: int) -> bool:
+    """Whether a chip that holds ``held`` of a router's ``router``
+    experts runs the kernel (module docstring): where it holds at least
+    half of them, the sorted buffer's worst case (every pair served
+    here) is at most twice the rows served on an even routing."""
+    return 2 * held >= router
+
+
 def grouped_swiglu(x, order, counts, pair_weight, w1, w3, w2, *, tile: int,
-                   whole: bool = False):
+                   router: int = 0):
     """``x`` [T, d]; ``order``, ``counts`` from :func:`group_by_expert`;
     ``pair_weight`` [T, K] float32; ``w1``, ``w3`` [G, d, f], ``w2``
     [G, f, d] -> float32 [T, d]: for each token the sum over its pairs
     with a held expert of ``weight * (silu(x w1) * (x w3)) w2``.
     ``tile``: the rows a loop's turn or the kernel's grid step takes.
-    ``whole``: the ``G`` experts are the whole router, so every one of
-    the ``T x K`` pairs is served here (module docstring)."""
+    ``router``: the width of the router the ``G`` experts are a share of
+    (what the layer knows of itself, not a choice of form: the share
+    decides, :func:`takes_kernel`; a caller that gives none keeps the
+    loops)."""
     t, d = x.shape
     k = order.shape[0] // t
-    if whole:
+    if router and takes_kernel(counts.shape[0], router):
         pairs, experts = t * k, counts.shape[0]
         tiles = -(-pairs // tile) + experts - 1     # the most a routing takes
         # the way in: every expert's run from a multiple of the dtype's
         # sublane tile on (of a smaller ``tile``'s, which only the
         # interpreter takes), a tile of slack behind the last; the pair
         # in each slot by one sort of the slots (a free slot holds pair
-        # 0: a real row, multiplied at most and read by nobody)
+        # 0: a real row, multiplied at most and read by nobody). Pairs
+        # of experts held elsewhere are sorted last: their rows lie
+        # behind the last held expert's, where no step begins
         align = math.gcd(tile, 32 // x.dtype.itemsize)
         slots = pairs + experts * (align - 1) + tile
         to, gaps = _spread(counts, pairs, align, slots)
@@ -236,8 +260,19 @@ def grouped_swiglu(x, order, counts, pair_weight, w1, w3, w2, *, tile: int,
         # the way out: every expert's run from a tile of its own on; back
         # in pair order, a token's K rows side by side, weighted, summed
         to, _ = _spread(counts, pairs, tile, tiles * tile)
-        _, slot = jax.lax.sort((order.astype(jnp.int32), to), num_keys=1)
-        return jnp.sum(y[slot].reshape(t, k, d) * pair_weight[:, :, None],
+        if experts == router:
+            _, slot = jax.lax.sort((order.astype(jnp.int32), to), num_keys=1)
+            rows = y[slot]
+        else:
+            # a pair served elsewhere has no row here: it reads the first
+            # and counts as nothing (not as 0 times it: a tile no step
+            # wrote holds whatever the memory held)
+            here = jnp.arange(pairs) < jnp.sum(counts)
+            _, slot, here = jax.lax.sort(
+                (order.astype(jnp.int32), jnp.where(here, to, 0), here),
+                num_keys=1)
+            rows = jnp.where(here[:, None], y[slot], 0.0)
+        return jnp.sum(rows.reshape(t, k, d) * pair_weight[:, :, None],
                        axis=1)
     weight = pair_weight.reshape(-1)
     row0 = jnp.cumsum(counts) - counts           # an expert's first row

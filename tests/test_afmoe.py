@@ -203,13 +203,16 @@ def test_config_reads_the_published_keys():
 
 @pytest.mark.parametrize("share,grouped", [
     ("", 3), ("&held_first=0&held_count=16", 3),
-    ("&held_first=4&held_count=4", 0), ("&held_first=0&held_count=15", 0)],
-    ids=["default", "all_sixteen", "a_quarter", "all_but_one"])
+    ("&held_first=4&held_count=4", 0), ("&held_first=0&held_count=15", 3),
+    ("&held_first=8&held_count=8", 3), ("&held_first=0&held_count=7", 0)],
+    ids=["default", "all_sixteen", "a_quarter", "all_but_one", "a_half",
+         "under_a_half"])
 def test_whole_router_runs_the_grouped_kernel(share, grouped):
     """``kernel_calls``: ``nns_grouped_swiglu`` once an expert layer
-    where the held experts are the whole router (what the layer
-    observes, no option), and not at all where any expert lives
-    elsewhere: those keep the tile loops."""
+    where the held experts are at least half the router (what the layer
+    observes, no option: ``ops/grouped.py::takes_kernel``), the whole
+    router among them, and not at all under a half: those keep the tile
+    loops."""
     caps = ("other/tensors,format=static,num_tensors=1,types=(string)int32,"
             "dimensions=(string)64,framerate=0/1")
     p = parse_launch(f'appsrc name=in caps="{caps}" ! tensor_filter name=f '

@@ -660,6 +660,50 @@ def test_mosaic_takes_the_window_walk_over_shared_heads(topology, lo, hi,
     assert tuple(call.params["grid_mapping"].grid) == (16, 1, tiles)
 
 
+def test_mosaic_takes_the_chunked_recurrence_at_the_cells_widths(
+        topology, monkeypatch):
+    """The TPU's compiler lays out ``ops/kda.py``'s two kernels at the
+    Kimi-Linear cell's widths: 32 heads of 128, S = 8192, chunks of 64
+    (an interpret-mode run shows neither an unaligned slice nor what a
+    kernel may hold in VMEM), and the grouped kernel at half a router:
+    128 experts of 2304 x 1024 over 8192 tokens choosing 8 of 256. The
+    op asks ``jax.default_backend()`` whether to interpret; this
+    process' answer is steered here, not by an option of the program."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from nnstreamer_tpu.ops import grouped, kda
+    dev = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name=topology).devices[0])
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    def experts(x, choice, weight, w1, w3, w2):
+        order, counts = grouped.group_by_expert(choice, 0, 128)
+        return grouped.grouped_swiglu(x, order, counts, weight, w1, w3, w2,
+                                      tile=256, router=256)
+
+    head = (32, 8192, 128)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        with jax.default_matmul_precision("default"):
+            text = jax.jit(lambda *a: kda.kda_chunked(*a, chunk=64)).lower(
+                spec(head), spec(head), spec(head), spec(head, jnp.float32),
+                spec(head[:2], jnp.float32)).compile().as_text()
+            half = jax.jit(experts).lower(
+                spec((8192, 2304)), spec((8192, 8), jnp.int32),
+                spec((8192, 8), jnp.float32), spec((128, 2304, 1024)),
+                spec((128, 2304, 1024)), spec((128, 1024, 2304))
+            ).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "nns_kda_chunk_intra" in text and "nns_kda_chunk_state" in text
+    assert half.count('custom_call_target="tpu_custom_call"') == 1
+    assert "nns_grouped_swiglu" in half
+
+
 @pytest.mark.parametrize("model,calls", [
     ("zoo://longcat?seq=128&v_head_dim=128&held_first=4&held_count=4", 4),
     ("zoo://glm_dsa?seq=128&v_head_dim=128&held_first=8&held_count=8", 3),

@@ -330,7 +330,7 @@ def test_grouped_swiglu_holds_the_whole_router(routing, tile, dtype, tol,
     x K`` pairs is served here, none goes to the tail, and the result is
     the dense form's (every expert over every token, weighted by the
     token's weight for it or 0), from the tile loops and from the
-    sorted form (``whole``: the rows gathered once, the kernel
+    sorted form (the router's width named: the rows gathered once, the kernel
     ``nns_grouped_swiglu`` through the interpreter, gathered back)
     alike. The routings are those a walk over row tiles can get wrong:
     experts that serve nobody first, last and two in the middle, an
@@ -349,7 +349,8 @@ def test_grouped_swiglu_holds_the_whole_router(routing, tile, dtype, tol,
     order, counts = group_by_expert(jnp.asarray(choice), 0, router)
     assert counts.tolist() == counts_want.tolist()
     assert sorted(np.asarray(order).tolist()) == list(range(t * k))
-    got = jax.jit(lambda *a: grouped_swiglu(*a, tile=tile, whole=whole))(
+    got = jax.jit(lambda *a: grouped_swiglu(
+        *a, tile=tile, router=router if whole else 0))(
         *(jnp.asarray(a, dtype) for a in (x,)), order, counts, weight,
         *(jnp.asarray(a, dtype) for a in (w1, w3, w2)))
     assert got.dtype == jnp.float32
@@ -467,7 +468,7 @@ def test_grouped_kernel_is_three_ragged_dots_to_the_bit(dtype):
         y = y[jnp.argsort(order)].reshape(t, k, -1)
         return jnp.sum(y * weight[:, :, None], axis=1)
 
-    got = jax.jit(lambda *a: grouped_swiglu(*a, tile=8, whole=True))(*args)
+    got = jax.jit(lambda *a: grouped_swiglu(*a, tile=8, router=6))(*args)
     want = np.asarray(jax.jit(ragged)(*args))
     assert np.abs(want).max() > 1
     np.testing.assert_array_equal(np.asarray(got), want)
@@ -486,7 +487,7 @@ def test_grouped_kernel_reads_no_row_of_a_tiles_tail():
     t, k, tile = x.shape[0], order.shape[0] // x.shape[0], 8
     # more than a tile's worth of tail rows in the live tiles
     assert int(tiles_walked(counts, tile)) * tile - t * k >= 8
-    run = jax.jit(lambda *a: grouped_swiglu(*a, tile=tile, whole=True))
+    run = jax.jit(lambda *a: grouped_swiglu(*a, tile=tile, router=6))
     clean = np.asarray(run(x, order, counts, *rest))
     dirty = np.asarray(run(x.at[0].set(jnp.nan), order, counts, *rest))
     assert np.isnan(dirty[0]).all() and np.isfinite(clean).all()
