@@ -152,8 +152,15 @@ class TestRouterE2E:
             assert st["router_shed"] == 0
             assert st["router_orphaned"] == 0
             # heartbeats flowed: both replicas healthy with load reports
-            time.sleep(0.2)
-            rep = rt.router_report()
+            # (a beat that a loaded machine delivers late reads "suspect"
+            # until the next one: wait for it, bounded)
+            deadline = time.monotonic() + 5.0
+            while True:
+                time.sleep(0.2)
+                rep = rt.router_report()
+                if time.monotonic() > deadline or all(
+                        r["state"] == "healthy" for r in rep.values()):
+                    break
             assert set(rep) == {f"localhost:{p}" for p in ports}
             for r in rep.values():
                 assert r["state"] == "healthy"
