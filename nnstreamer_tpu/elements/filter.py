@@ -20,7 +20,7 @@ from typing import Any, List, Optional
 import numpy as np
 
 from ..filters.base import (Accelerator, FilterEvent, FilterProperties,
-                            InvokeDrop)
+                            HeldStateNotCheckpointable, InvokeDrop)
 from ..filters.registry import (detect_framework, find_filter,
                                 shared_model_get, shared_model_insert,
                                 shared_model_release)
@@ -348,14 +348,30 @@ class TensorFilter(Element):
 
     # -- checkpoint/restore (checkpoint/) ---------------------------------
     CHECKPOINTABLE = ("whatever the loaded framework exposes (e.g. the "
-                      "llm backend's continuous-batching streams)")
+                      "llm backend's continuous-batching streams); NOT "
+                      "the device-resident state a jax model carries "
+                      "between buffers (a fifth item of get_model()): a "
+                      "snapshot of such a filter raises "
+                      "HeldStateNotCheckpointable")
 
     def snapshot_state(self, snap_dir):
-        # delegation, not ownership: the element is stateless between
-        # frames, but a framework may carry cross-invoke state (llm
-        # continuous batching) it knows how to snapshot
+        # delegation, not ownership: the element itself keeps nothing
+        # between frames, but a framework may carry cross-invoke state.
+        # The llm backend's continuous batching knows how to snapshot
+        # its own; the jax backend's held state (a model's recurrent
+        # state, on the device, advanced by every buffer) does not yet,
+        # and a snapshot that left it out would restore a stream that
+        # reads its documents' later buffers from an empty state
         if self.fw is not None and hasattr(self.fw, "snapshot_state"):
             return self.fw.snapshot_state(snap_dir)
+        held = self._held_state()
+        if held is not None:
+            raise HeldStateNotCheckpointable(
+                f"{self.name}: the model carries {held['leaves']} state "
+                f"arrays ({held['bytes']} bytes) on the device between "
+                "buffers, which checkpoint/ cannot snapshot yet; drain "
+                "the stream to a document's end and snapshot a pipeline "
+                "whose filter is stopped, or leave this element out")
         if self._fw_restore is not None:
             return self._fw_restore[0]  # restored, never started: re-emit
         return None
@@ -832,8 +848,10 @@ class TensorFilter(Element):
         (filters/prepare.py: equations run once per load),
         ``prepared_leaves`` / ``prepared_bytes`` (parameters held a
         second time in their compute dtype) and ``kernel_calls`` (the
-        program's Pallas kernels by name, with their call sites), and
-        under ``load`` what :meth:`load_report` gives; {} when running
+        program's Pallas kernels by name, with their call sites),
+        under ``load`` what :meth:`load_report` gives, and under
+        ``state``, for a model that carries one between buffers,
+        ``{leaves, bytes, dispatches, drops}``; {} when running
         synchronously with nothing prepared, no kernel and a backend
         that keeps no account of its load."""
         rep = self._overlap.report() if self._overlap is not None else {}
@@ -845,7 +863,16 @@ class TensorFilter(Element):
         load = self.load_report()
         if load is not None:
             rep = {**rep, "load": load}
+        state = self._held_state()
+        if state is not None:
+            rep = {**rep, "state": state}
         return rep
+
+    def _held_state(self) -> Optional[dict]:
+        """The backend's account of a state it carries between buffers
+        (``state_report()``), None where it has none."""
+        report = getattr(self.fw, "state_report", None)
+        return report() if callable(report) else None
 
     def load_report(self) -> Optional[dict]:
         """Where the seconds between ``start()`` and the first buffer

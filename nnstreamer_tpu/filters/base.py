@@ -39,6 +39,13 @@ class InvokeDrop(Exception):
     drop the frame rather than killing the pipeline."""
 
 
+class HeldStateNotCheckpointable(RuntimeError):
+    """Raised by ``tensor_filter.snapshot_state`` when the loaded model
+    carries a device-resident state between buffers (the jax backend's
+    five-item ``get_model()``): ``checkpoint/`` cannot snapshot it yet,
+    and a snapshot without it would not be the stream's."""
+
+
 class FilterEvent(enum.Enum):
     """(ref: event_ops enum, nnstreamer_plugin_api_filter.h:205-217)"""
 

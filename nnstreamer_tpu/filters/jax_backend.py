@@ -15,6 +15,23 @@ Model URIs accepted by the ``model`` property:
     ``get_model() -> (apply_fn, params, input_info, output_info)``.
   * ``<dir>`` with orbax checkpoint + ``model.json`` zoo spec.
 
+**A state the filter keeps.** ``get_model()`` (and a zoo builder) may
+return a fifth item, the tree of the model's state before its first
+buffer. The program is then ``apply_fn(params, state, *inputs) ->
+(outputs, state)``: the filter places the tree on its device, hands it
+to every invoke, donated, and keeps what comes back for the next, in
+stream order (the lock that orders dispatches orders the states), with
+no host synchronisation: the next dispatch takes the still-materialising
+arrays, so an in-flight window stays as deep as it is. The state never
+leaves the device between buffers; suspend moves it to the host with the
+parameters and resume back. A reload, ``close()`` and a failed invoke
+drop it: the next buffer meets the initial state again
+(``transfer_report()["state"]["drops"]`` counts those). When a document
+starts is the model's to read off its inputs. The reference closes
+recurrent loops on the host (``tensor_repo``); that stays for arbitrary
+graphs, and this is the form in which a model's own state never leaves
+HBM. A four-item model runs the code it ran before there was a fifth.
+
 Outputs stay device-resident (jax.Array) so chained elements keep HBM
 residency; they materialize only at host boundaries.
 
@@ -29,9 +46,11 @@ frames are RPC'd to other devices; here the mesh IS the device pool).
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import threading
+import time
 import urllib.parse
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -77,6 +96,16 @@ def _device_for(accelerators: Sequence[Accelerator]):
     return jax.devices()[0]
 
 
+class _Held:
+    """What ``dispatch`` hands out for a model that carries a state:
+    the outputs, and which state (by its drop count) the frame ran on."""
+
+    __slots__ = ("out", "gen")
+
+    def __init__(self, out, gen):
+        self.out, self.gen = out, gen
+
+
 @register_filter
 class JaxFilter(FilterFramework):
     """framework=jax (aliases: jax-tpu). The flagship backend."""
@@ -109,6 +138,17 @@ class JaxFilter(FilterFramework):
         self._on_prepared: set = set()
         self._in_info: Optional[TensorsInfo] = None
         self._out_info: Optional[TensorsInfo] = None
+        # a model that carries a state between buffers (a fifth item of
+        # get_model()): the tree before the first buffer as the model
+        # gave it, the leaves as the last dispatch returned them (None:
+        # a stateless model, and a suspended or closed one), their tree,
+        # and the account transfer_report()["state"] gives
+        self._state0: Any = None
+        self._state: Optional[List[Any]] = None
+        self._state_tree: Any = None
+        self._state_bytes = 0
+        self._state_gen = 0       # rises with every drop
+        self._state_stats = {"dispatches": 0, "drops": 0}
         self._jit_cache: Dict[Tuple, Any] = {}
         self._device = None
         self._mesh = None
@@ -159,8 +199,19 @@ class JaxFilter(FilterFramework):
             self._place(self._device, held, 1)
             logger.info("jax filter opened model=%s on %s", model,
                         self._device)
+        if self._state0 is not None:
+            if self._mesh is not None:
+                raise ValueError(
+                    f"{model}: a model that carries a state between "
+                    "buffers runs on one chip; custom=mesh: cannot place "
+                    "its state")
+            self._place_state()
         self._cache_key = f"{model}|mesh={opts.get('mesh', '')}"
         self._prewarm_from_cache()
+        if self._state0 is not None and self._state_stats["dispatches"]:
+            # the replayed signatures ran on zeros: not the stream's
+            self._place_state()
+            self._state_stats["dispatches"] = 0
 
     def _place(self, where: Any, held: int, devices: int) -> None:
         """The loaded tree onto its device or mesh. ``device_put`` may
@@ -171,6 +222,38 @@ class JaxFilter(FilterFramework):
         import jax
         with self._load_log.phase("place", bytes=held, devices=devices):
             self._params = jax.device_put(self._params, where)
+
+    def _place_state(self) -> None:
+        """The initial state onto the device (lock held, or before the
+        first invoke), each leaf an array of its own that a dispatch may
+        donate: a leaf that already lies there is copied."""
+        import jax
+        import jax.numpy as jnp
+        leaves, self._state_tree = jax.tree.flatten(self._state0)
+        placed = [jax.device_put(x, self._device) for x in leaves]
+        self._state = [jnp.copy(y) if y is x else y
+                       for x, y in zip(leaves, placed)]
+        self._state_bytes = sum(int(x.nbytes) for x in self._state)
+
+    def _drop_state(self, gen: Optional[int] = None) -> None:
+        """Forget the carried state (lock held): the next buffer meets
+        the initial one. ``gen``: only if no drop came since the
+        dispatch that failed took its state."""
+        if self._state0 is None or gen not in (None, self._state_gen):
+            return
+        self._state_gen += 1
+        self._state_stats["drops"] += 1
+        if not self._suspended:
+            self._place_state()
+
+    def state_report(self) -> Optional[Dict[str, int]]:
+        """``{leaves, bytes, dispatches, drops}`` of the carried state
+        (``drops``: states lost to an error or a reload); None for a
+        model that carries none."""
+        if self._state0 is None:
+            return None
+        return {"leaves": self._state_tree.num_leaves,
+                "bytes": self._state_bytes, **self._state_stats}
 
     def _prewarm_from_cache(self) -> None:
         """Replay every signature this model compiled in previous lives
@@ -217,8 +300,7 @@ class JaxFilter(FilterFramework):
                       urllib.parse.parse_qs(parsed.query).items()}
             name = parsed.netloc or parsed.path.lstrip("/")
             self._model_stem = name
-            (self._apply, self._params,
-             self._in_info, self._out_info) = zoo.build(name, **kwargs)
+            self._take_model(zoo.build(name, **kwargs))
         elif model.endswith(".py"):
             ns: Dict[str, Any] = {}
             with open(model) as f:
@@ -226,22 +308,28 @@ class JaxFilter(FilterFramework):
             exec(compile(code, model, "exec"), ns)  # noqa: S102 - user script, like python3 subplugin
             if "get_model" not in ns:
                 raise ValueError(f"{model}: must define get_model()")
-            (self._apply, self._params,
-             self._in_info, self._out_info) = ns["get_model"]()
+            self._take_model(ns["get_model"]())
         elif os.path.isdir(model) and os.path.exists(
                 os.path.join(model, "model.json")):
             with open(os.path.join(model, "model.json")) as f:
                 spec = json.load(f)
             from ..models import zoo
-            (self._apply, self._params,
-             self._in_info, self._out_info) = zoo.build(
-                spec["name"], params_dir=model, **spec.get("kwargs", {}))
+            self._take_model(zoo.build(
+                spec["name"], params_dir=model, **spec.get("kwargs", {})))
         else:
             raise ValueError(f"jax backend cannot load model {model!r}")
+
+    def _take_model(self, model: Tuple) -> None:
+        """What ``get_model()`` or a zoo builder returned: four items,
+        or five with the state before the first buffer."""
+        (self._apply, self._params, self._in_info, self._out_info,
+         *state) = model
+        self._state0 = state[0] if state else None
 
     def close(self) -> None:
         self._apply = None
         self._params = None
+        self._state0 = self._state = None
         self._drop_programs()
 
     def _drop_programs(self) -> None:
@@ -307,6 +395,8 @@ class JaxFilter(FilterFramework):
         the trace is cut as the loaded model's first one was
         (filters/prepare.py), else ``jax.jit`` of ``apply_fn`` itself
         on the loaded tree."""
+        if self._state0 is not None:
+            return self._run_held(xs, donate_idx)
         sig = tuple((tuple(x.shape), str(x.dtype)) for x in xs)
         key = (sig, donate_idx) if donate_idx else sig
         exe = self._jit_cache.get(key)
@@ -322,20 +412,83 @@ class JaxFilter(FilterFramework):
         return exe(self._prepared if key in self._on_prepared
                    else self._params, *xs)
 
-    def _build(self, key: Tuple, sig: Tuple, xs: Sequence[Any],
-               donate_idx: Tuple[int, ...]) -> Any:
-        """The program for a signature the jit cache missed (lock
-        held), traced, cut and cached; compiled by its first call."""
+    def flat_apply(self) -> Callable:
+        """A stateful model's program over flat arguments, ``fn(params,
+        *state leaves, *inputs) -> (outputs, next state's leaves)``:
+        what :meth:`_run_held` jits (and ``tools/aot_estimate.py``
+        compiles for a described chip)."""
         import jax
+        apply_fn, tree = self._apply, jax.tree.structure(self._state0)
+        n = tree.num_leaves
+
+        def fn(params, *flat):
+            out, new = apply_fn(params, jax.tree.unflatten(tree, flat[:n]),
+                                *flat[n:])
+            return out, jax.tree.leaves(new)
+
+        return fn
+
+    def _run_held(self, xs: Sequence[Any],
+                  donate_idx: Tuple[int, ...]) -> Any:
+        """:meth:`_run` for a model that carries a state (lock held):
+        the program takes the parameters, the state's leaves and the
+        inputs, the leaves donated, and returns the outputs and the next
+        leaves, which are kept as they are, still materialising: the
+        next dispatch queues behind this one on the device and no host
+        thread waits. Whatever fails here drops the state."""
+        state = self._state
+        n = len(state)
+        sig = tuple((tuple(x.shape), str(x.dtype)) for x in xs)
+        key = (sig, donate_idx, "state")
+        exe = self._jit_cache.get(key)
+        if _obs_spans.ENABLED:
+            _obs_spans.record_span(
+                "nns.filter.state", "filter", time.time_ns(), 0,
+                prof="nns.filter.state", leaves=n, bytes=self._state_bytes)
+        # as in _run, the span ends with the first call's return
+        span = contextlib.nullcontext() if exe is not None else \
+            self._load_log.program(
+                "jit_" + _obs_spans.identifier(
+                    "nns_filter_" + self._model_stem), sig, donate_idx)
+        try:
+            with span:
+                if exe is None:
+                    donate = tuple(i + n for i in donate_idx)
+                    if self._device.platform in self._DONATION_PLATFORMS:
+                        donate = tuple(range(1, n + 1)) + donate
+                    exe = self._build(key, sig, [*state, *xs], donate_idx,
+                                      self.flat_apply(), donate)
+                out, new = exe(self._prepared if key in self._on_prepared
+                               else self._params, *state, *xs)
+        except BaseException:
+            self._drop_state()
+            raise
+        self._state = list(new)
+        self._state_stats["dispatches"] += 1
+        return out
+
+    def _build(self, key: Tuple, sig: Tuple, xs: Sequence[Any],
+               donate_idx: Tuple[int, ...], apply: Optional[Callable] = None,
+               donate: Optional[Tuple[int, ...]] = None) -> Any:
+        """The program for a signature the jit cache missed (lock
+        held), traced, cut and cached; compiled by its first call.
+        ``apply`` and ``donate`` are :meth:`_run_held`'s: the program
+        over the state's leaves and the inputs (``xs`` then holds both:
+        to the cut the state is an input, never a leaf) and what of it
+        is donated; without them the model's ``apply_fn`` and
+        ``donate_idx``."""
+        import jax
+        if donate is None:
+            donate = donate_idx
 
         def jit(fn):
             # a stable program name for the trace's XLA Modules line
             fn = _obs_spans.named_program(
                 "nns_filter_" + self._model_stem, fn)
-            return jax.jit(fn, donate_argnums=donate_idx) \
-                if donate_idx else jax.jit(fn)
+            return jax.jit(fn, donate_argnums=donate) \
+                if donate else jax.jit(fn)
 
-        exe = jit(self._apply)
+        exe = jit(apply or self._apply)
         with self._load_log.trace() as span:
             closed, out_tree, cut = _prepare.trace(exe, self._params, xs)
             span.note(equations=len(closed.jaxpr.eqns))
@@ -453,9 +606,29 @@ class JaxFilter(FilterFramework):
                                      else np.asarray(x), self._device)
                       for x in inputs]
             out = self._run(xs)
+            gen = self._state_gen if self._state0 is not None else None
+        if gen is not None:
+            # a failure must not reach the next buffer's state
+            out = self._ready(out, gen)
         if isinstance(out, (list, tuple)):
             return list(out)
         return [out]
+
+    def _ready(self, out: Any, gen: Optional[int]) -> Any:
+        """``out`` once it is materialised (no lock held: only the
+        arrays are touched). Where that raises and the frame ran on a
+        model's carried state (``gen``: that state's drop count), the
+        state its successors were built on is dropped, under the lock
+        and once for the state: the frames dispatched behind it fail on
+        their own."""
+        import jax
+        try:
+            return jax.block_until_ready(out)
+        except BaseException:
+            if gen is not None:
+                with self._lock:
+                    self._drop_state(gen)
+            raise
 
     # -- overlapped execution ---------------------------------------------
     def dispatch(self, inputs: Sequence[Any], donate: bool = False) -> Any:
@@ -501,15 +674,21 @@ class JaxFilter(FilterFramework):
                         and self._device.platform in self._DONATION_PLATFORMS:
                     donate_idx = tuple(staged)
             out = self._run(xs, donate_idx)
+            if self._state0 is not None:
+                out = _Held(out, self._state_gen)
         return out
 
     def complete(self, handle: Any) -> List[Any]:
         """Block until a dispatched frame's outputs are on-device
         materialized (raises the deferred device error, if any). Takes
         no lock: runs on the completer thread concurrently with
-        dispatch — block_until_ready only touches the arrays."""
-        import jax
-        out = jax.block_until_ready(handle)
+        dispatch — block_until_ready only touches the arrays. A frame
+        of a model that carries a state, failing here, drops that state
+        (:meth:`_ready`)."""
+        gen = None
+        if isinstance(handle, _Held):
+            handle, gen = handle.out, handle.gen
+        out = self._ready(handle, gen)
         if isinstance(out, (list, tuple)):
             return list(out)
         return [out]
@@ -534,8 +713,8 @@ class JaxFilter(FilterFramework):
             if self._suspended:
                 self._resume()
             apply_fn, params = self._apply, self._params
-            if apply_fn is None:
-                return None
+            if apply_fn is None or self._state0 is not None:
+                return None     # a carried state has no place in a segment
 
         def fn(*xs):
             import jax
@@ -565,6 +744,12 @@ class JaxFilter(FilterFramework):
             with self._lock:
                 self._apply, self._params = fresh._apply, fresh._params
                 self._in_info, self._out_info = fresh._in_info, fresh._out_info
+                if self._state0 is not None:    # the old model's is lost
+                    self._state_gen += 1
+                    self._state_stats["drops"] += 1
+                self._state0, self._state = fresh._state0, fresh._state
+                self._state_tree = fresh._state_tree
+                self._state_bytes = fresh._state_bytes
                 self._mesh = fresh._mesh
                 self._param_sharding = fresh._param_sharding
                 self._device = fresh._device
@@ -576,6 +761,8 @@ class JaxFilter(FilterFramework):
             import jax
             with self._lock:
                 self._params = jax.device_get(self._params)
+                if self._state is not None:     # kept, beside the parameters
+                    self._state = jax.device_get(self._state)
                 self._drop_programs()
                 self._suspended = True
             return True
@@ -591,6 +778,9 @@ class JaxFilter(FilterFramework):
             self._params = jax.device_put(
                 self._params, self._param_sharding if self._mesh is not None
                 else self._device)
+            if self._state is not None:
+                self._state = [jax.device_put(x, self._device)
+                               for x in self._state]
             self._suspended = False
 
 

@@ -192,17 +192,6 @@ def _init_params(cfg: AfmoeConfig, key):
             "layers": layers}
 
 
-def head_weights(a, cfg: AfmoeConfig):
-    """The four input projections of an attention sublayer from its
-    leaves ``a`` alone, a head at a time with the stream's dimension
-    last (``[heads, hd, d]``: ``latent.mla_weights``' layout, read on
-    the chip the faster one), so that their products write q, k, v and
-    the gate head-major, as the kernel reads a head. No input is in it:
-    the jax filter runs it once per load (``filters/prepare.py``)."""
-    return tuple(latent.head_major(a[n], cfg.head_dim)
-                 for n in ("wq", "wk", "wv", "wg"))
-
-
 def attend(h, layer, kind: str, cfg: AfmoeConfig):
     """The attention half of a layer of ``kind`` for one sequence ``h``
     [S, d] -> ``a``."""
@@ -211,13 +200,13 @@ def attend(h, layer, kind: str, cfg: AfmoeConfig):
     scope = "block/attn/window" if sliding else "block/attn/full"
     with jax.named_scope(scope):
         x = rmsnorm(h, layer["attn_norm"], eps)
-        wq, wk, wv, wg = head_weights(a, cfg)
-        # [heads, S, hd] as the products write them; the kernel's and the
-        # rotation's [S, heads, hd] are views
+        # [heads, S, hd] as the products write them (the weights a head
+        # at a time with the stream's dimension last, re-laid once per
+        # load: filters/prepare.py); the kernel's and the rotation's
+        # [S, heads, hd] are views
         q, k, v, gate = (jnp.transpose(t, (1, 0, 2)) for t in (
-            rmsnorm(_mm_heads(x, wq), a["q_norm"], eps),
-            rmsnorm(_mm_heads(x, wk), a["k_norm"], eps),
-            _mm_heads(x, wv), _mm_heads(x, wg)))
+            *latent.qkv_heads(x, a, cfg.head_dim, eps),
+            _mm_heads(x, latent.head_major(a["wg"], cfg.head_dim))))
         if sliding:
             positions = jnp.arange(h.shape[0], dtype=jnp.int32)
             q = rope(q, positions, cfg.rope_theta)

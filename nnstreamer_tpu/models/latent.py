@@ -1,5 +1,6 @@
 """Parts the scoring decoders share (``models/glm_dsa.py``,
-``models/longcat.py``, ``models/afmoe.py``, ``models/kimi_linear.py``):
+``models/longcat.py``, ``models/afmoe.py``, ``models/kimi_linear.py``,
+``models/brumby.py``):
 rotary embedding on interleaved pairs and the latent (MLA) projections
 in the expanded form (the latent-attention decoders'), causal attention with the output
 projection, a SwiGLU MLP, the sigmoid router, the scoring head, and the
@@ -109,6 +110,20 @@ def head_major(w, head_dim: int):
     (:func:`mla_weights`' layout, read on the chip the faster one), so
     that :func:`_mm_heads` writes its product head-major."""
     return jnp.transpose(w.reshape(w.shape[0], -1, head_dim), (1, 2, 0))
+
+
+def qkv_heads(x, a, head_dim: int, eps: float):
+    """The grouped-query projections of normed ``x`` [S, d] from an
+    attention sublayer's leaves ``a`` (``wq`` [d, H x hd], ``wk``,
+    ``wv`` [d, H_kv x hd], no bias; ``q_norm``, ``k_norm`` [hd]): ``q``
+    [H, S, hd] and ``k`` [H_kv, S, hd] through an RMSNorm over the
+    head's ``hd`` with a learned weight, ``v`` [H_kv, S, hd] as it is.
+    Head-major, as the products write them and as a kernel reads a
+    head; the weights' re-lay (:func:`head_major`) has no input in it,
+    so the jax filter runs it once per load (``filters/prepare.py``)."""
+    wq, wk, wv = (head_major(a[n], head_dim) for n in ("wq", "wk", "wv"))
+    return (rmsnorm(_mm_heads(x, wq), a["q_norm"], eps),
+            rmsnorm(_mm_heads(x, wk), a["k_norm"], eps), _mm_heads(x, wv))
 
 
 def mla_weights(a, cfg):

@@ -4,7 +4,10 @@
 ``(apply_fn, params, input_info, output_info)`` where ``apply_fn(params,
 *inputs)`` is a pure jittable function over *unbatched* frame tensors
 (builders add/remove the batch dim internally so pipeline caps stay
-per-frame, matching the reference's per-buffer invoke model).
+per-frame, matching the reference's per-buffer invoke model). A model
+that carries a state from buffer to buffer returns a fifth item, the
+state before its first buffer, and its ``apply_fn(params, state,
+*inputs)`` gives ``(outputs, state)`` (``filters/jax_backend.py``).
 
 Params default to deterministic random init (seed in kwargs); pass
 ``params_dir=<orbax dir>`` to load trained weights.
@@ -30,11 +33,12 @@ def register_model(name: str):
 def build(name: str, params_dir: Optional[str] = None, **kwargs):
     if name not in _ZOO:
         raise ValueError(f"unknown zoo model {name!r}; known: {sorted(_ZOO)}")
-    apply_fn, params, in_info, out_info = _ZOO[name](**kwargs)
+    # four items, or five with the state before the first buffer
+    apply_fn, params, *rest = _ZOO[name](**kwargs)
     if params_dir is not None:
         from ..trainers.checkpoint import restore_params
         params = restore_params(params_dir, params)
-    return apply_fn, params, in_info, out_info
+    return (apply_fn, params, *rest)
 
 
 def model_names():

@@ -13,9 +13,12 @@ windowed attention a block of queries at a time), ``grouped`` (the
 routed experts' product: the kernel ``nns_grouped_swiglu`` where a chip
 holds at least half of a router, ``2 x held >= router width``, 9.4 ms a
 layer against the tile loops' 12.1 at a half on the chip, PR 38; the
-tile loops for any smaller share) and ``kda`` (``nns_kda_chunk_intra``
+tile loops for any smaller share), ``kda`` (``nns_kda_chunk_intra``
 / ``nns_kda_chunk_state``: the chunked gated delta rule, a state
-carried from chunk to chunk).
+carried from chunk to chunk) and ``power_retention``
+(``nns_power_retention``: the chunked gated power retention of degree
+2, a state in and a state out, so that the jax filter can carry it from
+one buffer of a document to the next).
 """
 from .normalize import fused_normalize, normalize_reference
 

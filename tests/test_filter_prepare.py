@@ -704,6 +704,47 @@ def test_mosaic_takes_the_chunked_recurrence_at_the_cells_widths(
     assert "nns_grouped_swiglu" in half
 
 
+def test_mosaic_takes_the_power_retention_at_the_cells_widths(
+        topology, monkeypatch):
+    """The TPU's compiler lays out ``ops/power_retention.py``'s kernel
+    at the Brumby cell's widths: 40 query heads on 8 key/value heads of
+    128 over a buffer of 4096 tokens in chunks of 512, a head's state of
+    8320 x 128 float32 resident in VMEM through its chunks (an
+    interpret-mode run shows neither an unaligned slice, nor a broadcast
+    the compiler has not, nor what a kernel may hold in VMEM), the state
+    aliased into its output."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from nnstreamer_tpu.ops import power_retention as op
+    dev = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name=topology).devices[0])
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(
+                lambda q, k, v, g, s, z: op.power_retention(
+                    q, k, v, g, (s, z), chunk=512),
+                donate_argnums=(4, 5)).lower(
+                spec((40, 4096, 128)), spec((8, 4096, 128)),
+                spec((8, 4096, 128)), spec((8, 4096), jnp.float32),
+                spec((8, 8320, 128), jnp.float32),
+                spec((8, 128, 128), jnp.float32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        op._call.clear_cache()      # traced for the chip: not this process'
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "nns_power_retention" in text
+    # the donated state is the kernel's output: no second copy of it
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == 8 * (8320 + 128) * 128 * 4
+
+
 @pytest.mark.parametrize("model,calls", [
     ("zoo://longcat?seq=128&v_head_dim=128&held_first=4&held_count=4", 4),
     ("zoo://glm_dsa?seq=128&v_head_dim=128&held_first=8&held_count=8", 3),

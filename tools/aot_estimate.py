@@ -36,16 +36,21 @@ _INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.-]+) = (\S+) ([\w-]+)\("
 @contextlib.contextmanager
 def mosaic_kernels():
     """While a model is traced here, its attention kernel is built for
-    Mosaic: ``blocked_causal_attention`` asks ``jax.default_backend()``,
-    which is ``cpu`` in this process whatever topology is described."""
-    from nnstreamer_tpu.ops import sparse_attention
-    real = sparse_attention._attend_block
-    sparse_attention._attend_block = functools.wraps(real)(
-        lambda *args, interpret, **kw: real(*args, interpret=False, **kw))
+    Mosaic, and so is the power retention's: ``blocked_causal_attention``
+    and ``power_retention`` ask ``jax.default_backend()``, which is
+    ``cpu`` in this process whatever topology is described."""
+    from nnstreamer_tpu.ops import power_retention, sparse_attention
+    sites = [(sparse_attention, "_attend_block"), (power_retention, "_call")]
+    real = [getattr(mod, name) for mod, name in sites]
+    for (mod, name), fn in zip(sites, real):
+        setattr(mod, name, functools.wraps(fn)(
+            lambda *args, interpret, _fn=fn, **kw: _fn(
+                *args, interpret=False, **kw)))
     try:
         yield
     finally:
-        sparse_attention._attend_block = real
+        for (mod, name), fn in zip(sites, real):
+            setattr(mod, name, fn)
 
 
 def compile_text(model: str, batch: int, topology: str,
@@ -67,8 +72,14 @@ def compile_text(model: str, batch: int, topology: str,
     xs = [jax.ShapeDtypeStruct(((batch,) if batch else ()) + tuple(i.shape),
                                i.type.np_dtype) for i in fw._in_info]
     leaves = jax.tree.leaves(shapes)
+    apply_fn = fw._apply
+    if fw._state0 is not None:
+        # a model that carries a state: its leaves are inputs, before xs
+        apply_fn = fw.flat_apply()
+        xs = [jax.ShapeDtypeStruct(x.shape, x.dtype)
+              for x in jax.tree.leaves(fw._state0)] + xs
     with mosaic_kernels():
-        closed, out_tree, cut = prepare.trace(jax.jit(fw._apply), shapes, xs)
+        closed, out_tree, cut = prepare.trace(jax.jit(apply_fn), shapes, xs)
     if as_loaded:
         cut = prepare.split(closed, 0)      # no leaf: nothing to the load
         held = leaves
