@@ -102,13 +102,45 @@ tokens and weights in per-step SMEM blocks, a third sort, 5.86 / 4.43;
 the last two and the scratch together 6.11 / 4.65: all exact against
 the loops, deleted. The slots' tokens and weights prefetched whole
 into SMEM take 268 KB each of its 1 MiB at the Kimi-Linear cell's
-67,712 slots. **What one call holds** (:func:`_most_tokens`): what it
-asks of VMEM (the sum, a step's float32 rows, an expert's matrices and a
-tile of rows twice over, 16 MiB spare) within a v5e's 128 MiB, and its
-prefetched scalars within its 1 MiB of SMEM, 64 KiB spare: 9,159 tokens
-at the Kimi-Linear cell's widths (VMEM binds: Mosaic refuses one sum of
-16,384, 151 MB), 10,752 at Trinity's, 14,976 at experts of 512 (SMEM
-binds: one call of 20,000 tokens asks 1.25 MiB of it). Past that the
+67,712 slots. **The weights pipeline and the half tile.** Pallas'
+own pipeline fetches a step's blocks only during the step before it, so
+every step that changed expert waited for its 12.6 MB of matrices
+(20.5 us at 93 % of the memory's peak against 18.3 us of products) and
+a short last tile gained nothing while it waited. So the matrices stay
+in HBM (``memory_space=ANY``) and the kernel fetches them itself into a
+ring of ``depth`` sets in VMEM scratch with a DMA semaphore each
+(:func:`_swiglu_kernel`): the order of the experts is the walk's
+(:func:`_ring`, an expert with no step never fetched), an expert's
+first step starts the copy of the expert ``depth - 1`` ahead into the
+set the last one left and waits for its own, and the first step of all
+starts the first ``depth - 1``. ``depth`` (:func:`_depth`) is as many
+sets as VMEM holds beside the sum, a step's float32 rows, a tile of rows
+twice over and 16 MiB, at least 2 and at most 3: at the Trinity cell's
+widths (the sum of 4096 rows of 2048 35.7 MB, a set 12.6 MB) six fit
+and three are taken; at the Kimi-Linear cell's (8192 rows of 2304, the
+sum 75.5 MB, a set 14.2 MB) two sets already ask 125.3 MB of the 128
+MiB, a third would cut the call into runs that read every expert's
+1.81 GB again, and the ring stays at 2. With the fetch ahead a step is
+bound by its products, and **a step whose own rows (``rows[i]``) are at
+most ``tile // 2`` multiplies only the first half of its tile**
+(:func:`_short`, :func:`half_tiles`: the rows it adds are the same,
+and so are their bits). Read on a TPU v5e (PERF.md §6), ms, the
+parent's kernel against rings of 2 / 3 / 4 / 6: the op alone at 4096
+tokens of 2048 on a skewed routing (195 tiles, 71 of them at half)
+4.15 against 3.87 / 3.61 / 3.62 / 4.35, the sum the parent's to the
+bit at every depth; the Trinity cell's whole program a sequence 38.45
+against 37.53 / **36.29** / 36.27 / 36.15: a fourth set no faster than
+the third (the rounds spread 0.03), six 0.4 % faster in the program
+and 20 % slower alone, so 3 is the most taken; the Kimi-Linear cell's
+widths at a ring of 2, the op alone 6.62 against 6.31, its program
+81.76 against 80.22. **What one call holds** (:func:`_most_tokens`): what it
+asks of VMEM (the sum, a step's float32 rows, two sets of an expert's
+matrices, a tile of rows twice over, 16 MiB spare) within a v5e's 128
+MiB, and its prefetched scalars within its 1 MiB of SMEM, 64 KiB spare:
+9,159 tokens at the Kimi-Linear cell's widths (VMEM binds: Mosaic
+refuses one sum of 16,384, 151 MB), 10,752 at Trinity's, 14,967 at
+experts of 512 (SMEM binds: one call of 20,000 tokens asks 1.25 MiB of
+it). Past that the
 tokens go in runs as even as they go, a call each, each run's pairs
 grouped anew from the sorted pairs' experts by one sort; each run reads
 every held expert's matrices again (1.81 GB a layer at Kimi-Linear's
@@ -150,11 +182,25 @@ def _by_key(key, held_count: int):
 def tiles_walked(counts, tile: int):
     """How many ``tile``-row tiles the aligned walk costs a routing:
     ``counts`` [..., G] pairs an expert (any integer array, numpy's or
-    jax's) -> the sum over the experts of ``ceil(count / tile)``. The
-    kernel multiplies that many tiles whole (:func:`_walk`'s live
-    steps); the rows served over ``tile`` times it is the share of its
-    multiplications that somebody reads."""
+    jax's) -> the sum over the experts of ``ceil(count / tile)``
+    (:func:`_walk`'s live steps)."""
     return (-(-counts // tile)).sum(-1)
+
+
+def _short(counts, tile: int):
+    """Whether an expert's last tile holds at most half its rows, so
+    that it is taken at ``tile // 2`` (the kernel's step, the loops'
+    second loop): ``counts`` [..., G] -> bool [..., G]."""
+    rest = counts % tile
+    return (rest > 0) & (rest <= tile // 2)
+
+
+def half_tiles(counts, tile: int):
+    """How many of the walked tiles (:func:`tiles_walked`) the kernel
+    multiplies at half their rows: ``counts`` [..., G] pairs an expert
+    (numpy's or jax's) -> the experts whose last tile holds at most
+    ``tile // 2`` rows."""
+    return _short(counts, tile).sum(-1)
 
 
 def _spread(counts, rows: int, align: int, slots: int):
@@ -204,14 +250,46 @@ def _walk(counts, tiles: int, tile: int, align: int):
                  for a in (expert, live.reshape(1), row, rows))
 
 
-def _swiglu_kernel(expert_ref, live_ref, row_ref, rows_ref, token_ref,
-                   weight_ref, x_ref, w1_ref, w3_ref, w2_ref, o_ref, y_ref):
+def _ring(counts, depth: int):
+    """The experts in the order the kernel's walk (:func:`_walk`) meets
+    them, each fetched once: ``counts`` [G] -> int32 ``[G + depth - 1]``,
+    every expert with a pair in turn, then -1 (no expert: nothing to
+    fetch), as far as the ring can look ahead of the last."""
+    g = counts.shape[0]
+    walked = jnp.argsort(counts == 0, stable=True).astype(jnp.int32)
+    return jnp.where(jnp.arange(g + depth - 1) < jnp.sum(counts > 0),
+                     jnp.pad(walked, (0, depth - 1)), -1)
+
+
+def _swiglu_kernel(expert_ref, live_ref, row_ref, rows_ref, ring_ref,
+                   token_ref, weight_ref, x_ref, w1_hbm, w3_hbm, w2_hbm,
+                   o_ref, w1_ref, w3_ref, w2_ref, sem, nth_ref, y_ref):
     """One grid step (:func:`_walk`): a tile's rows through its
     expert's three matrices, and each of the expert's own rows, weighted,
-    added into its token's row of the sum that stays in VMEM."""
+    added into its token's row of the sum that stays in VMEM. The
+    matrices come from a ring of ``depth`` sets in VMEM: the ``k``-th
+    expert walked (:func:`_ring`) lies in set ``k % depth``, and its
+    first step starts the fetch of the expert ``depth - 1`` ahead into
+    the set the last expert left, then waits for its own."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     i = pl.program_id(0)
+    depth, tile = w1_ref.shape[0], x_ref.shape[0]
+
+    def copies(k):
+        slot = k % depth
+        return [pltpu.make_async_copy(src.at[ring_ref[k]],
+                                      dst.at[slot], sem.at[m, slot])
+                for m, (src, dst) in enumerate(((w1_hbm, w1_ref),
+                                                (w3_hbm, w3_ref),
+                                                (w2_hbm, w2_ref)))]
+
+    def fetch(k):
+        @pl.when(ring_ref[k] >= 0)
+        def _():
+            for c in copies(k):
+                c.start()
 
     @pl.when(i == 0)
     def _():
@@ -224,14 +302,40 @@ def _swiglu_kernel(expert_ref, live_ref, row_ref, rows_ref, token_ref,
         jax.lax.fori_loop(0, t // 8, zero, 0)
         if t % 8:
             o_ref[t - t % 8:, :] = jnp.zeros((t % 8, d), o_ref.dtype)
+        nth_ref[0] = -1
+        for k in range(depth - 1):
+            fetch(k)
 
     @pl.when(i < live_ref[0])
     def _():
-        x = x_ref[...]
-        gate = jnp.dot(x, w1_ref[...], preferred_element_type=jnp.float32)
-        up = jnp.dot(x, w3_ref[...], preferred_element_type=jnp.float32)
-        y_ref[...] = jnp.dot((jax.nn.silu(gate) * up).astype(x.dtype),
-                             w2_ref[...], preferred_element_type=jnp.float32)
+        new = (i == 0) | (expert_ref[i] != expert_ref[jnp.maximum(i - 1, 0)])
+
+        @pl.when(new)
+        def _():
+            k = nth_ref[0] + 1
+            nth_ref[0] = k
+            fetch(k + depth - 1)
+            for c in copies(k):
+                c.wait()
+        slot = nth_ref[0] % depth
+        n = rows_ref[i]
+
+        def products(rows: int):
+            x = x_ref[pl.ds(0, rows), :]
+            gate = jnp.dot(x, w1_ref[slot], preferred_element_type=jnp.float32)
+            up = jnp.dot(x, w3_ref[slot], preferred_element_type=jnp.float32)
+            y_ref[pl.ds(0, rows), :] = jnp.dot(
+                (jax.nn.silu(gate) * up).astype(x.dtype), w2_ref[slot],
+                preferred_element_type=jnp.float32)
+
+        # an expert's last tile of at most half its rows (:func:`_short`)
+        # is multiplied at half: rows past ``n`` are never added
+        half = tile // 2
+        if half:
+            pl.when(n <= half)(lambda: products(half))
+            pl.when(n > half)(lambda: products(tile))
+        else:
+            products(tile)
         at = row_ref[i]
 
         # rows past ``rows`` (the free slots, the next expert's rows on
@@ -251,17 +355,17 @@ def _swiglu_kernel(expert_ref, live_ref, row_ref, rows_ref, token_ref,
         def one(r, carry):
             add(r)
             return carry
-        n = rows_ref[i]
         jax.lax.fori_loop(0, n // 8, eight, 0)
         jax.lax.fori_loop(n // 8 * 8, n, one, 0)
 
 
-def _aligned_swiglu(xs, token, weight, expert, live, row, rows, w1, w3, w2,
-                    *, tokens: int, tile: int, align: int):
+def _aligned_swiglu(xs, token, weight, expert, live, row, rows, ring, w1, w3,
+                    w2, *, tokens: int, tile: int, align: int):
     """``xs`` [slots, d], each expert's run from a multiple of ``align``
     rows on (:func:`_spread`); ``token``, ``weight`` [slots] the token
     and the weight of the pair in each slot; ``expert``, ``live``,
-    ``row``, ``rows`` from :func:`_walk` -> float32 ``[tokens, d]``: for
+    ``row``, ``rows`` from :func:`_walk`, ``ring`` from :func:`_ring`
+    at the depth :func:`_depth` gives -> float32 ``[tokens, d]``: for
     each token the sum over the slots of step ``i``'s first ``rows[i]``
     rows that hold it of ``weight * (silu(x w1[g]) * (x w3[g])) w2[g]``
     on the step's expert's matrices, bfloat16 operands as they come,
@@ -269,65 +373,82 @@ def _aligned_swiglu(xs, token, weight, expert, live, row, rows, w1, w3, w2,
     ``xs``' dtype, the sum in float32 in the order of the steps. One
     ``nns_grouped_swiglu`` call (module docstring). The sum is its output
     block, one for the whole grid, held in VMEM and written once; beside
-    it an expert's three matrices twice over (one set read while the
-    last is multiplied), so it asks for the VMEM that takes."""
+    it the ring of the experts' matrices, which the kernel fetches from
+    HBM itself, so it asks for the VMEM that takes."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     d, f = w1.shape[1:]
+    depth = ring.shape[0] - w1.shape[0] + 1
 
     def rows_in(i, expert, live, row, *_):
         # an element offset, not a block's: Mosaic takes a row of HBM that
         # it can prove a multiple of the sublane tile, and no other
         return pl.multiple_of(row[i], align), 0
 
-    def matrices(i, expert, *_):
-        return expert[i], 0, 0
-
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
         _swiglu_kernel,
         out_shape=jax.ShapeDtypeStruct((tokens, d), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=6, grid=expert.shape,
+            num_scalar_prefetch=7, grid=expert.shape,
             in_specs=[pl.BlockSpec((pl.Element(tile), pl.Element(d)),
-                                   rows_in),
-                      pl.BlockSpec((None, d, f), matrices),
-                      pl.BlockSpec((None, d, f), matrices),
-                      pl.BlockSpec((None, f, d), matrices)],
+                                   rows_in), hbm, hbm, hbm],
             out_specs=pl.BlockSpec((tokens, d), lambda i, *_: (0, 0),
                                    pipeline_mode=pl.Buffered(1)),
-            scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((depth, d, f), w1.dtype),
+                            pltpu.VMEM((depth, d, f), w3.dtype),
+                            pltpu.VMEM((depth, f, d), w2.dtype),
+                            pltpu.SemaphoreType.DMA((3, depth)),
+                            pltpu.SMEM((1,), jnp.int32),
+                            pltpu.VMEM((tile, d), jnp.float32)]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_vmem_asked(tokens, d, f, tile,
-                                         xs.dtype.itemsize)),
+                                         xs.dtype.itemsize, depth)),
         interpret=jax.default_backend() != "tpu",
         name="nns_grouped_swiglu",
-    )(expert, live, row, rows, token, weight, xs, w1, w3, w2)
+    )(expert, live, row, rows, ring, token, weight, xs, w1, w3, w2)
 
 
 # a v5e's per core (jax's ``pltpu.get_tpu_info``); what the kernel asks
 # of either must lie within it, or Mosaic refuses the program
 _VMEM, _SMEM = 128 << 20, 1 << 20
+# the most sets of matrices the ring holds: a fourth helped no more in
+# the program (module docstring)
+_DEEPEST = 3
 
 
-def _vmem_asked(tokens: int, d: int, f: int, tile: int, itemsize: int):
+def _vmem_asked(tokens: int, d: int, f: int, tile: int, itemsize: int,
+                depth: int = 2):
     """The VMEM ``nns_grouped_swiglu`` asks for: the float32 sum of
-    ``tokens`` rows and a step's float32 rows, an expert's three matrices
-    and a tile of rows twice over, and 16 MiB for Mosaic's own."""
-    return (tokens + tile) * d * 4 + 2 * (3 * d * f + tile * d) * itemsize \
-        + (16 << 20)
+    ``tokens`` rows and a step's float32 rows, ``depth`` sets of an
+    expert's three matrices, a tile of rows twice over, and 16 MiB for
+    Mosaic's own."""
+    return (tokens + tile) * d * 4 + (depth * 3 * d * f + 2 * tile * d) \
+        * itemsize + (16 << 20)
+
+
+def _depth(tokens: int, d: int, f: int, tile: int, itemsize: int) -> int:
+    """How many sets of matrices the kernel's ring holds: as many as VMEM
+    holds beside the rest of what it asks (:func:`_vmem_asked`), at least
+    two (:func:`_most_tokens` sees to their room), at most
+    ``_DEEPEST``."""
+    room = _VMEM - _vmem_asked(tokens, d, f, tile, itemsize, 0)
+    return max(2, min(_DEEPEST, room // (3 * d * f * itemsize)))
 
 
 def _most_tokens(d: int, f: int, k: int, experts: int, tile: int,
                  align: int, itemsize: int) -> int:
     """The most tokens one ``nns_grouped_swiglu`` call takes (module
-    docstring): the VMEM it asks for (:func:`_vmem_asked`) and its
-    prefetched scalars (a token and a weight a slot, an expert, a row
-    and a row count a step) within the chip's, 64 KiB of SMEM spare."""
+    docstring): the VMEM it asks for with a ring of two sets
+    (:func:`_vmem_asked`) and its prefetched scalars (a token and a
+    weight a slot, an expert, a row and a row count a step, the ring's
+    experts and its count) within the chip's, 64 KiB of SMEM spare."""
     vmem = (_VMEM - _vmem_asked(0, d, f, tile, itemsize)) // (4 * d)
     smem = (_SMEM - (64 << 10) - 8 * (experts * (align - 1) + tile)
-            - 12 * experts - 4) * tile // (k * (8 * tile + 12))
+            - 16 * experts - 4 * (_DEEPEST + 1)) * tile \
+        // (k * (8 * tile + 12))
     return min(vmem, smem)
 
 
@@ -399,13 +520,15 @@ def grouped_swiglu(x, order, counts, pair_weight, w1, w3, w2, *, tile: int,
             token = pair // k
             xs = x[token]
             walk = _walk(counts, tiles, tile, align)
-        return _aligned_swiglu(xs, token, weight, *walk, w1, w3, w2,
+            ring = _ring(counts, _depth(t, d, w1.shape[2], tile,
+                                        x.dtype.itemsize))
+        return _aligned_swiglu(xs, token, weight, *walk, ring, w1, w3, w2,
                                tokens=t, tile=tile, align=align)
     weight = pair_weight.reshape(-1)
     row0 = jnp.cumsum(counts) - counts           # an expert's first row
     half = tile // 2
     rest = counts % tile
-    short = (rest > 0) & (rest <= half)          # last tile at most half full
+    short = _short(counts, tile)
 
     def turns(acc, tiles, rows: int, first):
         """``acc`` plus ``tiles[g]`` tiles of ``rows`` rows of each expert

@@ -704,25 +704,32 @@ def test_mosaic_takes_the_chunked_recurrence_at_the_cells_widths(
     assert "nns_grouped_swiglu" in half
 
 
-@pytest.mark.parametrize("t,d,held,router,calls", [
-    (8192, 2304, 128, 256, 1), (4096, 2048, 128, 128, 1),
-    (9216, 2304, 128, 256, 2)],
-    ids=["kimi_linear_half", "trinity_whole", "kimi_linear_past_vmem"])
+@pytest.mark.parametrize("t,d,held,router,calls,depth", [
+    (8192, 2304, 128, 256, 1, 2), (4096, 2048, 128, 128, 1, 3),
+    (9216, 2304, 128, 256, 2, 3), (8192, 2048, 128, 128, 1, 3),
+    (4096, 2304, 128, 256, 1, 3)],
+    ids=["kimi_linear_half", "trinity_whole", "kimi_linear_past_vmem",
+         "trinity_s8192", "kimi_linear_s4096"])
 def test_mosaic_keeps_the_experts_sum_in_vmem(topology, monkeypatch, t, d,
-                                              held, router, calls):
+                                              held, router, calls, depth):
     """The grouped kernel's way out at the two cells' widths (experts of
     ``d`` x 1024, 8 a token, tiles of 256): Mosaic lays out the float32
-    ``[t, d]`` sum as the kernel's one output block beside an expert's
-    matrices twice over, within the VMEM the kernel asks for, and the
-    program holds no float32 row a pair (``[t x 8, d]``) and no other
-    kernel. Past 9,159 tokens at Kimi-Linear's widths, where what one
-    call would ask of VMEM (the sum, the matrices twice over, 16 MiB
-    spare) passes the chip's 128 MiB, two calls on runs of 4,608."""
+    ``[t, d]`` sum as the kernel's one output block beside the ring of
+    ``depth`` sets of an expert's matrices that the kernel fetches
+    itself (the deepest, 3, at the Trinity cell's 4096 tokens, at its
+    widths on 8192 and at Kimi-Linear's on 4096; 2 at the Kimi-Linear
+    cell's 8192), within the VMEM the kernel asks for,
+    and the program holds no float32 row a pair (``[t x 8, d]``) and no
+    other kernel. Past 9,159 tokens at Kimi-Linear's widths, where what
+    one call would ask of VMEM (the sum, two sets of matrices, a tile of
+    rows twice over, 16 MiB spare) passes the chip's 128 MiB, two calls
+    on runs of 4,608, each with a ring of 3."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     from nnstreamer_tpu.ops import grouped
     dev = SingleDeviceSharding(topologies.get_topology_desc(
         platform="tpu", topology_name=topology).devices[0])
+    assert grouped._depth(-(-t // calls), d, 1024, 256, 2) == depth
 
     def spec(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)

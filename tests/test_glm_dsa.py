@@ -423,17 +423,23 @@ def test_grouped_kernel_walks_an_expert_a_tile(counts):
     assert np.bincount(expert[:live], added[:live], g).tolist() == counts
 
 
-def _whole_router_case(rng, dtype, t=48, k=2, router=6, d=16, f=24):
+def _whole_router_case(rng, dtype, t=48, k=2, router=6, d=16, f=24,
+                       counts=None):
     """Operands on which every order of accumulation gives the same
     bits: ``x``, ``w1``, ``w3`` small integers (gate and up are exact),
     each column of an expert's ``w2`` one signed power of two (the last
     product's sums have one term), the pairs' weights powers of two (a
     weighted row is exact, fused into its add or not), so two
     statements of one product differ only where they serve a row
-    otherwise."""
+    otherwise. ``counts``: the pairs of each expert instead, a token
+    choosing one (``t = sum(counts)``, ``k = 1``)."""
+    if counts is not None:
+        router, t, k = len(counts), sum(counts), 1
     x = rng.integers(-2, 3, (t, d)).astype(np.float32)
     choice = np.stack([rng.permutation(router)[:k] for _ in range(t)]
-                      ).astype(np.int32)
+                      ).astype(np.int32) if counts is None else \
+        rng.permutation(np.repeat(np.arange(router), counts)
+                        )[:, None].astype(np.int32)
     weight = rng.choice([.25, .5, 1., 2.], (t, k)).astype(np.float32)
     w1, w3 = (rng.integers(-2, 3, (router, d, f)).astype(np.float32)
               for _ in range(2))
@@ -474,24 +480,115 @@ def test_grouped_kernel_is_three_ragged_dots_to_the_bit(dtype):
     np.testing.assert_array_equal(np.asarray(got), want)
 
 
+# routings of one pair a token over tiles of 8 whose experts' last tiles
+# hold exactly half the rows, one row more, one row, and experts whose
+# every row lies in one half tile; with the last tiles the kernel takes
+# at half (``half_tiles``)
+_HALF_TILE_ROUTINGS = {
+    "last_at_half": ([12, 20, 0, 4, 28], 4),
+    "half_and_one": ([13, 5, 0, 21, 8], 0),
+    "one_row": ([9, 1, 17, 0, 25], 4),
+    "in_one_half": ([0, 3, 0, 2, 4, 0, 16], 3),
+}
+
+
 def test_grouped_kernel_reads_no_row_of_a_tiles_tail():
-    """A step multiplies a whole tile: past its expert's rows the
-    slots left free by the alignment (they hold pair 0's row, a real
-    one) and the next experts' rows, on the wrong matrices, stored
-    where nobody reads. With pair 0's token poisoned (NaN), every other
-    token's result is what it was, bit for bit, though the tiles hold
-    the NaN row many times over."""
-    from nnstreamer_tpu.ops.grouped import tiles_walked
-    x, order, counts, *rest = _whole_router_case(np.random.default_rng(9),
-                                                 jnp.float32)
-    t, k, tile = x.shape[0], order.shape[0] // x.shape[0], 8
-    # more than a tile's worth of tail rows in the live tiles
-    assert int(tiles_walked(counts, tile)) * tile - t * k >= 8
-    run = jax.jit(lambda *a: grouped_swiglu(*a, tile=tile, router=6))
-    clean = np.asarray(run(x, order, counts, *rest))
-    dirty = np.asarray(run(x.at[0].set(jnp.nan), order, counts, *rest))
-    assert np.isnan(dirty[0]).all() and np.isfinite(clean).all()
-    np.testing.assert_array_equal(dirty[1:], clean[1:])
+    """A step multiplies a whole tile, or its first half where its
+    expert's last rows fit there: past its expert's rows the slots left
+    free by the alignment (they hold pair 0's row, a real one) and the
+    next experts' rows, on the wrong matrices, stored where nobody
+    reads. With pair 0's token poisoned (NaN), every other token's
+    result is what it was, bit for bit, though the tiles hold the NaN
+    row many times over; on the routings whose last tiles fall at half
+    a tile, one row over, one row, and inside one half tile, the kernel
+    gives the tile loops' result bit for bit."""
+    from nnstreamer_tpu.ops.grouped import half_tiles, tiles_walked
+    tile = 8
+    cases = [(None, None)] + list(_HALF_TILE_ROUTINGS.values())
+    for i, (counts_want, halves) in enumerate(cases):
+        x, order, counts, *rest = _whole_router_case(
+            np.random.default_rng(9 + i), jnp.float32, counts=counts_want)
+        t, k = x.shape[0], order.shape[0] // x.shape[0]
+        router = counts.shape[0]
+        if counts_want is None:
+            # more than a tile's worth of tail rows in the live tiles
+            assert int(tiles_walked(counts, tile)) * tile - t * k >= 8
+        else:
+            assert counts.tolist() == counts_want
+            assert int(half_tiles(counts, tile)) == halves
+        run = jax.jit(lambda *a: grouped_swiglu(*a, tile=tile, router=router))
+        clean = np.asarray(run(x, order, counts, *rest))
+        dirty = np.asarray(run(x.at[0].set(jnp.nan), order, counts, *rest))
+        assert np.isnan(dirty[0]).all() and np.isfinite(clean).all()
+        np.testing.assert_array_equal(dirty[1:], clean[1:])
+        loops = jax.jit(lambda *a: grouped_swiglu(*a, tile=tile))(
+            x, order, counts, *rest)
+        np.testing.assert_array_equal(clean, np.asarray(loops))
+
+
+@pytest.mark.parametrize("counts", [
+    [0, 5, 0, 0, 9, 1, 0, 17, 0],       # gaps first, inside and last
+    [3, 0, 0, 0, 0, 0, 0, 2],           # two experts, six between them
+    [0, 0, 0, 40, 0],                   # one expert
+    [8, 8, 8, 8, 8, 8, 8, 8],           # more experts than any ring
+], ids=["gaps", "far_apart", "one_expert", "no_gap"])
+@pytest.mark.parametrize("depth", [2, 3, 4])
+def test_grouped_kernel_fetches_the_experts_in_the_walks_order(
+        monkeypatch, counts, depth):
+    """The ring of matrices (``_ring``) lists the experts in the order
+    ``_walk``'s steps meet them, each once and none that serves nobody,
+    and the kernel run as the chip runs it (Pallas' TPU interpreter: a
+    copy lands only when it is waited for, memory nobody wrote reads
+    NaN, races are looked for) gives the tile loops' result bit for bit
+    at every depth of the ring."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental.pallas import tpu as pltpu
+    from nnstreamer_tpu.ops import grouped
+    tile = 8
+    rows = sum(counts)
+    expert, live, _, _ = (np.asarray(a) for a in grouped._walk(
+        jnp.asarray(counts, jnp.int32), -(-rows // tile) + len(counts) - 1,
+        tile, 2))
+    walked = expert[:int(live[0])]
+    met = walked[np.r_[True, walked[1:] != walked[:-1]]].tolist()
+    ring = np.asarray(grouped._ring(jnp.asarray(counts, jnp.int32), depth))
+    assert ring.shape == (len(counts) + depth - 1,)
+    assert ring.tolist() == met + [-1] * (len(ring) - len(met))
+    assert met == [e for e, c in enumerate(counts) if c]
+    monkeypatch.setattr(grouped, "_DEEPEST", depth)
+    args = _whole_router_case(np.random.default_rng(depth), jnp.float32,
+                              counts=counts)
+    assert grouped._depth(rows, 16, 24, tile, 4) == depth
+    with pltpu.force_tpu_interpret_mode(pltpu.InterpretParams(
+            detect_races=True, dma_execution_mode="on_wait")):
+        got = jax.jit(lambda *a: grouped.grouped_swiglu(
+            *a, tile=tile, router=len(counts)))(*args)
+        got = np.asarray(got)
+    assert not interpret_pallas_call.races.races_found
+    loops = jax.jit(lambda *a: grouped.grouped_swiglu(*a, tile=tile))(*args)
+    np.testing.assert_array_equal(got, np.asarray(loops))
+
+
+@pytest.mark.parametrize("t,d,depth,most", [
+    (8192, 2304, 2, 8192), (4096, 2048, 3, 4096), (2048, 2304, 3, 4096),
+    (9159, 2304, 2, 9159)],
+    ids=["kimi_linear", "trinity", "kimi_linear_s2048", "kimi_linear_most"])
+def test_the_ring_is_as_deep_as_vmem_holds(t, d, depth, most):
+    """At the cells' widths (experts of ``d`` x 1024, 8 a token, 128 held,
+    tiles of 256, bfloat16): the ring holds as many sets of matrices as
+    VMEM holds beside the sum, a step's float32 rows and a tile of rows
+    twice over, at least two and at most ``_DEEPEST`` (2 at Kimi-Linear's
+    8192 tokens of 2304, where a third set would split the call; 3 at
+    Trinity's 4096 of 2048, where six would fit, and at a quarter of
+    Kimi-Linear's tokens), within the chip's VMEM at each; and one call
+    still takes the cells' tokens."""
+    from nnstreamer_tpu.ops import grouped
+    got = grouped._depth(t, d, 1024, 256, 2)
+    assert got == depth and 2 <= got <= grouped._DEEPEST
+    assert grouped._vmem_asked(t, d, 1024, 256, 2, got) <= grouped._VMEM
+    assert grouped._vmem_asked(t, d, 1024, 256, 2, got + 1) \
+        > grouped._VMEM or got == grouped._DEEPEST
+    assert grouped._most_tokens(d, 1024, 8, 128, 256, 16, 2) >= most
 
 
 def _choice(case, rng, t, k, tile, held_first, held, router):
@@ -508,6 +605,15 @@ def _choice(case, rng, t, k, tile, held_first, held, router):
         # and a single row: both sides of where the two loops part
         half = tile // 2
         pairs = [2 * tile + half, tile + half + 1, tile, 1]
+        ids = np.repeat(inside, pairs)
+        ids = np.concatenate([ids, np.full(t - len(ids), outside[0])])
+        return rng.permutation(ids)[:, None]
+    if case == "half_tiles":
+        # k == 1; experts whose every row lies in one half tile (half of
+        # it and one row less), one a row over a tile, one whose last
+        # tile is half full
+        half = tile // 2
+        pairs = [half, tile + 1, 2 * tile + half, half - 1]
         ids = np.repeat(inside, pairs)
         ids = np.concatenate([ids, np.full(t - len(ids), outside[0])])
         return rng.permutation(ids)[:, None]
@@ -535,16 +641,27 @@ def _choice(case, rng, t, k, tile, held_first, held, router):
     # silu(gate) * up reaches the last product in bfloat16
     ("bfloat16", 64, 2, 16, jnp.bfloat16, 2e-2),
     ("bfloat16_half_full", 96, 1, 16, jnp.bfloat16, 2e-2),
+    # the kernel (half the router held), its steps at half a tile
+    ("kernel_half_full", 64, 1, 8, jnp.float32, 1e-5),
+    ("kernel_half_tiles", 64, 1, 8, jnp.float32, 1e-5),
+    ("kernel_bfloat16_half_full", 96, 1, 16, jnp.bfloat16, 2e-2),
+    ("kernel_bfloat16_half_tiles", 96, 1, 16, jnp.bfloat16, 2e-2),
 ])
 def test_grouped_swiglu_serves_every_routing(case, t, k, tile, dtype, tol):
     """Both loops of :func:`grouped_swiglu` (whole tiles, and last tiles
     at half the rows) against each pair computed alone: an expert of
     several tiles, one that serves nobody between two that do, every
     pair on one expert, no pair of a held expert (exactly 0), last
-    tiles on both sides of half full, an odd tile, bfloat16."""
-    held_first, held, router, d, f = 2, 4, 9, 16, 24
+    tiles on both sides of half full, an odd tile, bfloat16. The kernel
+    (``kernel_``: half a router of 8 held) on last tiles at half full,
+    a row over, one row, and experts whose every row lies in one half
+    tile: against each pair alone, and the tile loops' result bit for
+    bit (a token's one pair, its products on a tile or a half tile in
+    both)."""
+    kernel = case.startswith("kernel_")
+    held_first, held, router, d, f = 2, 4, 8 if kernel else 9, 16, 24
     rng = np.random.default_rng(len(case))
-    case = case.removeprefix("bfloat16_")
+    case = case.removeprefix("kernel_").removeprefix("bfloat16_")
     choice = _choice(case, rng, t, k, tile, held_first, held, router)
     x = jnp.asarray(rng.standard_normal((t, d)), dtype)
     weight = jnp.asarray(rng.random((t, k)), jnp.float32)
@@ -558,10 +675,18 @@ def test_grouped_swiglu_serves_every_routing(case, t, k, tile, dtype, tol):
                 "one_expert": counts[1] == t * k,
                 "none_held": counts.sum() == 0,
                 "half_full": (counts % tile == jnp.asarray(
-                    [tile // 2, tile // 2 + 1, 0, 1])).all()}.get(case, True)
+                    [tile // 2, tile // 2 + 1, 0, 1])).all(),
+                "half_tiles": (counts == jnp.asarray(
+                    [tile // 2, tile + 1, 2 * tile + tile // 2,
+                     tile // 2 - 1])).all()}.get(case, True)
     assert bool(expected), counts
-    got = jax.jit(lambda *a: grouped_swiglu(*a, tile=tile))(
+    got = jax.jit(lambda *a: grouped_swiglu(
+        *a, tile=tile, router=router if kernel else 0))(
         x, order, counts, weight, w1, w3, w2)
+    if kernel:
+        loops = jax.jit(lambda *a: grouped_swiglu(*a, tile=tile))(
+            x, order, counts, weight, w1, w3, w2)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(loops))
     assert got.shape == (t, d) and got.dtype == jnp.float32
     want = np.zeros((t, d), np.float32)
     for tok, slot in np.argwhere((choice >= held_first)
