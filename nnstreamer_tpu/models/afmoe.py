@@ -32,8 +32,7 @@ For one sequence ``x`` [S, d], layer ``l`` of kind ``layer_types[l]``:
   for equation); ``Shared`` is a SwiGLU ``moe_intermediate_size x
   num_shared_experts`` wide. The layer is given ``(num_experts,
   held_first, held_count)``, routes over the whole router and computes
-  its own experts' part (``ops/grouped.py``); what the experts of other
-  chips would add is not stood in for.
+  its own experts' part (``latent.routed_experts``).
 * **Stack.** ``h0 = E[tokens] * sqrt(d)`` (``mup_enabled``), the layers,
   ``RMSNorm``, an untied head; ``rms_norm_eps``.
 
@@ -46,23 +45,24 @@ Zoo entry ``zoo://afmoe?...``: int32 token frame ``[S]`` -> three
 tensors, ``last_logits`` float32 ``[V]``, ``logprobs`` float32 ``[S]``
 (log-softmax of token t+1 at position t; 0 at S-1) and ``expert_load``
 int32 ``[expert layers, held]`` (token-expert pairs each held expert
-served).
+served). Options: any numeric field of :class:`AfmoeConfig` by its name
+(``global_attn_every_n_layers`` sets the layers' kinds), ``seq``,
+``seed``; the defaults are a tiny model whose window is live at ``seq``
+64.
 """
 from __future__ import annotations
 
 import dataclasses
-import itertools
+import functools
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ..ops.grouped import group_by_expert, grouped_swiglu
 from . import latent
-from .latent import (BLOCK_Q, EXPERT_TILE, _mm_heads, _scaled,
-                     causal_attention_out, sigmoid_route, swiglu)
+from .latent import (BLOCK_Q, _mm_heads, _scaled, causal_attention_out,
+                     routed_experts, sigmoid_route, swiglu)
 from .transformer import rmsnorm, rope
-from .zoo import register_model
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -142,54 +142,37 @@ class AfmoeConfig:
                              "the chosen weights is built")
 
 
-def init_params(cfg: AfmoeConfig, key: jax.Array) -> Dict[str, Any]:
-    """Seeded tree in ``cfg.dtype``; the router's bias small and not
-    zero, so that it changes choices."""
-    return jax.jit(_init_params, static_argnums=0)(cfg, key)
-
-
-def _init_params(cfg: AfmoeConfig, key):
-    dt, d, hd = cfg.dtype, cfg.hidden_size, cfg.head_dim
+def _init_params(cfg: AfmoeConfig, key: jax.Array) -> Dict[str, Any]:
+    """Seeded tree in ``cfg.dtype``."""
+    d, hd = cfg.hidden_size, cfg.head_dim
     h, hkv = cfg.num_attention_heads, cfg.num_key_value_heads
-    counter = itertools.count()
-
-    def dense(*shape, fan_in=None):
-        k = jax.random.fold_in(key, next(counter))
-        scale = (fan_in or shape[-2]) ** -0.5
-        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dt)
-
-    def ones(n):
-        return jnp.ones((n,), dt)
-
-    def ffn(width, *lead):
-        return {"w1": dense(*lead, d, width), "w3": dense(*lead, d, width),
-                "w2": dense(*lead, width, d)}
-
+    w = latent.Seeded(key, cfg.dtype, d)
     layers = []
     for i in range(cfg.num_hidden_layers):
         layer = {
-            "attn_norm": ones(d),
+            "attn_norm": w.ones(d),
             "attn": {
-                "wq": dense(d, h * hd), "wk": dense(d, hkv * hd),
-                "wv": dense(d, hkv * hd), "wg": dense(d, h * hd),
-                "q_norm": ones(hd), "k_norm": ones(hd),
-                "wo": dense(h * hd, d)},
-            "post_attn_norm": ones(d),
-            "ffn_norm": ones(d),
-            "post_ffn_norm": ones(d)}
+                "wq": w.dense(d, h * hd), "wk": w.dense(d, hkv * hd),
+                "wv": w.dense(d, hkv * hd), "wg": w.dense(d, h * hd),
+                "q_norm": w.ones(hd), "k_norm": w.ones(hd),
+                "wo": w.dense(h * hd, d)},
+            "post_attn_norm": w.ones(d),
+            "ffn_norm": w.ones(d),
+            "post_ffn_norm": w.ones(d)}
         if i < cfg.num_dense_layers:
-            layer["mlp"] = ffn(cfg.intermediate_size)
+            layer["mlp"] = w.ffn(cfg.intermediate_size)
         else:
-            layer["moe"] = {
-                "gate": dense(d, cfg.num_experts),
-                "bias": 0.02 * dense(cfg.num_experts, fan_in=1),
-                "shared": ffn(cfg.num_shared_experts
-                              * cfg.moe_intermediate_size),
-                "experts": ffn(cfg.moe_intermediate_size, cfg.held)}
+            layer["moe"] = w.moe(
+                cfg.num_experts,
+                shared=cfg.num_shared_experts * cfg.moe_intermediate_size,
+                width=cfg.moe_intermediate_size, held=cfg.held)
         layers.append(layer)
-    return {"embed": dense(cfg.vocab_size, d, fan_in=d),
-            "head": dense(d, cfg.vocab_size), "norm_f": ones(d),
+    return {"embed": w.dense(cfg.vocab_size, d, fan_in=d),
+            "head": w.dense(d, cfg.vocab_size), "norm_f": w.ones(d),
             "layers": layers}
+
+
+init_params = latent.jitted_init(_init_params)
 
 
 def attend(h, layer, kind: str, cfg: AfmoeConfig):
@@ -219,33 +202,17 @@ def attend(h, layer, kind: str, cfg: AfmoeConfig):
         return h + rmsnorm(out, layer["post_attn_norm"], eps)
 
 
-def moe(x, m, cfg: AfmoeConfig):
-    """The expert layer over normed tokens ``x`` [T, d] -> ``(out
-    float32 [T, d], load int32 [held])``: the shared expert plus this
-    chip's routed experts' part, and the pairs each held expert
-    served."""
-    with jax.named_scope("block/moe/route"):
-        choice, weight = sigmoid_route(x, m, cfg.num_experts_per_tok,
-                                       cfg.route_scale)
-        order, load = group_by_expert(choice, cfg.held_first, cfg.held)
-    with jax.named_scope("block/moe/shared"):
-        out = swiglu(x, m["shared"])
-    with jax.named_scope("block/moe/experts"):
-        e = m["experts"]
-        routed = grouped_swiglu(x, order, load, weight, e["w1"], e["w3"],
-                                e["w2"], tile=EXPERT_TILE,
-                                router=cfg.num_experts)
-        return out + routed, load
-
-
 def ffn(a, layer, cfg: AfmoeConfig):
     """The second half of a layer over tokens ``a`` [T, d] -> ``(y,
     load)``; ``load`` is None for a dense layer."""
     eps = cfg.rms_norm_eps
     if "moe" in layer:
+        m = layer["moe"]
         with jax.named_scope("block/moe/route"):
             x = rmsnorm(a, layer["ffn_norm"], eps)
-        out, load = moe(x, layer["moe"], cfg)
+            choice, weight = sigmoid_route(x, m, cfg.num_experts_per_tok,
+                                           cfg.route_scale)
+        out, load = routed_experts(x, m, choice, weight, cfg.held_first)
         with jax.named_scope("block/moe/experts"):
             return a + rmsnorm(out.astype(a.dtype), layer["post_ffn_norm"],
                                eps), load
@@ -259,38 +226,16 @@ def forward(params, tokens, cfg: AfmoeConfig):
     """``tokens`` int32 [B, S] -> ``(last_logits float32 [B, V],
     logprobs float32 [B, S], expert_load int32 [expert layers, held])``;
     the load is summed over the batch."""
-    b, s = tokens.shape
     with jax.named_scope("embed"):
         h = jnp.take(params["embed"], tokens, axis=0)
         if cfg.mup_enabled:
             h = _scaled(h, cfg.hidden_size ** 0.5)
-    loads = []
-    for layer, kind in zip(params["layers"], cfg.kinds):
-        h = jnp.stack([attend(h[j], layer, kind, cfg) for j in range(b)])
-        flat, load = ffn(h.reshape(b * s, -1), layer, cfg)
-        if load is not None:
-            loads.append(load)
-        h = flat.reshape(b, s, -1)
-    return latent.score(h, params, tokens, cfg.rms_norm_eps) \
-        + (jnp.stack(loads),)
+    return latent.decode(
+        params, h, tokens, cfg.rms_norm_eps,
+        [functools.partial(attend, kind=kind, cfg=cfg) for kind in cfg.kinds],
+        functools.partial(ffn, cfg=cfg))
 
 
-def frame_model(cfg: AfmoeConfig, seq: int):
-    """``(apply_fn, in_info, out_info)`` for ``tensor_filter
-    framework=jax``: one int32 ``[seq]`` token frame a buffer in, the
-    three tensors of :func:`forward` out."""
-    return latent.frame_model(forward, cfg, seq,
-                              f"{cfg.held}:{cfg.n_moe_layers}")
-
-
-@register_model("afmoe")
-def _build_afmoe(seq: str = "64", seed: str = "0", dtype: str = "bfloat16",
-                 **sizes: str):
-    """``zoo://afmoe?seq=64&sliding_window=16&held_count=4&...``: any
-    numeric field of :class:`AfmoeConfig` by its name
-    (``global_attn_every_n_layers`` sets the layers' kinds); the defaults
-    are a tiny model whose window is live at ``seq`` 64."""
-    cfg = latent.config_from_options(AfmoeConfig, "afmoe", dtype, sizes)
-    params = init_params(cfg, jax.random.PRNGKey(int(seed)))
-    apply_fn, in_info, out_info = frame_model(cfg, int(seq))
-    return apply_fn, params, in_info, out_info
+frame_model = latent.frame_model(
+    forward, lambda cfg: f"{cfg.held}:{cfg.n_moe_layers}")
+latent.register("afmoe", AfmoeConfig, init_params, frame_model)

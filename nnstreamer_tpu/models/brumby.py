@@ -46,12 +46,13 @@ its document); two out, ``last_logits`` float32 ``[V]`` and ``logprobs``
 float32 ``[S]`` (log-softmax of token t+1 at position t; 0 at S-1). The
 builder hands the jax filter a fifth item, the state before a document,
 which the filter keeps on the device between buffers
-(``filters/jax_backend.py``).
+(``filters/jax_backend.py``). Options: any numeric field of
+:class:`BrumbyConfig` by its name, ``seq``, ``seed``; the defaults are a
+tiny model whose buffers of 64 tokens are four chunks.
 """
 from __future__ import annotations
 
 import dataclasses
-import itertools
 from typing import Any, Dict
 
 import jax
@@ -63,7 +64,6 @@ from ..tensors.info import TensorsInfo
 from . import latent
 from .latent import qkv_heads, swiglu
 from .transformer import rmsnorm, rope
-from .zoo import register_model
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,45 +100,34 @@ class BrumbyConfig:
                              "built (ops/power_retention.py)")
 
 
-def init_params(cfg: BrumbyConfig, key: jax.Array) -> Dict[str, Any]:
+def _init_params(cfg: BrumbyConfig, key: jax.Array) -> Dict[str, Any]:
     """Seeded tree in ``cfg.dtype``; the gates' offsets spread the
     heads' memories from a few tokens to thousands."""
-    return jax.jit(_init_params, static_argnums=0)(cfg, key)
-
-
-def _init_params(cfg: BrumbyConfig, key):
-    dt, d, hd = cfg.dtype, cfg.hidden_size, cfg.head_dim
+    d, hd = cfg.hidden_size, cfg.head_dim
     h, hkv = cfg.num_attention_heads, cfg.num_key_value_heads
-    counter = itertools.count()
-
-    def dense(*shape, scale=1.0):
-        k = jax.random.fold_in(key, next(counter))
-        return (jax.random.normal(k, shape, jnp.float32)
-                * scale * shape[-2] ** -0.5).astype(dt)
-
+    w = latent.Seeded(key, cfg.dtype, d)
     # b = logit(exp(-1 / tau)), tau from 4 to 4096 tokens over the heads
     tau = jnp.exp(jnp.linspace(jnp.log(4.0), jnp.log(4096.0), hkv))
     keep = jnp.exp(-1.0 / tau)
     layers = [{
-        "attn_norm": jnp.ones((d,), dt),
+        "attn_norm": w.ones(d),
         "attn": {
-            "wq": dense(d, h * hd), "wk": dense(d, hkv * hd),
-            "wv": dense(d, hkv * hd), "wg": dense(d, hkv, scale=0.1),
+            "wq": w.dense(d, h * hd), "wk": w.dense(d, hkv * hd),
+            "wv": w.dense(d, hkv * hd), "wg": w.dense(d, hkv, gain=0.1),
             "bg": jnp.log(keep / (1.0 - keep)).astype(jnp.float32),
-            "q_norm": jnp.ones((hd,), dt), "k_norm": jnp.ones((hd,), dt),
-            "wo": dense(h * hd, d)},
-        "ffn_norm": jnp.ones((d,), dt),
-        "mlp": {"w1": dense(d, cfg.intermediate_size),
-                "w3": dense(d, cfg.intermediate_size),
-                "w2": dense(cfg.intermediate_size, d)}}
+            "q_norm": w.ones(hd), "k_norm": w.ones(hd),
+            "wo": w.dense(h * hd, d)},
+        "ffn_norm": w.ones(d),
+        "mlp": w.ffn(cfg.intermediate_size)}
         for _ in range(cfg.num_hidden_layers)]
     # the embedding's rows at unit variance: a token's identity leads its
     # stream (PERF.md, PR 38)
-    embed = jax.random.normal(jax.random.fold_in(key, next(counter)),
-                              (cfg.vocab_size, d), jnp.float32).astype(dt)
-    return {"embed": embed,
-            "head": dense(d, cfg.vocab_size), "norm_f": jnp.ones((d,), dt),
+    return {"embed": w.dense(cfg.vocab_size, d, fan_in=1),
+            "head": w.dense(d, cfg.vocab_size), "norm_f": w.ones(d),
             "layers": layers}
+
+
+init_params = latent.jitted_init(_init_params)
 
 
 def state_shapes(cfg: BrumbyConfig):
@@ -220,13 +209,4 @@ def frame_model(cfg: BrumbyConfig, seq: int):
     return apply_fn, in_info, out_info, zero_state(cfg)
 
 
-@register_model("brumby")
-def _build_brumby(seq: str = "64", seed: str = "0", dtype: str = "bfloat16",
-                  **sizes: str):
-    """``zoo://brumby?seq=64&num_hidden_layers=2&...``: any numeric
-    field of :class:`BrumbyConfig` by its name; the defaults are a tiny
-    model whose buffers of 64 tokens are four chunks."""
-    cfg = latent.config_from_options(BrumbyConfig, "brumby", dtype, sizes)
-    params = init_params(cfg, jax.random.PRNGKey(int(seed)))
-    apply_fn, in_info, out_info, state = frame_model(cfg, int(seq))
-    return apply_fn, params, in_info, out_info, state
+latent.register("brumby", BrumbyConfig, init_params, frame_model)

@@ -19,14 +19,13 @@ absorbed form belongs to a decode path and is not built here):
   all of them while ``t < index_topk``, ties to the lower index
   (``ops/sparse_attention.py``).
 * **Router** (``latent.sigmoid_route``: ``noaux_tc``, one group). ``s
-  = sigmoid(x W_g)`` float32
-  over the whole router; the ``top`` largest of ``s + b`` are chosen;
-  weights ``s_e / (sum_chosen s + 1e-20) x routed_scaling_factor``, the
-  sum over every chosen expert, held or not. The layer's output is ``x
+  = sigmoid(x W_g)`` float32 over the whole router; the ``top`` largest
+  of ``s + b`` are chosen; weights ``s_e / (sum_chosen s + 1e-20) x
+  routed_scaling_factor``, the sum over every chosen expert, held or
+  not. The layer's output is ``x
   + shared(x) + sum_{e chosen and held} w_e expert_e(x)``: it is given
   ``(n_routed_experts, held_first, held_count)``, routes over all and
-  computes its own experts' part (``ops/grouped.py``); what the experts
-  of other chips would add is not stood in for.
+  computes its own experts' part (``latent.routed_experts``).
 
 The first ``first_k_dense_replace`` layers have a dense SwiGLU MLP in the
 expert layer's place. The latent projections, the rotations, the SwiGLU
@@ -40,24 +39,24 @@ tensors, ``last_logits`` float32 ``[V]``, ``logprobs`` float32 ``[S]``
 (log-softmax of token t+1 at position t; 0 at S-1) and ``expert_load``
 int32 ``[moe layers, held]`` (token-expert pairs each held expert
 served). The multi-token-prediction layer does nothing on a scoring
-pass and is not loaded.
+pass and is not loaded. Options: any field of :class:`GLMDSAConfig` by
+its name, ``seq``, ``seed``; the defaults are a tiny model whose sparse
+regime is live at ``seq`` 64.
 """
 from __future__ import annotations
 
 import dataclasses
-import itertools
+import functools
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 
-from ..ops.grouped import group_by_expert, grouped_swiglu
 from ..ops.sparse_attention import topk_mask
 from . import latent
-from .latent import (BLOCK_Q, EXPERT_TILE, _mm, causal_attention_out,
-                     mla_qkv, rope_interleaved, sigmoid_route, swiglu)
+from .latent import (BLOCK_Q, _mm, causal_attention_out, mla_qkv,
+                     rope_interleaved)
 from .transformer import rmsnorm
-from .zoo import register_model
 
 LAYER_NORM_EPS = 1e-6     # the indexer's key norm
 
@@ -120,62 +119,45 @@ class GLMDSAConfig:
             raise ValueError("glm_dsa needs at least one expert layer")
 
 
-def init_params(cfg: GLMDSAConfig, key: jax.Array) -> Dict[str, Any]:
-    """Seeded tree in ``cfg.dtype``; the router's bias small and not
-    zero, so that it changes choices."""
-    return jax.jit(_init_params, static_argnums=0)(cfg, key)
-
-
-def _init_params(cfg: GLMDSAConfig, key):
-    dt, d, h = cfg.dtype, cfg.hidden_size, cfg.num_attention_heads
+def _init_params(cfg: GLMDSAConfig, key: jax.Array) -> Dict[str, Any]:
+    """Seeded tree in ``cfg.dtype``."""
+    d, h = cfg.hidden_size, cfg.num_attention_heads
     nope, rp, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    counter = itertools.count()
-
-    def dense(*shape, fan_in=None):
-        k = jax.random.fold_in(key, next(counter))
-        scale = (fan_in or shape[-2]) ** -0.5
-        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dt)
-
-    def ones(n):
-        return jnp.ones((n,), dt)
-
-    def ffn(width, *lead):
-        return {"w1": dense(*lead, d, width), "w3": dense(*lead, d, width),
-                "w2": dense(*lead, width, d)}
-
+    w = latent.Seeded(key, cfg.dtype, d)
     layers = []
     for i in range(cfg.num_hidden_layers):
         layer = {
-            "attn_norm": ones(d),
+            "attn_norm": w.ones(d),
             "attn": {
-                "wq_a": dense(d, cfg.q_lora_rank),
-                "q_norm": ones(cfg.q_lora_rank),
-                "wq_b": dense(cfg.q_lora_rank, h * (nope + rp)),
-                "wkv_a": dense(d, cfg.kv_lora_rank + rp),
-                "kv_norm": ones(cfg.kv_lora_rank),
-                "wkv_b": dense(cfg.kv_lora_rank, h * (nope + vd)),
-                "wo": dense(h * vd, d)},
+                "wq_a": w.dense(d, cfg.q_lora_rank),
+                "q_norm": w.ones(cfg.q_lora_rank),
+                "wq_b": w.dense(cfg.q_lora_rank, h * (nope + rp)),
+                "wkv_a": w.dense(d, cfg.kv_lora_rank + rp),
+                "kv_norm": w.ones(cfg.kv_lora_rank),
+                "wkv_b": w.dense(cfg.kv_lora_rank, h * (nope + vd)),
+                "wo": w.dense(h * vd, d)},
             "indexer": {
-                "wq_b": dense(cfg.q_lora_rank,
-                              cfg.index_n_heads * cfg.index_head_dim),
-                "wk": dense(d, cfg.index_head_dim),
-                "k_norm_w": ones(cfg.index_head_dim),
-                "k_norm_b": jnp.zeros((cfg.index_head_dim,), dt),
-                "w_proj": dense(d, cfg.index_n_heads)},
-            "ffn_norm": ones(d)}
+                "wq_b": w.dense(cfg.q_lora_rank,
+                                cfg.index_n_heads * cfg.index_head_dim),
+                "wk": w.dense(d, cfg.index_head_dim),
+                "k_norm_w": w.ones(cfg.index_head_dim),
+                "k_norm_b": jnp.zeros((cfg.index_head_dim,), cfg.dtype),
+                "w_proj": w.dense(d, cfg.index_n_heads)},
+            "ffn_norm": w.ones(d)}
         if i < cfg.first_k_dense_replace:
-            layer["mlp"] = ffn(cfg.intermediate_size)
+            layer["mlp"] = w.ffn(cfg.intermediate_size)
         else:
-            layer["moe"] = {
-                "gate": dense(d, cfg.n_routed_experts),
-                "bias": 0.02 * dense(cfg.n_routed_experts, fan_in=1),
-                "shared": ffn(cfg.n_shared_experts
-                              * cfg.moe_intermediate_size),
-                "experts": ffn(cfg.moe_intermediate_size, cfg.held)}
+            layer["moe"] = w.moe(
+                cfg.n_routed_experts,
+                shared=cfg.n_shared_experts * cfg.moe_intermediate_size,
+                width=cfg.moe_intermediate_size, held=cfg.held)
         layers.append(layer)
-    return {"embed": dense(cfg.vocab_size, d, fan_in=d),
-            "head": dense(d, cfg.vocab_size), "norm_f": ones(d),
+    return {"embed": w.dense(cfg.vocab_size, d, fan_in=d),
+            "head": w.dense(d, cfg.vocab_size), "norm_f": w.ones(d),
             "layers": layers}
+
+
+init_params = latent.jitted_init(_init_params)
 
 
 def indexer_qkw(x, c_q, ix, positions, cfg: GLMDSAConfig):
@@ -232,63 +214,21 @@ def attend(h, layer, cfg: GLMDSAConfig):
         scope="block/attn")
 
 
-def moe_ffn(h, layer, cfg: GLMDSAConfig):
-    """The expert half of a layer over tokens ``h`` [T, d] ->
-    ``(h', load)``: shared expert plus this chip's routed experts'
-    part, and the pairs each held expert served, int32 [held]."""
-    moe = layer["moe"]
-    with jax.named_scope("block/moe/route"):
-        x = rmsnorm(h, layer["ffn_norm"], cfg.rms_norm_eps)
-        choice, weight = sigmoid_route(x, moe, cfg.num_experts_per_tok,
-                                       cfg.routed_scaling_factor)
-        order, load = group_by_expert(choice, cfg.held_first, cfg.held)
-    with jax.named_scope("block/moe/shared"):
-        out = swiglu(x, moe["shared"])
-    with jax.named_scope("block/moe/experts"):
-        e = moe["experts"]
-        out = out + grouped_swiglu(x, order, load, weight, e["w1"], e["w3"],
-                                   e["w2"], tile=EXPERT_TILE)
-        return h + out.astype(h.dtype), load
-
-
 def forward(params, tokens, cfg: GLMDSAConfig):
     """``tokens`` int32 [B, S] -> ``(last_logits float32 [B, V],
     logprobs float32 [B, S], expert_load int32 [moe layers, held])``;
     the load is summed over the batch."""
-    b, s = tokens.shape
     with jax.named_scope("embed"):
         h = jnp.take(params["embed"], tokens, axis=0)
-    loads = []
-    for layer in params["layers"]:
-        h = jnp.stack([attend(h[j], layer, cfg) for j in range(b)])
-        flat = h.reshape(b * s, -1)
-        if "moe" in layer:
-            flat, load = moe_ffn(flat, layer, cfg)
-            loads.append(load)
-        else:
-            with jax.named_scope("block/mlp"):
-                x = rmsnorm(flat, layer["ffn_norm"], cfg.rms_norm_eps)
-                flat = flat + swiglu(x, layer["mlp"]).astype(flat.dtype)
-        h = flat.reshape(b, s, -1)
-    return latent.score(h, params, tokens, cfg.rms_norm_eps) \
-        + (jnp.stack(loads),)
+    ffn = functools.partial(
+        latent.sigmoid_ffn, top=cfg.num_experts_per_tok,
+        scale=cfg.routed_scaling_factor, held_first=cfg.held_first,
+        eps=cfg.rms_norm_eps)
+    mix = functools.partial(attend, cfg=cfg)
+    return latent.decode(params, h, tokens, cfg.rms_norm_eps,
+                         [mix] * cfg.num_hidden_layers, ffn)
 
 
-def frame_model(cfg: GLMDSAConfig, seq: int):
-    """``(apply_fn, in_info, out_info)`` for ``tensor_filter
-    framework=jax``: one int32 ``[seq]`` token frame a buffer in, the
-    three tensors of :func:`forward` out."""
-    return latent.frame_model(forward, cfg, seq,
-                              f"{cfg.held}:{cfg.n_moe_layers}")
-
-
-@register_model("glm_dsa")
-def _build_glm_dsa(seq: str = "64", seed: str = "0", dtype: str = "bfloat16",
-                   **sizes: str):
-    """``zoo://glm_dsa?seq=64&hidden_size=64&held_count=8&...``: any
-    field of :class:`GLMDSAConfig` by its name; the defaults are a tiny
-    model whose sparse regime is live at ``seq`` 64."""
-    cfg = latent.config_from_options(GLMDSAConfig, "glm_dsa", dtype, sizes)
-    params = init_params(cfg, jax.random.PRNGKey(int(seed)))
-    apply_fn, in_info, out_info = frame_model(cfg, int(seq))
-    return apply_fn, params, in_info, out_info
+frame_model = latent.frame_model(
+    forward, lambda cfg: f"{cfg.held}:{cfg.n_moe_layers}")
+latent.register("glm_dsa", GLMDSAConfig, init_params, frame_model)

@@ -38,9 +38,7 @@ after each sublayer:
   the choice only, ``moe_renormalize``, ``routed_scaling_factor``; one
   group), the shared expert unweighted. The layer is given
   ``(num_experts, held_first, held_count)``, routes over the whole
-  router and computes its own experts' part (``ops/grouped.py``, which
-  takes the kernel's path where the share is at least half the router);
-  what the experts of other chips would add is not stood in for.
+  router and computes its own experts' part (``latent.routed_experts``).
 * **Stack.** ``h0 = E[tokens]`` (no scale), the layers, ``RMSNorm``, an
   untied head; ``rms_norm_eps``.
 
@@ -55,24 +53,23 @@ Zoo entry ``zoo://kimi_linear?...``: int32 token frame ``[S]`` -> three
 tensors, ``last_logits`` float32 ``[V]``, ``logprobs`` float32 ``[S]``
 (log-softmax of token t+1 at position t; 0 at S-1) and ``expert_load``
 int32 ``[expert layers, held]`` (token-expert pairs each held expert
-served).
+served). Options: any numeric field of :class:`KimiLinearConfig` by its
+name, ``seq``, ``seed``; the defaults are a tiny model of five layers,
+KDA, KDA, KDA, MLA, KDA, the first dense.
 """
 from __future__ import annotations
 
 import dataclasses
-import itertools
+import functools
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ..ops.grouped import group_by_expert, grouped_swiglu
 from ..ops.kda import kda_chunked
 from . import latent
-from .latent import (BLOCK_Q, EXPERT_TILE, _mm, _mm_heads,
-                     causal_attention_out, mla_qkv, sigmoid_route, swiglu)
+from .latent import BLOCK_Q, _mm, _mm_heads, causal_attention_out, mla_qkv
 from .transformer import rmsnorm
-from .zoo import register_model
 
 KDA, MLA = "kda", "mla"
 
@@ -179,77 +176,59 @@ class KimiLinearConfig:
                              "the chosen weights is built")
 
 
-def init_params(cfg: KimiLinearConfig, key: jax.Array) -> Dict[str, Any]:
-    """Seeded tree in ``cfg.dtype``; the router's bias small and not
-    zero, so that it changes choices; ``A_log`` and ``dt_bias`` as the
+def _init_params(cfg: KimiLinearConfig, key: jax.Array) -> Dict[str, Any]:
+    """Seeded tree in ``cfg.dtype``; ``A_log`` and ``dt_bias`` as the
     gated delta-rule layers are initialised (decays ``1 .. 16`` times a
     step of ``0.001 .. 0.1``)."""
-    return jax.jit(_init_params, static_argnums=0)(cfg, key)
-
-
-def _init_params(cfg: KimiLinearConfig, key):
     dt, d = cfg.dtype, cfg.hidden_size
     hk, dk = cfg.kda_num_heads, cfg.kda_head_dim
     h, nope = cfg.num_attention_heads, cfg.qk_nope_head_dim
-    counter = itertools.count()
-
-    def fresh():
-        return jax.random.fold_in(key, next(counter))
-
-    def dense(*shape, fan_in=None):
-        scale = (fan_in or shape[-2]) ** -0.5
-        return (jax.random.normal(fresh(), shape, jnp.float32)
-                * scale).astype(dt)
-
-    def ones(n):
-        return jnp.ones((n,), dt)
-
-    def ffn(width, *lead):
-        return {"w1": dense(*lead, d, width), "w3": dense(*lead, d, width),
-                "w2": dense(*lead, width, d)}
+    w = latent.Seeded(key, dt, d)
 
     def kda():
         step = jnp.exp(jax.random.uniform(
-            fresh(), (hk * dk,), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
+            w.key(), (hk * dk,), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
         return {
-            "wq": dense(d, hk * dk), "wk": dense(d, hk * dk),
-            "wv": dense(d, hk * dk),
-            **{"conv_" + n: dense(cfg.kda_conv_kernel, hk * dk,
-                                  fan_in=cfg.kda_conv_kernel) for n in "qkv"},
-            "wf_a": dense(d, dk), "wf_b": dense(dk, hk * dk),
+            "wq": w.dense(d, hk * dk), "wk": w.dense(d, hk * dk),
+            "wv": w.dense(d, hk * dk),
+            **{"conv_" + n: w.dense(cfg.kda_conv_kernel, hk * dk,
+                                    fan_in=cfg.kda_conv_kernel)
+               for n in "qkv"},
+            "wf_a": w.dense(d, dk), "wf_b": w.dense(dk, hk * dk),
             "A_log": jnp.log(jax.random.uniform(
-                fresh(), (hk,), jnp.float32, 1.0, 16.0)).astype(dt),
+                w.key(), (hk,), jnp.float32, 1.0, 16.0)).astype(dt),
             # the inverse of softplus at the step
             "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dt),
-            "wb": dense(d, hk), "wg_a": dense(d, dk),
-            "wg_b": dense(dk, hk * dk), "o_norm": ones(dk),
-            "wo": dense(hk * dk, d)}
+            "wb": w.dense(d, hk), "wg_a": w.dense(d, dk),
+            "wg_b": w.dense(dk, hk * dk), "o_norm": w.ones(dk),
+            "wo": w.dense(hk * dk, d)}
 
     def mla():
         return {
-            "wq": dense(d, h * (nope + cfg.qk_rope_head_dim)),
-            "wkv_a": dense(d, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
-            "kv_norm": ones(cfg.kv_lora_rank),
-            "wkv_b": dense(cfg.kv_lora_rank, h * (nope + cfg.v_head_dim)),
-            "wo": dense(h * cfg.v_head_dim, d)}
+            "wq": w.dense(d, h * (nope + cfg.qk_rope_head_dim)),
+            "wkv_a": w.dense(d, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+            "kv_norm": w.ones(cfg.kv_lora_rank),
+            "wkv_b": w.dense(cfg.kv_lora_rank, h * (nope + cfg.v_head_dim)),
+            "wo": w.dense(h * cfg.v_head_dim, d)}
 
     layers = []
     for i, kind in enumerate(cfg.kinds):
-        layer = {"attn_norm": ones(d), "ffn_norm": ones(d),
+        layer = {"attn_norm": w.ones(d), "ffn_norm": w.ones(d),
                  "attn": kda() if kind == KDA else mla()}
         if i < cfg.first_k_dense_replace:
-            layer["mlp"] = ffn(cfg.intermediate_size)
+            layer["mlp"] = w.ffn(cfg.intermediate_size)
         else:
-            layer["moe"] = {
-                "gate": dense(d, cfg.num_experts),
-                "bias": 0.02 * dense(cfg.num_experts, fan_in=1),
-                "shared": ffn(cfg.num_shared_experts
-                              * cfg.moe_intermediate_size),
-                "experts": ffn(cfg.moe_intermediate_size, cfg.held)}
+            layer["moe"] = w.moe(
+                cfg.num_experts,
+                shared=cfg.num_shared_experts * cfg.moe_intermediate_size,
+                width=cfg.moe_intermediate_size, held=cfg.held)
         layers.append(layer)
-    return {"embed": dense(cfg.vocab_size, d, fan_in=d),
-            "head": dense(d, cfg.vocab_size), "norm_f": ones(d),
+    return {"embed": w.dense(cfg.vocab_size, d, fan_in=d),
+            "head": w.dense(d, cfg.vocab_size), "norm_f": w.ones(d),
             "layers": layers}
+
+
+init_params = latent.jitted_init(_init_params)
 
 
 def kda_weights(a, cfg: KimiLinearConfig):
@@ -318,74 +297,22 @@ def mla_mix(h, layer, cfg: KimiLinearConfig):
         scale=(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5)
 
 
-def moe(x, m, cfg: KimiLinearConfig):
-    """The expert layer over normed tokens ``x`` [T, d] -> ``(out
-    float32 [T, d], load int32 [held])``: the shared expert plus this
-    chip's routed experts' part, and the pairs each held expert
-    served."""
-    with jax.named_scope("block/moe/route"):
-        choice, weight = sigmoid_route(x, m, cfg.num_experts_per_token,
-                                       cfg.routed_scaling_factor)
-        order, load = group_by_expert(choice, cfg.held_first, cfg.held)
-    with jax.named_scope("block/moe/shared"):
-        out = swiglu(x, m["shared"])
-    with jax.named_scope("block/moe/experts"):
-        e = m["experts"]
-        routed = grouped_swiglu(x, order, load, weight, e["w1"], e["w3"],
-                                e["w2"], tile=EXPERT_TILE,
-                                router=cfg.num_experts)
-        return out + routed, load
-
-
-def ffn(h, layer, cfg: KimiLinearConfig):
-    """The second half of a layer over tokens ``h`` [T, d] -> ``(h',
-    load)``; ``load`` is None for a dense layer."""
-    if "moe" in layer:
-        with jax.named_scope("block/moe/route"):
-            x = rmsnorm(h, layer["ffn_norm"], cfg.rms_norm_eps)
-        out, load = moe(x, layer["moe"], cfg)
-        with jax.named_scope("block/moe/experts"):
-            return h + out.astype(h.dtype), load
-    with jax.named_scope("block/mlp"):
-        x = rmsnorm(h, layer["ffn_norm"], cfg.rms_norm_eps)
-        return h + swiglu(x, layer["mlp"]).astype(h.dtype), None
-
-
 def forward(params, tokens, cfg: KimiLinearConfig):
     """``tokens`` int32 [B, S] -> ``(last_logits float32 [B, V],
     logprobs float32 [B, S], expert_load int32 [expert layers, held])``;
     the load is summed over the batch."""
-    b, s = tokens.shape
     with jax.named_scope("embed"):
         h = jnp.take(params["embed"], tokens, axis=0)
-    loads = []
-    for layer, kind in zip(params["layers"], cfg.kinds):
-        mix = kda_mix if kind == KDA else mla_mix
-        h = jnp.stack([mix(h[j], layer, cfg) for j in range(b)])
-        flat, load = ffn(h.reshape(b * s, -1), layer, cfg)
-        if load is not None:
-            loads.append(load)
-        h = flat.reshape(b, s, -1)
-    return latent.score(h, params, tokens, cfg.rms_norm_eps) \
-        + (jnp.stack(loads),)
+    ffn = functools.partial(
+        latent.sigmoid_ffn, top=cfg.num_experts_per_token,
+        scale=cfg.routed_scaling_factor, held_first=cfg.held_first,
+        eps=cfg.rms_norm_eps)
+    return latent.decode(
+        params, h, tokens, cfg.rms_norm_eps,
+        [functools.partial(kda_mix if kind == KDA else mla_mix, cfg=cfg)
+         for kind in cfg.kinds], ffn)
 
 
-def frame_model(cfg: KimiLinearConfig, seq: int):
-    """``(apply_fn, in_info, out_info)`` for ``tensor_filter
-    framework=jax``: one int32 ``[seq]`` token frame a buffer in, the
-    three tensors of :func:`forward` out."""
-    return latent.frame_model(forward, cfg, seq,
-                              f"{cfg.held}:{cfg.n_moe_layers}")
-
-
-@register_model("kimi_linear")
-def _build_kimi_linear(seq: str = "64", seed: str = "0",
-                       dtype: str = "bfloat16", **sizes: str):
-    """``zoo://kimi_linear?seq=64&held_count=8&...``: any numeric field
-    of :class:`KimiLinearConfig` by its name; the defaults are a tiny
-    model of five layers, KDA, KDA, KDA, MLA, KDA, the first dense."""
-    cfg = latent.config_from_options(KimiLinearConfig, "kimi_linear", dtype,
-                                     sizes)
-    params = init_params(cfg, jax.random.PRNGKey(int(seed)))
-    apply_fn, in_info, out_info = frame_model(cfg, int(seq))
-    return apply_fn, params, in_info, out_info
+frame_model = latent.frame_model(
+    forward, lambda cfg: f"{cfg.held}:{cfg.n_moe_layers}")
+latent.register("kimi_linear", KimiLinearConfig, init_params, frame_model)

@@ -1,28 +1,36 @@
-"""Parts the scoring decoders share (``models/glm_dsa.py``,
-``models/longcat.py``, ``models/afmoe.py``, ``models/kimi_linear.py``,
-``models/brumby.py``):
+"""Parts the scoring decoders of this package share, each written once:
 rotary embedding on interleaved pairs and the latent (MLA) projections
-in the expanded form (the latent-attention decoders'), causal attention with the output
-projection, a SwiGLU MLP, the sigmoid router, the scoring head, and the
-frame and zoo wrappers of a scoring pass. A decoder's own file holds
-what is its own: the layer's order, its projections, an indexer.
+in the expanded form, causal attention with its output projection, a
+SwiGLU MLP, the sigmoid router, **the expert layer**
+(:func:`routed_experts`: the experts a chip holds through
+``ops/grouped.py``, whose form the share decides, the router's width
+read off the layer's own ``gate``), the stack of layers
+(:func:`decode`), the scoring head, the seeded parameter tree
+(:class:`Seeded`, :func:`jitted_init`) and the frame and zoo entries
+of a scoring pass. A decoder's own file holds what is its own: its
+configuration, the layer's order, its mixers, a router of its own.
 
 The configuration object a function takes is the caller's dataclass;
 only the fields named in the function's docstring are read, by the HF
-``config.json`` keys both architectures publish them under.
+``config.json`` keys the architectures publish them under. The scope
+names (``block/moe/route``, ``block/moe/shared``, ``block/moe/experts``
+with ``rows`` inside, ``block/mlp``) are what the per-layer metrics read.
 """
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.grouped import group_by_expert, grouped_swiglu
 from ..ops.sparse_attention import LANES, blocked_causal_attention
 from ..tensors.info import TensorsInfo
 from .transformer import rmsnorm
+from .zoo import register_model
 
 # how the work is cut, not what is computed
 BLOCK_Q = 512             # queries an attention block
@@ -251,6 +259,68 @@ def sigmoid_route(x, moe, top: int, scale: float):
     return choice.astype(jnp.int32), weight
 
 
+def routed_experts(x, m, choice, weight, held_first: int):
+    """The expert layer ``m`` over normed tokens ``x`` [T, d], routed
+    over the whole router (``choice`` int32 [T, K], ``weight`` float32
+    [T, K]) -> ``(out float32 [T, d], load int32 [held])``: the shared
+    expert where ``m`` has one, plus the part of the experts held here
+    (``m["experts"]``, the router's ``held_first`` on), and the pairs
+    each held expert served. What the experts of other chips would add
+    is not stood in for. The grouped product's form follows from the
+    share held of the router, whose width is ``m["gate"]``'s
+    (``ops/grouped.py``)."""
+    e = m["experts"]
+    with jax.named_scope("block/moe/route"):
+        order, load = group_by_expert(choice, held_first, e["w1"].shape[0])
+    shared = None
+    if "shared" in m:
+        with jax.named_scope("block/moe/shared"):
+            shared = swiglu(x, m["shared"])
+    with jax.named_scope("block/moe/experts"):
+        out = grouped_swiglu(x, order, load, weight, e["w1"], e["w3"],
+                             e["w2"], tile=EXPERT_TILE,
+                             router=m["gate"].shape[-1])
+        return (out if shared is None else shared + out), load
+
+
+def sigmoid_ffn(h, layer, *, top: int, scale: float, held_first: int,
+                eps: float):
+    """The second half of a pre-norm layer over tokens ``h`` [T, d] ->
+    ``(h', load)``: ``h`` plus the sigmoid-routed expert layer
+    (:func:`sigmoid_route`, :func:`routed_experts`) over ``RMSNorm(h)``
+    where the layer has one, else plus its dense SwiGLU, ``load``
+    None."""
+    if "moe" not in layer:
+        with jax.named_scope("block/mlp"):
+            x = rmsnorm(h, layer["ffn_norm"], eps)
+            return h + swiglu(x, layer["mlp"]).astype(h.dtype), None
+    m = layer["moe"]
+    with jax.named_scope("block/moe/route"):
+        x = rmsnorm(h, layer["ffn_norm"], eps)
+        choice, weight = sigmoid_route(x, m, top, scale)
+    out, load = routed_experts(x, m, choice, weight, held_first)
+    with jax.named_scope("block/moe/experts"):
+        return h + out.astype(h.dtype), load
+
+
+def decode(params, h, tokens, eps: float, mixers, ffn):
+    """The stack over embedded tokens ``h`` [B, S, d], ``tokens`` int32
+    [B, S] -> :func:`score`'s two tensors and the expert loads, int32
+    [expert layers, held]: layer ``l`` mixes one sequence at a time,
+    ``mixers[l](h [S, d], layer)``, then ``ffn(h [B * S, d], layer) ->
+    (h, load or None)`` takes every sequence's tokens together (the
+    load is summed over the batch)."""
+    b, s = tokens.shape
+    loads = []
+    for layer, mix in zip(params["layers"], mixers):
+        h = jnp.stack([mix(h[j], layer) for j in range(b)])
+        flat, load = ffn(h.reshape(b * s, -1), layer)
+        if load is not None:
+            loads.append(load)
+        h = flat.reshape(b, s, -1)
+    return score(h, params, tokens, eps) + (jnp.stack(loads),)
+
+
 def score(h, params, tokens, eps: float):
     """The head of a scoring pass over ``h`` [B, S, d] -> ``(last_logits
     float32 [B, V], logprobs float32 [B, S])``: the last position's
@@ -266,20 +336,90 @@ def score(h, params, tokens, eps: float):
     return logits[:, -1], logprobs
 
 
-def frame_model(forward, cfg, seq: int, load_dims: str):
-    """``(apply_fn, in_info, out_info)`` for ``tensor_filter
-    framework=jax``: one int32 ``[seq]`` token frame a buffer in, the
-    three tensors of ``forward(params, tokens [1, seq], cfg)`` out, the
-    load's dimensions as the caps spell them (innermost first)."""
+def frame_model(forward, load_dims: Callable[..., str]):
+    """``frame_model(cfg, seq) -> (apply_fn, in_info, out_info)`` for
+    ``tensor_filter framework=jax``: one int32 ``[seq]`` token frame a
+    buffer in, the three tensors of ``forward(params, tokens [1, seq],
+    cfg)`` out, the load's dimensions ``load_dims(cfg)`` as the caps
+    spell them (innermost first)."""
 
-    def apply_fn(p, tokens):
-        last, logprobs, load = forward(p, tokens[None].astype(jnp.int32), cfg)
-        return last[0], logprobs[0], load
+    def make(cfg, seq: int):
+        def apply_fn(p, tokens):
+            last, logprobs, load = forward(
+                p, tokens[None].astype(jnp.int32), cfg)
+            return last[0], logprobs[0], load
 
-    in_info = TensorsInfo.make("int32", str(seq))
-    out_info = TensorsInfo.make("float32,float32,int32",
-                                f"{cfg.vocab_size},{seq},{load_dims}")
-    return apply_fn, in_info, out_info
+        in_info = TensorsInfo.make("int32", str(seq))
+        out_info = TensorsInfo.make(
+            "float32,float32,int32",
+            f"{cfg.vocab_size},{seq},{load_dims(cfg)}")
+        return apply_fn, in_info, out_info
+    return make
+
+
+def register(name: str, cls, init_params, frame_model):
+    """Registers ``zoo://<name>?seq=64&seed=0&dtype=bfloat16&...``: a
+    ``cls`` configuration from the URI's options
+    (:func:`config_from_options`), ``init_params(cfg, key)``'s seeded
+    tree, and ``frame_model(cfg, seq)``'s function and tensor infos (a
+    model that carries a state gives it last)."""
+
+    @register_model(name)
+    def build(seq: str = "64", seed: str = "0", dtype: str = "bfloat16",
+              **sizes: str):
+        cfg = config_from_options(cls, name, dtype, sizes)
+        params = init_params(cfg, jax.random.PRNGKey(int(seed)))
+        apply_fn, *infos = frame_model(cfg, int(seq))
+        return (apply_fn, params, *infos)
+
+
+def jitted_init(build):
+    """``init_params(cfg, key)``: ``build`` jitted with the configuration
+    (a frozen dataclass, hashable) static, so that a decoder's whole
+    seeded tree is one compiled dispatch."""
+    return jax.jit(build, static_argnums=0)
+
+
+class Seeded:
+    """The leaves of a seeded parameter tree in ``dtype``, of a stream
+    ``width`` wide: each draw takes the next key folded in from ``key``,
+    so a tree's values follow from the order its leaves are drawn in."""
+
+    def __init__(self, key, dtype, width: int):
+        self.dtype, self.width = dtype, width
+        self._key, self._drawn = key, itertools.count()
+
+    def key(self):
+        """The next key."""
+        return jax.random.fold_in(self._key, next(self._drawn))
+
+    def dense(self, *shape, fan_in: Optional[int] = None,
+              gain: float = 1.0):
+        """Normal draws times ``gain`` over the root of the fan-in, the
+        next-to-last dimension unless ``fan_in`` is given."""
+        return (jax.random.normal(self.key(), shape, jnp.float32) * gain
+                * (fan_in or shape[-2]) ** -0.5).astype(self.dtype)
+
+    def ones(self, n: int):
+        return jnp.ones((n,), self.dtype)
+
+    def ffn(self, width: int, *lead: int):
+        """A SwiGLU ``width`` wide: ``w1``, ``w3`` [*lead, d, width],
+        ``w2`` [*lead, width, d]."""
+        d = self.width
+        return {"w1": self.dense(*lead, d, width),
+                "w3": self.dense(*lead, d, width),
+                "w2": self.dense(*lead, width, d)}
+
+    def moe(self, router: int, *, shared: int, width: int, held: int):
+        """A sigmoid-routed expert layer (:func:`routed_experts`): the
+        router's ``gate`` [d, router] and ``bias`` [router] (small and not
+        zero, so that it changes choices), a ``shared`` SwiGLU that wide,
+        and ``held`` experts ``width`` wide."""
+        return {"gate": self.dense(self.width, router),
+                "bias": 0.02 * self.dense(router, fan_in=1),
+                "shared": self.ffn(shared),
+                "experts": self.ffn(width, held)}
 
 
 def config_from_options(cls, zoo_name: str, dtype: str, sizes: dict):

@@ -30,8 +30,10 @@ A layer, for one sequence ``h0`` [S, d] (prefill, the expanded form):
   n_routed_experts of g_e) x``: an identity expert costs no product.
   The layer is given ``(n_routed_experts, held_first, held_count)``,
   routes over the whole router and computes its own experts' part
-  (``ops/grouped.py``) and the identity part, which every chip computes
-  alike; what the experts of other chips would add is not stood in for.
+  (``latent.routed_experts``, the share taken of the whole router,
+  identity outputs included) and the identity part, which every chip
+  computes alike; what the experts of other chips would add is not
+  stood in for.
 
 Where ``MoE`` runs between ``x1`` and the last line is the compiler's:
 the program states the dependency and no order. Scope names: ``embed``,
@@ -44,22 +46,22 @@ tensors, ``last_logits`` float32 ``[V]``, ``logprobs`` float32 ``[S]``
 (log-softmax of token t+1 at position t; 0 at S-1) and ``expert_load``
 int32 ``[layers, held + 1]`` (token-expert pairs each held expert
 served, and in the last column the pairs the identity experts took).
+Options: any field of :class:`LongCatConfig` by its name, ``seq``,
+``seed``; the defaults are a tiny model whose router chooses real and
+identity experts alike.
 """
 from __future__ import annotations
 
 import dataclasses
-import itertools
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 
-from ..ops.grouped import group_by_expert, grouped_swiglu
 from . import latent
-from .latent import (BLOCK_Q, EXPERT_TILE, causal_attention_out, mla_qkv,
+from .latent import (BLOCK_Q, causal_attention_out, mla_qkv, routed_experts,
                      swiglu)
 from .transformer import rmsnorm
-from .zoo import register_model
 
 
 @dataclasses.dataclass(frozen=True)
@@ -132,57 +134,44 @@ class LongCatConfig:
                              "moe_topk wide")
 
 
-def init_params(cfg: LongCatConfig, key: jax.Array) -> Dict[str, Any]:
+def _init_params(cfg: LongCatConfig, key: jax.Array) -> Dict[str, Any]:
     """Seeded tree in ``cfg.dtype``: a layer is two ``sub`` layers
     (``attn_norm``, ``attn``, ``ffn_norm``, ``mlp``) and one ``moe``
     (``gate``, ``bias``, ``experts``). The router's bias is small and
     not zero, so that it changes choices, and its classifier sharp
     enough that the chosen scores carry weight."""
-    return jax.jit(_init_params, static_argnums=0)(cfg, key)
-
-
-def _init_params(cfg: LongCatConfig, key):
-    dt, d, h = cfg.dtype, cfg.hidden_size, cfg.num_attention_heads
+    d, h = cfg.hidden_size, cfg.num_attention_heads
     nope, rp, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    counter = itertools.count()
-
-    def dense(*shape, fan_in=None, gain=1.0):
-        k = jax.random.fold_in(key, next(counter))
-        scale = gain * (fan_in or shape[-2]) ** -0.5
-        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dt)
-
-    def ones(n):
-        return jnp.ones((n,), dt)
-
-    def ffn(width, *lead):
-        return {"w1": dense(*lead, d, width), "w3": dense(*lead, d, width),
-                "w2": dense(*lead, width, d)}
+    w = latent.Seeded(key, cfg.dtype, d)
 
     def sub():
         return {
-            "attn_norm": ones(d),
+            "attn_norm": w.ones(d),
             "attn": {
-                "wq_a": dense(d, cfg.q_lora_rank),
-                "q_norm": ones(cfg.q_lora_rank),
-                "wq_b": dense(cfg.q_lora_rank, h * (nope + rp)),
-                "wkv_a": dense(d, cfg.kv_lora_rank + rp),
-                "kv_norm": ones(cfg.kv_lora_rank),
-                "wkv_b": dense(cfg.kv_lora_rank, h * (nope + vd)),
-                "wo": dense(h * vd, d)},
-            "ffn_norm": ones(d),
-            "mlp": ffn(cfg.ffn_hidden_size)}
+                "wq_a": w.dense(d, cfg.q_lora_rank),
+                "q_norm": w.ones(cfg.q_lora_rank),
+                "wq_b": w.dense(cfg.q_lora_rank, h * (nope + rp)),
+                "wkv_a": w.dense(d, cfg.kv_lora_rank + rp),
+                "kv_norm": w.ones(cfg.kv_lora_rank),
+                "wkv_b": w.dense(cfg.kv_lora_rank, h * (nope + vd)),
+                "wo": w.dense(h * vd, d)},
+            "ffn_norm": w.ones(d),
+            "mlp": w.ffn(cfg.ffn_hidden_size)}
 
     layers = [{
         "sub": [sub(), sub()],
         "moe": {
-            "gate": dense(d, cfg.router_width, gain=2.0),
-            "bias": dense(cfg.router_width, fan_in=1,
-                          gain=0.25 / cfg.router_width),
-            "experts": ffn(cfg.expert_ffn_hidden_size, cfg.held)},
+            "gate": w.dense(d, cfg.router_width, gain=2.0),
+            "bias": w.dense(cfg.router_width, fan_in=1,
+                            gain=0.25 / cfg.router_width),
+            "experts": w.ffn(cfg.expert_ffn_hidden_size, cfg.held)},
     } for _ in range(cfg.num_layers)]
-    return {"embed": dense(cfg.vocab_size, d, fan_in=d),
-            "head": dense(d, cfg.vocab_size), "norm_f": ones(d),
+    return {"embed": w.dense(cfg.vocab_size, d, fan_in=d),
+            "head": w.dense(d, cfg.vocab_size), "norm_f": w.ones(d),
             "layers": layers}
+
+
+init_params = latent.jitted_init(_init_params)
 
 
 def attend(h, sub, cfg: LongCatConfig):
@@ -219,17 +208,14 @@ def moe(x, m, cfg: LongCatConfig):
     expert served with the identity experts' pairs last."""
     with jax.named_scope("block/moe/route"):
         choice, weight = route(x, m, cfg)
-        order, counts = group_by_expert(choice, cfg.held_first, cfg.held)
+    routed, counts = routed_experts(x, m, choice, weight, cfg.held_first)
     with jax.named_scope("block/moe/zero"):
         zero = choice >= cfg.n_routed_experts
         out = x.astype(jnp.float32) * jnp.sum(
             jnp.where(zero, weight, 0.0), -1, keepdims=True)
         load = jnp.concatenate([counts, jnp.sum(zero, dtype=jnp.int32)[None]])
     with jax.named_scope("block/moe/experts"):
-        e = m["experts"]
-        out = out + grouped_swiglu(x, order, counts, weight, e["w1"],
-                                   e["w3"], e["w2"], tile=EXPERT_TILE)
-    return out, load
+        return out + routed, load
 
 
 def block(h, layer, cfg: LongCatConfig):
@@ -269,21 +255,6 @@ def forward(params, tokens, cfg: LongCatConfig):
         + (jnp.stack(loads),)
 
 
-def frame_model(cfg: LongCatConfig, seq: int):
-    """``(apply_fn, in_info, out_info)`` for ``tensor_filter
-    framework=jax``: one int32 ``[seq]`` token frame a buffer in, the
-    three tensors of :func:`forward` out."""
-    return latent.frame_model(forward, cfg, seq,
-                              f"{cfg.held + 1}:{cfg.num_layers}")
-
-
-@register_model("longcat")
-def _build_longcat(seq: str = "64", seed: str = "0", dtype: str = "bfloat16",
-                   **sizes: str):
-    """``zoo://longcat?seq=64&held_first=4&held_count=4&...``: any field
-    of :class:`LongCatConfig` by its name; the defaults are a tiny model
-    whose router chooses real and identity experts alike."""
-    cfg = latent.config_from_options(LongCatConfig, "longcat", dtype, sizes)
-    params = init_params(cfg, jax.random.PRNGKey(int(seed)))
-    apply_fn, in_info, out_info = frame_model(cfg, int(seq))
-    return apply_fn, params, in_info, out_info
+frame_model = latent.frame_model(
+    forward, lambda cfg: f"{cfg.held + 1}:{cfg.num_layers}")
+latent.register("longcat", LongCatConfig, init_params, frame_model)

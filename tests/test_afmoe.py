@@ -42,7 +42,7 @@ RANK1 = dict(SIZES, expert_rank=1)
 def small_blocks(monkeypatch):
     """Several attention blocks and several expert tiles at this size."""
     monkeypatch.setattr(afmoe, "BLOCK_Q", 16)
-    monkeypatch.setattr(afmoe, "EXPERT_TILE", 8)
+    monkeypatch.setattr(latent, "EXPERT_TILE", 8)
 
 
 def _cfg(dtype=jnp.float32, **over):
@@ -139,20 +139,24 @@ def test_a_window_layer_keeps_its_window_and_a_full_layer_no_position():
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_the_shares_add_up(seed):
-    """The routed parts of all four shares of 4 of the 16 experts plus
-    the shared expert once are the uncut layer's output, and the
-    shares' loads side by side are the uncut layer's load: every
-    token-expert pair is served by exactly one share."""
+    """The routed parts of all four shares of 4 of the 16 experts (the
+    tile loops) plus the shared expert once are the uncut layer's output
+    (the kernel's path), and the shares' loads side by side are the
+    uncut layer's load: every token-expert pair is served by exactly one
+    share."""
     full = _cfg(held_first=0, held_count=0)
     m = afmoe.init_params(full, jax.random.PRNGKey(seed))["layers"][2]["moe"]
     x = _hidden(seed + 20, full)
-    whole, load = afmoe.moe(x, m, full)
+    choice, weight = latent.sigmoid_route(x, m, full.num_experts_per_tok,
+                                          full.route_scale)
+    whole, load = latent.routed_experts(x, m, choice, weight, 0)
     shared = latent.swiglu(x, m["shared"])
     total, loads = shared, []
     for rank in range(4):
         part = dict(m, experts=jax.tree.map(
             lambda w: w[4 * rank:4 * rank + 4], m["experts"]))
-        out, load_r = afmoe.moe(x, part, _cfg(held_first=4 * rank))
+        out, load_r = latent.routed_experts(x, part, choice, weight,
+                                            4 * rank)
         total = total + (out - shared)
         loads.append(np.asarray(load_r))
     np.testing.assert_allclose(total, whole, atol=2e-5)
@@ -162,14 +166,19 @@ def test_the_shares_add_up(seed):
 
 def test_the_decoders_share_their_parts():
     """One router, one SwiGLU, one attention half with its output
-    projection, one grouped product, one scoring head: the three
-    decoders name the same function objects."""
-    assert afmoe.sigmoid_route is glm_dsa.sigmoid_route \
-        is latent.sigmoid_route
-    for name in ("swiglu", "causal_attention_out", "rmsnorm",
-                 "group_by_expert", "grouped_swiglu"):
+    projection, one expert layer, one scoring head: the three decoders
+    name the same function objects, and none the grouped product's but
+    through the expert layer."""
+    assert afmoe.sigmoid_route is latent.sigmoid_route
+    assert afmoe.routed_experts is longcat.routed_experts \
+        is latent.routed_experts
+    for name in ("causal_attention_out", "rmsnorm"):
         assert getattr(afmoe, name) is getattr(glm_dsa, name) \
             is getattr(longcat, name), name
+    assert afmoe.swiglu is longcat.swiglu is latent.swiglu
+    for module in (afmoe, glm_dsa, longcat):
+        assert not hasattr(module, "grouped_swiglu") \
+            and not hasattr(module, "group_by_expert"), module
     assert not hasattr(glm_dsa, "route")
     assert latent.blocked_causal_attention \
         is sparse_attention.blocked_causal_attention
@@ -201,78 +210,12 @@ def test_config_reads_the_published_keys():
         zoo.build("afmoe", hidden="64")
 
 
-def _whole_router_choice(case, rng, t, k, router, tile):
-    """``[t, k]`` expert ids over the whole router, a token's all
-    different, for a way-out edge of the grouped kernel (below)."""
-    for _ in range(100):
-        choice = np.stack([rng.permutation(router)[:k] for _ in range(t)])
-        if case == "an_expert_nobody_chose":
-            for row in choice:
-                row[row == 2] = rng.choice(np.setdiff1d(np.arange(router),
-                                                        [2, *row]))
-        counts = np.bincount(choice.ravel(), minlength=router)
-        if case == "ends_mid_tile" and (counts[0] % tile == 0
-                                        or not counts[1]):
-            continue
-        if case == "a_tile_in_the_slack" and (counts[-1] % tile == 0
-                                              or router - 1 not in choice[0]):
-            continue
-        return choice
-    raise AssertionError(case)
-
-
-@pytest.mark.parametrize("case", ["mixed", "an_expert_nobody_chose",
-                                  "ends_mid_tile", "a_tile_in_the_slack"])
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-3),
-                                       (jnp.bfloat16, 0.15)],
-                         ids=["float32", "bfloat16"])
-def test_the_whole_router_adds_each_pair_once(case, dtype, tol):
-    """The whole router held (Trinity's share): every one of a token's
-    ``K`` pairs is served here and added, weighted, into its row by the
-    kernel, once. The way out's edges: an expert nobody chose, expert
-    0's run ending inside a tile with expert 1's rows behind it (not
-    added), the last expert's tile reaching the slack slots that hold
-    pair 0 (token 0's, which the last expert serves: counted once).
-    Against the dense form and the tile loops."""
-    from nnstreamer_tpu.ops.grouped import group_by_expert, grouped_swiglu
-    rng = np.random.default_rng(41)
-    t, d, f, k, router, tile = 48, 16, 24, 4, 8, 32
-    choice = _whole_router_choice(case, rng, t, k, router, tile)
-    x = rng.standard_normal((t, d)).astype(np.float32)
-    weight = rng.random((t, k)).astype(np.float32)
-    w1, w3 = (rng.standard_normal((router, d, f)).astype(np.float32)
-              for _ in range(2))
-    w2 = rng.standard_normal((router, f, d)).astype(np.float32)
-    order, counts = group_by_expert(jnp.asarray(choice, jnp.int32), 0, router)
-    assert (case != "an_expert_nobody_chose") == bool(int(counts[2]))
-    args = (jnp.asarray(x, dtype), order, counts, weight,
-            *(jnp.asarray(w, dtype) for w in (w1, w3, w2)))
-    got = jax.jit(lambda *a: grouped_swiglu(*a, tile=tile, router=router))(
-        *args)
-    loops = jax.jit(lambda *a: grouped_swiglu(*a, tile=tile))(*args)
-    dense = np.zeros((t, router), np.float32)
-    np.add.at(dense, (np.arange(t)[:, None], choice), weight)
-    every = jnp.einsum("tef,efd->ted", jax.nn.silu(
-        jnp.einsum("td,edf->tef", x, w1)) * jnp.einsum("td,edf->tef", x, w3),
-        w2)
-    want = jnp.einsum("ted,te->td", every, dense)
-    atol = tol * (1 + 9 * (dtype == jnp.bfloat16))
-    np.testing.assert_allclose(got, want, atol=atol, rtol=tol)
-    np.testing.assert_allclose(got, loops, atol=atol, rtol=tol)
-
-
-@pytest.mark.parametrize("share,grouped", [
-    ("", 3), ("&held_first=0&held_count=16", 3),
-    ("&held_first=4&held_count=4", 0), ("&held_first=0&held_count=15", 3),
-    ("&held_first=8&held_count=8", 3), ("&held_first=0&held_count=7", 0)],
-    ids=["default", "all_sixteen", "a_quarter", "all_but_one", "a_half",
-         "under_a_half"])
+@pytest.mark.parametrize("share,grouped", [("", 3)], ids=["default"])
 def test_whole_router_runs_the_grouped_kernel(share, grouped):
-    """``kernel_calls``: ``nns_grouped_swiglu`` once an expert layer
-    where the held experts are at least half the router (what the layer
-    observes, no option: ``ops/grouped.py::takes_kernel``), the whole
-    router among them, and not at all under a half: those keep the tile
-    loops."""
+    """``kernel_calls`` through the pipeline: ``nns_grouped_swiglu`` once
+    an expert layer where the whole router is held (the other shares,
+    on both sides of half the router, read off the traced program:
+    ``test_grouped.py::test_the_share_picks_the_form``)."""
     caps = ("other/tensors,format=static,num_tensors=1,types=(string)int32,"
             "dimensions=(string)64,framerate=0/1")
     p = parse_launch(f'appsrc name=in caps="{caps}" ! tensor_filter name=f '
@@ -285,10 +228,8 @@ def test_whole_router_runs_the_grouped_kernel(share, grouped):
     report = p["f"].transfer_report()
     p.stop()
     # 4 layers x 4 blocks of 16 queries; 3 of the 4 are expert layers
-    calls = {"nns_masked_attention": 16}
-    if grouped:
-        calls["nns_grouped_swiglu"] = grouped
-    assert report["kernel_calls"] == calls
+    assert report["kernel_calls"] == {"nns_masked_attention": 16,
+                                      "nns_grouped_swiglu": grouped}
 
 
 @pytest.mark.parametrize("window", ["", "in-flight=4 prefetch-host=true"],

@@ -16,9 +16,6 @@ import jax.numpy as jnp
 
 from nnstreamer_tpu import Buffer, parse_launch
 from nnstreamer_tpu.models import afmoe, glm_dsa, kimi_linear, latent, zoo
-from nnstreamer_tpu.ops import grouped
-from nnstreamer_tpu.ops.grouped import (group_by_expert, grouped_swiglu,
-                                        takes_kernel)
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark"))
@@ -52,7 +49,7 @@ SEQ = 64
 def small_blocks(monkeypatch):
     """Several attention blocks and several expert tiles at this size."""
     monkeypatch.setattr(kimi_linear, "BLOCK_Q", 16)
-    monkeypatch.setattr(kimi_linear, "EXPERT_TILE", 8)
+    monkeypatch.setattr(latent, "EXPERT_TILE", 8)
 
 
 def _cfg(dtype=jnp.float32, **over):
@@ -185,7 +182,9 @@ def test_the_shares_add_up(seed):
     for rank in range(2):
         part = dict(layer, moe=dict(m, experts=jax.tree.map(
             lambda w: w[8 * rank:8 * rank + 8], m["experts"])))
-        out, load = kimi_linear.ffn(h, part, _cfg(held_first=8 * rank))
+        out, load = latent.sigmoid_ffn(
+            h, part, top=full.num_experts_per_token,
+            scale=full.routed_scaling_factor, held_first=8 * rank, eps=eps)
         total = total + (out - once)
         loads.append(np.asarray(load))
     np.testing.assert_allclose(total, want, atol=2e-5)
@@ -195,152 +194,18 @@ def test_the_shares_add_up(seed):
         == SEQ * full.num_experts_per_token
 
 
-def _routing(case, rng, t, k, held_first, held, router):
-    """``[t, k]`` expert ids, a token's all different."""
-    inside = np.arange(held_first, held_first + held)
-    outside = np.setdiff1d(np.arange(router), inside)
-    if case == "every_pair_absent":
-        return np.stack([rng.permutation(outside)[:k] for _ in range(t)])
-    if case in ("every_pair_here", "a_tile_in_the_slack"):
-        return np.stack([rng.permutation(inside)[:k] for _ in range(t)])
-    choice = np.stack([rng.permutation(router)[:k] for _ in range(t)])
-    if case == "a_token_all_here":
-        choice[5] = rng.permutation(inside)[:k]
-    if case == "a_token_none_here":
-        choice[5] = rng.permutation(outside)[:k]
-    if case == "an_expert_nobody_chose":
-        choice[choice == held_first + 2] = outside[0]
-        # a token's ids stay different: drop a doubled one on another
-        for row in choice:
-            seen = set()
-            for c in range(k):
-                while row[c] in seen:
-                    row[c] = rng.choice(np.setdiff1d(
-                        np.arange(router), [held_first + 2, *seen]))
-                seen.add(row[c])
-    return choice
-
-
-@pytest.mark.parametrize("case", [
-    "mixed", "an_expert_nobody_chose", "every_pair_absent", "every_pair_here",
-    "a_token_all_here", "a_token_none_here", "ends_mid_tile",
-    "a_tile_in_the_slack", "runs_of_tokens"])
-@pytest.mark.parametrize("held_first,dtype,tol", [
-    (0, jnp.float32, 1e-3), (8, jnp.float32, 1e-3), (8, jnp.bfloat16, 0.15)],
-    ids=["rank0", "rank1", "rank1_bfloat16"])
-def test_the_kernels_path_serves_a_share(monkeypatch, case, held_first,
-                                         dtype, tol):
-    """Half a router held (8 of 16): the sorted buffer holds the pairs
-    of the experts held elsewhere behind the last held expert's, where
-    the kernel's walk never begins a step, and on the way out they
-    count as nothing: the kernel adds each served pair, weighted, into
-    its token's row, and no other row. Against the tile loops (the same
-    arguments with no router named) and against the dense form (every
-    held expert over every token, weighted by the token's weight for it
-    or 0). The way out's edges: a token whose pairs are all here, one
-    with none here (its row exactly 0), an expert whose run ends inside
-    a tile with the next expert's rows behind it, the last expert's
-    tile reaching the slack slots (which hold pair 0, token 0's: counted
-    once), an expert nobody chose; and a sum of more tokens than the
-    chip's VMEM holds (the module's capacity steered down to 40 tokens'
-    sum): three calls on runs of 32 tokens, each run grouped anew."""
-    rng = np.random.default_rng(3)
-    t, d, f, k, router, held, tile = 96, 16, 24, 4, 16, 8, 32
-    assert takes_kernel(held, router)
-    if case == "runs_of_tokens":
-        monkeypatch.setattr(grouped, "_VMEM", grouped._vmem_asked(
-            40, d, f, tile, jnp.dtype(dtype).itemsize))
-    x = rng.standard_normal((t, d)).astype(np.float32)
-    choice = _routing(case, rng, t, k, held_first, held, router
-                      ).astype(np.int32)
-    weight = rng.random((t, k)).astype(np.float32)
-    w1, w3 = (rng.standard_normal((held, d, f)).astype(np.float32)
-              for _ in range(2))
-    w2 = rng.standard_normal((held, f, d)).astype(np.float32)
-    order, counts = group_by_expert(jnp.asarray(choice), held_first, held)
-    if case == "every_pair_absent":
-        assert int(counts.sum()) == 0
-    if case == "every_pair_here":
-        assert int(counts.sum()) == t * k
-    if case == "an_expert_nobody_chose":
-        assert int(counts[2]) == 0 and int(counts.sum()) > 0
-    if case == "ends_mid_tile":
-        # an expert's last tile holds the next expert's first rows
-        assert any(c % tile and n for c, n in zip(counts[:-1].tolist(),
-                                                  counts[1:].tolist()))
-    if case == "a_tile_in_the_slack":
-        # nothing is held elsewhere: the last expert's last tile runs
-        # into the free slots behind it, and token 0 is served here
-        assert int(counts.sum()) == t * k and int(counts[-1]) % tile
-    args = (jnp.asarray(x, dtype), order, counts, weight,
-            *(jnp.asarray(w, dtype) for w in (w1, w3, w2)))
-    got = jax.jit(lambda *a: grouped_swiglu(*a, tile=tile, router=router))(
-        *args)
-    assert _kernel_calls(lambda *a: grouped_swiglu(
-        *a, tile=tile, router=router), *args) \
-        == (3 if case == "runs_of_tokens" else 1)
-    loops = jax.jit(lambda *a: grouped_swiglu(*a, tile=tile))(*args)
-    assert got.dtype == jnp.float32
-    dense = np.zeros((t, router), np.float32)
-    np.add.at(dense, (np.arange(t)[:, None], choice), weight)
-    dense = dense[:, held_first:held_first + held]
-    every = jnp.einsum("tef,efd->ted", jax.nn.silu(
-        jnp.einsum("td,edf->tef", x, w1)) * jnp.einsum("td,edf->tef", x, w3),
-        w2)
-    want = jnp.einsum("ted,te->td", every, dense)
-    atol = tol * (1 + 9 * (dtype == jnp.bfloat16))
-    np.testing.assert_allclose(got, want, atol=atol, rtol=tol)
-    np.testing.assert_allclose(got, loops, atol=atol, rtol=tol)
-    if case == "a_token_none_here":
-        assert not np.asarray(got[5]).any()
-
-
-def _kernel_calls(fn, *args):
-    return sum(e.primitive.name == "pallas_call"
-               for e in jax.make_jaxpr(fn)(*args).eqns)
-
-
-@pytest.mark.parametrize("t,d,calls", [
-    (8192, 2304, 1), (9159, 2304, 1), (9160, 2304, 2), (16384, 2304, 2),
-    (4096, 2048, 1), (14967, 512, 1), (20000, 512, 2)],
-    ids=["kimi_linear", "kimi_linear_most", "kimi_linear_past_vmem",
-         "kimi_linear_16k", "trinity", "narrow_most", "narrow_past_smem"])
-def test_runs_of_tokens_fit_the_chip(t, d, calls):
-    """The kernel's float32 sum of ``t`` tokens stays in VMEM beside an
-    expert's matrices twice over (experts of ``d`` x 1024, 8 a token, 128
-    held of 256, tiles of 256, bfloat16), and its slots' tokens and
-    weights in SMEM: past either, the tokens go in runs that fit, a call
-    each (VMEM binds at the Kimi-Linear cell's widths, SMEM at narrow
-    ones)."""
-    spec = jax.ShapeDtypeStruct
-    args = (spec((t, d), jnp.bfloat16), spec((t * 8,), jnp.int32),
-            spec((128,), jnp.int32), spec((t, 8), jnp.float32),
-            spec((128, d, 1024), jnp.bfloat16),
-            spec((128, d, 1024), jnp.bfloat16),
-            spec((128, 1024, d), jnp.bfloat16))
-    assert _kernel_calls(lambda *a: grouped_swiglu(
-        *a, tile=256, router=256), *args) == calls
-
-
-def test_which_shares_take_the_kernel():
-    """At least half the router held: the buffer sorted by expert, sized
-    for every pair, is at most twice the rows an even routing serves."""
-    assert [takes_kernel(h, 16) for h in (16, 15, 8, 7, 4, 1)] \
-        == [True, True, True, False, False, False]
-    assert takes_kernel(128, 256) and not takes_kernel(16, 256) \
-        and not takes_kernel(16, 768) and takes_kernel(128, 128)
-
-
 def test_the_decoders_share_their_parts():
     """One router, one SwiGLU, one attention half with its output
-    projection, one grouped product, one scoring head, one set of
-    latent projections."""
-    assert kimi_linear.sigmoid_route is glm_dsa.sigmoid_route \
-        is latent.sigmoid_route
-    for name in ("swiglu", "causal_attention_out", "rmsnorm",
-                 "group_by_expert", "grouped_swiglu"):
+    projection, one expert layer, one scoring head, one set of latent
+    projections; neither this decoder nor GLM-5 names the grouped
+    product's parts."""
+    for name in ("causal_attention_out", "rmsnorm"):
         assert getattr(kimi_linear, name) is getattr(glm_dsa, name) \
             is getattr(afmoe, name), name
+    assert afmoe.routed_experts is latent.routed_experts
+    for module in (kimi_linear, glm_dsa):
+        assert not hasattr(module, "grouped_swiglu") \
+            and not hasattr(module, "group_by_expert"), module
     assert kimi_linear.mla_qkv is glm_dsa.mla_qkv is latent.mla_qkv
 
 
@@ -392,15 +257,14 @@ CAPS = ("other/tensors,format=static,num_tensors=1,types=(string)int32,"
         "dimensions=(string)64,framerate=0/1")
 
 
-@pytest.mark.parametrize("share,grouped", [
-    ("", 4), ("&held_first=8&held_count=8", 4),
-    ("&held_first=0&held_count=7", 0), ("&held_first=12&held_count=4", 0)],
-    ids=["whole", "a_half", "under_a_half", "a_quarter"])
+@pytest.mark.parametrize("share,grouped", [("", 4)], ids=["whole"])
 def test_half_a_router_runs_the_grouped_kernel(share, grouped):
     """``kernel_calls`` names every Pallas kernel of the program: the
     chunked recurrence's two a KDA layer, the attention kernel a block
     of the latent layer's queries, and ``nns_grouped_swiglu`` once an
-    expert layer where at least half the router is held."""
+    expert layer where at least half the router is held (the other
+    shares, read off the traced program:
+    ``test_grouped.py::test_the_share_picks_the_form``)."""
     p = parse_launch(f'appsrc name=in caps="{CAPS}" ! tensor_filter name=f '
                      f'framework=jax model=zoo://kimi_linear?seq=64&seed=3'
                      f'{share} ! appsink name=out')
@@ -411,11 +275,9 @@ def test_half_a_router_runs_the_grouped_kernel(share, grouped):
     report = p["f"].transfer_report()
     p.stop()
     # 4 KDA layers; the one MLA layer x 4 blocks of 16 queries
-    calls = {"nns_kda_chunk_intra": 4, "nns_kda_chunk_state": 4,
-             "nns_masked_attention": 4}
-    if grouped:
-        calls["nns_grouped_swiglu"] = grouped
-    assert report["kernel_calls"] == calls
+    assert report["kernel_calls"] == {
+        "nns_kda_chunk_intra": 4, "nns_kda_chunk_state": 4,
+        "nns_masked_attention": 4, "nns_grouped_swiglu": grouped}
 
 
 @pytest.mark.parametrize("window", ["", "in-flight=4 prefetch-host=true"],

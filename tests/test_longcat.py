@@ -39,7 +39,7 @@ RANK1 = dict(SIZES, n_routed_experts_total=32, expert_rank=1)
 def small_blocks(monkeypatch):
     """Several attention blocks and several expert tiles at this size."""
     monkeypatch.setattr(longcat, "BLOCK_Q", 16)
-    monkeypatch.setattr(longcat, "EXPERT_TILE", 8)
+    monkeypatch.setattr(latent, "EXPERT_TILE", 8)
 
 
 def _cfg(dtype=jnp.float32, **over):
@@ -154,7 +154,8 @@ def test_router_bias_moves_the_choice_not_the_weight():
 def test_identity_experts_cost_no_product():
     """A token whose chosen experts are all identities gets
     ``routed_scaling_factor x sum(p) x`` and runs no product: the
-    grouped loops turn zero times (NaN weights would poison any turn),
+    grouped kernel (2 real experts of a router of 4 held) walks no
+    step (NaN weights would poison any),
     and the load counts its pairs in the last column alone. One whose
     choice is half real gets ``g_0 SwiGLU_0(x) + g_2 x``."""
     cfg, gate, x = _router(np.asarray([0.1, 0.05, 0.45, 0.4]))
@@ -266,15 +267,21 @@ def test_bfloat16_scale_is_not_rounded_to_the_stream():
 
 
 def test_both_decoders_share_the_latent_parts():
-    """One copy of the latent projections, the rotations, the SwiGLU
-    and the attention half: ``models/glm_dsa.py`` and
-    ``models/longcat.py`` name the same function objects."""
-    for name in ("mla_qkv", "swiglu", "causal_attention_out", "rmsnorm",
-                 "group_by_expert", "grouped_swiglu"):
+    """One copy of the latent projections, the rotations, the attention
+    half and the expert layer: ``models/glm_dsa.py`` and
+    ``models/longcat.py`` name the same function objects, and neither
+    the grouped product's parts."""
+    for name in ("mla_qkv", "causal_attention_out", "rmsnorm"):
         assert getattr(glm_dsa, name) is getattr(longcat, name), name
+    assert longcat.routed_experts is latent.routed_experts
+    assert longcat.swiglu is latent.swiglu
+    for module in (glm_dsa, longcat):
+        assert not hasattr(module, "grouped_swiglu") \
+            and not hasattr(module, "group_by_expert"), module
     assert glm_dsa.rope_interleaved is latent.rope_interleaved
     assert glm_dsa.BLOCK_Q == latent.BLOCK_Q == 512
-    assert glm_dsa.EXPERT_TILE == latent.EXPERT_TILE == 256
+    assert not hasattr(glm_dsa, "EXPERT_TILE") \
+        and not hasattr(longcat, "EXPERT_TILE")
 
 
 def test_config_reads_the_published_keys():
