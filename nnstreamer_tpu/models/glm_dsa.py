@@ -54,9 +54,7 @@ import jax.numpy as jnp
 
 from ..ops.sparse_attention import topk_mask
 from . import latent
-from .latent import (BLOCK_Q, _mm, causal_attention_out, mla_qkv,
-                     rope_interleaved)
-from .transformer import rmsnorm
+from .latent import BLOCK_Q, _mm, rope_interleaved
 
 LAYER_NORM_EPS = 1e-6     # the indexer's key norm
 
@@ -188,15 +186,16 @@ def index_scores(q_i, k_i, w):
     return jnp.sum(jax.nn.relu(dots) * w[:, :, None], axis=1)
 
 
-def attend(h, layer, cfg: GLMDSAConfig):
-    """The attention half of a layer for one sequence ``h`` [S, d]."""
-    s = h.shape[0]
-    positions = jnp.arange(s, dtype=jnp.int32)
-    with jax.named_scope("block/attn"):
-        x = rmsnorm(h, layer["attn_norm"], cfg.rms_norm_eps)
-        c_q, q, k, v = mla_qkv(x, layer["attn"], positions, cfg)
+def selection(ix, cfg, x, c_q, positions):
+    """The indexer ``ix`` over normed ``x`` [S, d] and the query latent
+    ``c_q`` as ``blocked_causal_attention``'s ``key_mask``: for queries
+    ``[lo, hi)`` the ``index_topk`` keys of largest ``I[t, s <= t]``,
+    None for a block that keeps every causal key. Its operations are
+    named ``block/indexer`` (``select`` inside). Reads
+    ``cfg.index_n_heads``, ``index_head_dim``, ``index_topk``,
+    ``qk_rope_head_dim`` and ``rope_theta``."""
     with jax.named_scope("block/indexer"):
-        q_i, k_i, w = indexer_qkw(x, c_q, layer["indexer"], positions, cfg)
+        q_i, k_i, w = indexer_qkw(x, c_q, ix, positions, cfg)
 
     def selected(lo, hi):
         # up to index_topk keys lie at or before these queries: all kept
@@ -208,10 +207,14 @@ def attend(h, layer, cfg: GLMDSAConfig):
                 causal = jnp.arange(lo, hi)[:, None] >= jnp.arange(hi)[None]
                 return topk_mask(scores, cfg.index_topk, causal)
 
-    return h + causal_attention_out(
-        q, k, v, layer["attn"]["wo"], block_q=BLOCK_Q, key_mask=selected,
-        scale=(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5,
-        scope="block/attn")
+    return selected
+
+
+def attend(h, layer, cfg: GLMDSAConfig):
+    """The attention half of a layer for one sequence ``h`` [S, d]."""
+    return latent.mla_attention(
+        h, layer, cfg, block_q=BLOCK_Q, scope="block/attn",
+        select=functools.partial(selection, layer["indexer"], cfg))
 
 
 def forward(params, tokens, cfg: GLMDSAConfig):

@@ -68,7 +68,7 @@ import jax.numpy as jnp
 
 from ..ops.kda import kda_chunked
 from . import latent
-from .latent import BLOCK_Q, _mm, _mm_heads, causal_attention_out, mla_qkv
+from .latent import BLOCK_Q, _mm, _mm_heads
 from .transformer import rmsnorm
 
 KDA, MLA = "kda", "mla"
@@ -288,13 +288,8 @@ def kda_mix(h, layer, cfg: KimiLinearConfig):
 def mla_mix(h, layer, cfg: KimiLinearConfig):
     """The latent-attention half of a layer for one sequence ``h``
     [S, d] -> ``h + MLA(RMSNorm(h))``: no query latent, no rotation."""
-    scope = "block/attn/mla"
-    with jax.named_scope(scope):
-        x = rmsnorm(h, layer["attn_norm"], cfg.rms_norm_eps)
-        _, q, k, v = mla_qkv(x, layer["attn"], None, cfg, rope=False)
-    return h + causal_attention_out(
-        q, k, v, layer["attn"]["wo"], block_q=BLOCK_Q, scope=scope,
-        scale=(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5)
+    return latent.mla_attention(h, layer, cfg, block_q=BLOCK_Q,
+                                scope="block/attn/mla", rope=False)
 
 
 def forward(params, tokens, cfg: KimiLinearConfig):

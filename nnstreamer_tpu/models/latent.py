@@ -1,6 +1,7 @@
 """Parts the scoring decoders of this package share, each written once:
 rotary embedding on interleaved pairs and the latent (MLA) projections
-in the expanded form, causal attention with its output projection, a
+in the expanded form, causal attention with its output projection, the
+latent-attention half of a layer that joins them (:func:`mla_attention`), a
 SwiGLU MLP, the sigmoid router, **the expert layer**
 (:func:`routed_experts`: the experts a chip holds through
 ``ops/grouped.py``, whose form the share decides, the router's width
@@ -228,9 +229,9 @@ def causal_attention_out(
     ``v`` may have fewer heads: ``ops/sparse_attention.py``), scores
     times ``scale`` (the caller's, from the head's published size: a
     head may be padded), the result times ``sigmoid(gate)`` [S, H, Dv]
-    where a gate is given, then the output projection ``wo`` [H * v,
-    d]: -> [S, d] in ``q``'s dtype. ``scope`` names the operations in a
-    trace."""
+    (or [S, H, 1]: one a head) where a gate is given, then the output
+    projection ``wo`` [H * v, d]: -> [S, d] in ``q``'s dtype. ``scope``
+    names the operations in a trace."""
     o = blocked_causal_attention(
         q, k, v, scale=scale, block_q=block_q, key_mask=key_mask,
         window=window, scope=scope)
@@ -243,6 +244,38 @@ def causal_attention_out(
         return jnp.einsum("shv,hvd->sd", o, wo,
                           preferred_element_type=jnp.float32
                           ).astype(q.dtype)
+
+
+def mla_attention(h, layer, sizes, *, block_q: int, scope: str,
+                  q_scale: float = 1.0, kv_scale: float = 1.0,
+                  rope: bool = True, select=None,
+                  window: Optional[int] = None):
+    """The latent-attention half of a layer for one sequence ``h`` [S,
+    d]: ``h + MLA(RMSNorm(h))``. :func:`mla_qkv` at ``sizes`` (the
+    configuration, or one layer kind's view of it under the same names)
+    with ``q_scale``, ``kv_scale`` and ``rope``; ``select(x, c_q,
+    positions)``, where given, makes the kernel's ``key_mask`` from the
+    normed input and the query latent (an indexer); ``window`` narrows
+    the keys; a ``wg`` [d, H] beside the projections is a headwise gate,
+    ``sigmoid(x W_g)``, one scalar a head. The norm, the projections,
+    the gate, the kernel and the output projection are named ``scope``.
+    Reads ``sizes.rms_norm_eps``, ``qk_nope_head_dim`` and
+    ``qk_rope_head_dim`` besides what :func:`mla_qkv` reads."""
+    a = layer["attn"]
+    positions = jnp.arange(h.shape[0], dtype=jnp.int32) if rope else None
+    gate = None
+    with jax.named_scope(scope):
+        x = rmsnorm(h, layer["attn_norm"], sizes.rms_norm_eps)
+        c_q, q, k, v = mla_qkv(x, a, positions, sizes, q_scale=q_scale,
+                               kv_scale=kv_scale, rope=rope)
+        if "wg" in a:
+            gate = jnp.dot(x, a["wg"],
+                           preferred_element_type=jnp.float32)[:, :, None]
+    key_mask = None if select is None else select(x, c_q, positions)
+    return h + causal_attention_out(
+        q, k, v, a["wo"], block_q=block_q, key_mask=key_mask,
+        window=window, gate=gate, scope=scope,
+        scale=(sizes.qk_nope_head_dim + sizes.qk_rope_head_dim) ** -0.5)
 
 
 def sigmoid_route(x, moe, top: int, scale: float):

@@ -59,8 +59,7 @@ import jax
 import jax.numpy as jnp
 
 from . import latent
-from .latent import (BLOCK_Q, causal_attention_out, mla_qkv, routed_experts,
-                     swiglu)
+from .latent import BLOCK_Q, routed_experts, swiglu
 from .transformer import rmsnorm
 
 
@@ -176,14 +175,9 @@ init_params = latent.jitted_init(_init_params)
 
 def attend(h, sub, cfg: LongCatConfig):
     """One attention sublayer for one sequence ``h`` [S, d]."""
-    positions = jnp.arange(h.shape[0], dtype=jnp.int32)
-    with jax.named_scope("block/attn"):
-        x = rmsnorm(h, sub["attn_norm"], cfg.rms_norm_eps)
-        _, q, k, v = mla_qkv(x, sub["attn"], positions, cfg,
-                             q_scale=cfg.q_scale, kv_scale=cfg.kv_scale)
-    return h + causal_attention_out(
-        q, k, v, sub["attn"]["wo"], block_q=BLOCK_Q, scope="block/attn",
-        scale=(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5)
+    return latent.mla_attention(h, sub, cfg, block_q=BLOCK_Q,
+                                scope="block/attn", q_scale=cfg.q_scale,
+                                kv_scale=cfg.kv_scale)
 
 
 def route(x, moe, cfg: LongCatConfig):

@@ -32,7 +32,8 @@ not have:
   one (ROADMAP). The same kernel body runs everywhere: compiled by
   Mosaic on a TPU, through the Pallas interpreter elsewhere (how the
   CPU tests run it). :func:`reference_blocked_attention` is the block
-  in plain XLA: the tests' oracle, never a stand-in.
+  in plain XLA: the tests' oracle, never a stand-in;
+  :func:`pairs_walked` counts the pairs the walk multiplies.
 """
 from __future__ import annotations
 
@@ -139,6 +140,44 @@ def _first_tile(row0, window: Optional[int], tk: int):
             else jnp.maximum(behind, 0)) // tk
 
 
+def _walks(lo: int, hi: int, tq: int, tk: int, window: Optional[int]):
+    """The key tiles each query tile of block ``[lo, hi)`` multiplies,
+    as ``(first, last)``: from its window's first (:func:`_first_tile`)
+    to its diagonal, and without a window no further than the block's
+    keys ``[0, hi)`` reach."""
+    walks = [(_first_tile(lo + i * tq, window, tk),
+              (lo + (i + 1) * tq - 1) // tk)
+             for i in range(-(-(hi - lo) // tq))]
+    if window is None:
+        walks = [(start, min(last, -(-hi // tk) - 1))
+                 for start, last in walks]
+    return walks
+
+
+def _tiles(s: int, block_q: int):
+    """The query and key tiles of a sequence of ``s`` in blocks of
+    ``block_q`` (:func:`blocked_causal_attention`)."""
+    tq = max((t for t in range(8, min(block_q, TILE_Q) + 1, 8)
+              if block_q % t == 0), default=0)
+    if not tq:
+        raise ValueError(f"block_q={block_q} has no divisor that is a "
+                         f"multiple of 8 and at most {TILE_Q}")
+    return tq, min(TILE_K, _round_up(s, LANES))
+
+
+def pairs_walked(s: int, *, block_q: int,
+                 window: Optional[int] = None) -> int:
+    """Query-key pairs ``nns_masked_attention`` multiplies for one head
+    over a sequence of ``s`` in blocks of ``block_q`` under a
+    ``window`` (None: causal): every key tile a query tile visits, whole,
+    padding and dropped pairs included. Against the pairs the rule
+    keeps it reads how much of the walk is work."""
+    tq, tk = _tiles(s, block_q)
+    return tq * tk * sum(
+        last - start + 1 for lo in range(0, s, block_q)
+        for start, last in _walks(lo, min(lo + block_q, s), tq, tk, window))
+
+
 def _attention_kernel(*refs, scale: float, lo: int, tq: int, tk: int,
                       masked: bool, window: Optional[int]):
     """Grid ``(head group, query tile, key tile)``, the key tiles in
@@ -233,13 +272,10 @@ def _attend_block(q, k, v, keep, out, *, lo: int, hi: int, tq: int, tk: int,
     group = max(g for g in range(1, HEADS_PER_STEP + 1) if heads % g == 0
                 and (shared == 1 or shared % g == 0))
     kv_group = group if shared == 1 else 1
-    nq, nk, first = -(-(hi - lo) // tq), -(-hi // tk), lo // tq
-    if window is not None:
-        # key tiles a query tile walks, from its window's first to its
-        # diagonal: the grid holds the longest walk and no step before it
-        nk = max((lo + (i + 1) * tq - 1) // tk
-                 - _first_tile(lo + i * tq, window, tk) + 1
-                 for i in range(nq))
+    walks = _walks(lo, hi, tq, tk, window)
+    # the grid holds the longest walk and no step before it
+    nq, first = len(walks), lo // tq
+    nk = max(last - start + 1 for start, last in walks)
 
     def query_tile(h, i, j):
         return h, first + i, 0
@@ -313,12 +349,7 @@ def blocked_causal_attention(
                          f"and {v.shape[1]} value heads")
     if window is not None and window < 1:
         raise ValueError(f"window={window} keeps no key")
-    tq = max((t for t in range(8, min(block_q, TILE_Q) + 1, 8)
-              if block_q % t == 0), default=0)
-    if not tq:
-        raise ValueError(f"block_q={block_q} has no divisor that is a "
-                         f"multiple of 8 and at most {TILE_Q}")
-    tk = min(TILE_K, _round_up(s, LANES))
+    tq, tk = _tiles(s, block_q)
     rows = _round_up(s, math.lcm(tq, tk))
     interpret = jax.default_backend() != "tpu"
 

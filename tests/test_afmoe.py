@@ -172,9 +172,11 @@ def test_the_decoders_share_their_parts():
     assert afmoe.sigmoid_route is latent.sigmoid_route
     assert afmoe.routed_experts is longcat.routed_experts \
         is latent.routed_experts
-    for name in ("causal_attention_out", "rmsnorm"):
-        assert getattr(afmoe, name) is getattr(glm_dsa, name) \
-            is getattr(longcat, name), name
+    assert afmoe.causal_attention_out is latent.causal_attention_out
+    assert afmoe.rmsnorm is longcat.rmsnorm is latent.rmsnorm
+    # the latent decoders' attention half is latent.mla_attention
+    for module in (glm_dsa, longcat):
+        assert not hasattr(module, "causal_attention_out"), module
     assert afmoe.swiglu is longcat.swiglu is latent.swiglu
     for module in (afmoe, glm_dsa, longcat):
         assert not hasattr(module, "grouped_swiglu") \

@@ -141,8 +141,8 @@ def test_sparse_regime_selects_the_reference_set(seed):
     h = jax.random.normal(jax.random.PRNGKey(seed + 5),
                           (SEQ, cfg.hidden_size), jnp.float32)
     pos = jnp.arange(SEQ)
-    x = glm_dsa.rmsnorm(h, layer["attn_norm"], cfg.rms_norm_eps)
-    c_q, _, _, _ = glm_dsa.mla_qkv(x, layer["attn"], pos, cfg)
+    x = latent.rmsnorm(h, layer["attn_norm"], cfg.rms_norm_eps)
+    c_q, _, _, _ = latent.mla_qkv(x, layer["attn"], pos, cfg)
     q_i, k_i, w = glm_dsa.indexer_qkw(x, c_q, layer["indexer"], pos, cfg)
     scores = glm_dsa.index_scores(q_i, k_i, w)
     causal = np.tril(np.ones((SEQ, SEQ), bool))
@@ -163,12 +163,12 @@ def test_mla_is_per_head_attention_over_expanded_keys(block_q):
     a = glm_dsa.init_params(cfg, jax.random.PRNGKey(3))["layers"][0]["attn"]
     x = jax.random.normal(jax.random.PRNGKey(4), (SEQ, cfg.hidden_size))
     pos = jnp.arange(SEQ)
-    _, q, k, v = glm_dsa.mla_qkv(x, a, pos, cfg)
+    _, q, k, v = latent.mla_qkv(x, a, pos, cfg)
     got = blocked_causal_attention(q, k, v, scale=16 ** -0.5,
                                    block_q=block_q)
     nope, rp, vd, r = 12, 4, 16, cfg.kv_lora_rank
     kv = x @ a["wkv_a"]
-    c_kv = glm_dsa.rmsnorm(kv[:, :r], a["kv_norm"], cfg.rms_norm_eps)
+    c_kv = latent.rmsnorm(kv[:, :r], a["kv_norm"], cfg.rms_norm_eps)
     k_r = glm_dsa.rope_interleaved(kv[:, r:], pos, cfg.rope_theta)
     wkv_b = a["wkv_b"].reshape(r, cfg.num_attention_heads, nope + vd)
     causal = np.tril(np.ones((SEQ, SEQ), bool))
@@ -257,7 +257,7 @@ def test_the_shares_add_up(seed):
 
     whole, load = ffn(layer, full)
     shared = latent.swiglu(
-        glm_dsa.rmsnorm(h, layer["ffn_norm"], full.rms_norm_eps),
+        latent.rmsnorm(h, layer["ffn_norm"], full.rms_norm_eps),
         layer["moe"]["shared"])
     total, loads = h + shared, []
     for rank in range(4):

@@ -199,14 +199,16 @@ def test_the_decoders_share_their_parts():
     projection, one expert layer, one scoring head, one set of latent
     projections; neither this decoder nor GLM-5 names the grouped
     product's parts."""
-    for name in ("causal_attention_out", "rmsnorm"):
-        assert getattr(kimi_linear, name) is getattr(glm_dsa, name) \
-            is getattr(afmoe, name), name
+    assert kimi_linear.rmsnorm is afmoe.rmsnorm is latent.rmsnorm
+    assert afmoe.causal_attention_out is latent.causal_attention_out
     assert afmoe.routed_experts is latent.routed_experts
     for module in (kimi_linear, glm_dsa):
         assert not hasattr(module, "grouped_swiglu") \
             and not hasattr(module, "group_by_expert"), module
-    assert kimi_linear.mla_qkv is glm_dsa.mla_qkv is latent.mla_qkv
+    # the latent-attention half is latent.mla_attention, for both
+    for module in (kimi_linear, glm_dsa):
+        assert not hasattr(module, "mla_qkv") \
+            and not hasattr(module, "causal_attention_out"), module
 
 
 def test_config_reads_the_published_keys():

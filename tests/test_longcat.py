@@ -269,10 +269,14 @@ def test_bfloat16_scale_is_not_rounded_to_the_stream():
 def test_both_decoders_share_the_latent_parts():
     """One copy of the latent projections, the rotations, the attention
     half and the expert layer: ``models/glm_dsa.py`` and
-    ``models/longcat.py`` name the same function objects, and neither
+    ``models/longcat.py`` go through the same functions
+    (``latent.mla_attention`` for the attention half), and neither
     the grouped product's parts."""
-    for name in ("mla_qkv", "causal_attention_out", "rmsnorm"):
-        assert getattr(glm_dsa, name) is getattr(longcat, name), name
+    # the attention half is latent.mla_attention, for both
+    for module in (glm_dsa, longcat):
+        assert not hasattr(module, "mla_qkv") \
+            and not hasattr(module, "causal_attention_out"), module
+    assert longcat.rmsnorm is latent.rmsnorm
     assert longcat.routed_experts is latent.routed_experts
     assert longcat.swiglu is latent.swiglu
     for module in (glm_dsa, longcat):
@@ -354,11 +358,14 @@ DECODERS = {
 }
 
 
-def _plain_mla_qkv(x, a, positions, cfg, *, q_scale=1.0, kv_scale=1.0):
+def _plain_mla_qkv(x, a, positions, cfg, *, q_scale=1.0, kv_scale=1.0,
+                   rope=True):
     """The latent projections the plainest way, a head's columns as
     published: ``[nope | rope]`` concatenated and nothing padded, the
     weights multiplied as they are loaded. What ``latent.mla_qkv``
-    returned before it padded a head."""
+    returned before it padded a head (with its rotation: both decoders
+    here rotate)."""
+    assert rope
     h, nope = cfg.num_attention_heads, cfg.qk_nope_head_dim
     rp, r = cfg.qk_rope_head_dim, cfg.kv_lora_rank
     c_q = latent._scaled(latent.rmsnorm(
@@ -443,7 +450,7 @@ def test_forward_is_the_unpadded_forward(monkeypatch, name, seed):
         return [np.asarray(t) for t in out]
 
     got = run()
-    monkeypatch.setattr(model, "mla_qkv", _plain_mla_qkv)
+    monkeypatch.setattr(latent, "mla_qkv", _plain_mla_qkv)
     want = run()
     spread = float(np.ptp(want[0]))
     np.testing.assert_allclose(got[0], want[0], atol=1e-6 * max(spread, 1))
@@ -483,6 +490,6 @@ def test_no_activation_is_padded_to_the_lane_width(monkeypatch, name):
                 and e.outvars[0].aval.shape[2] > e.invars[0].aval.shape[2]]
 
     assert widened() == []
-    monkeypatch.setattr(model, "mla_qkv", _plain_mla_qkv)
+    monkeypatch.setattr(latent, "mla_qkv", _plain_mla_qkv)
     attentions = 4 if name == "longcat" else cfg.num_hidden_layers
     assert len(widened()) == 2 * attentions

@@ -179,3 +179,54 @@ def test_block_without_a_query_tile_raises():
     x = jnp.zeros((24, 1, 8))
     with pytest.raises(ValueError, match="block_q=12"):
         blocked_causal_attention(x, x, x, scale=1.0, block_q=12)
+
+
+@pytest.mark.parametrize("s,window", [(1536, 513), (3072, 2048)],
+                         ids=["window_513", "window_2048"])
+def test_window_walk_at_the_models_tiles_is_dense_masked_attention(s,
+                                                                   window):
+    """The window walk at the tiles the models pass (512 queries, 512
+    keys) against softmax attention written out over the whole ``[S,
+    S]`` mask: a window of one tile plus one key (513: each query tile
+    walks its own tile and the one before, both masked) and one of four
+    tiles (2048: the walk's middle tiles unmasked), latent heads of
+    ``Dk`` 256 and ``Dv`` 128."""
+    assert (sparse_attention.TILE_Q, sparse_attention.TILE_K) == (512, 512)
+    rng = np.random.default_rng(window)
+    q, k = (jnp.asarray(rng.standard_normal((s, 2, 256)), jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rng.standard_normal((s, 2, 128)), jnp.float32)
+    got = blocked_causal_attention(q, k, v, scale=0.1, block_q=512,
+                                   window=window)
+    ahead = np.arange(s)[:, None] - np.arange(s)[None, :]
+    keep = (ahead >= 0) & (ahead < window)
+    scores = jnp.einsum("qhd,khd->hqk", q, k,
+                        precision=jax.lax.Precision.HIGHEST) * 0.1
+    probs = jax.nn.softmax(jnp.where(keep[None], scores, -jnp.inf), -1)
+    want = jnp.einsum("hqk,khd->qhd", probs, v,
+                      precision=jax.lax.Precision.HIGHEST)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    # the window is live: the last query loses keys to it
+    causal = jax.nn.softmax(jnp.where((ahead >= 0)[None], scores, -jnp.inf),
+                            -1)
+    assert float(jnp.abs(causal[:, -1] - probs[:, -1]).max()) > 1e-3
+
+
+@pytest.mark.parametrize("s,block_q,window,tiles", [
+    # query tile i walks key tiles i - 1 and i, tile 0 its own alone
+    (1536, 512, 513, 1 + 2 + 2),
+    (8192, 512, 513, 1 + 15 * 2),
+    # tiles 1, 2, 3, 4, then 5 each: a window of four tiles reaches back
+    # four and walks the diagonal
+    (3072, 512, 2048, 1 + 2 + 3 + 4 + 5 + 5),
+    # causal: every tile up to the diagonal
+    (1536, 512, None, 1 + 2 + 3),
+    # a window of under a tile: the tile before while the window reaches
+    # back into it, which it does from every tile's first query
+    (1536, 512, 40, 1 + 2 + 2),
+])
+def test_pairs_walked_counts_the_visited_tiles(s, block_q, window, tiles):
+    """``pairs_walked``: whole 512 x 512 tiles, as the kernel's grid
+    visits them, counted by hand."""
+    assert sparse_attention.pairs_walked(s, block_q=block_q,
+                                         window=window) == tiles * 512 * 512
