@@ -11,9 +11,9 @@ The models' kernels live beside them and are imported by their modules:
 ``sparse_attention`` (``nns_masked_attention``: causal, masked and
 windowed attention a block of queries at a time), ``grouped`` (the
 routed experts' product: the kernel ``nns_grouped_swiglu`` where a chip
-holds at least half of a router, ``2 x held >= router width``, 9.4 ms a
-layer against the tile loops' 12.1 at a half on the chip, PR 38; the
-tile loops for any smaller share), ``kda`` (``nns_kda_chunk_intra``
+holds at least half of a router, ``2 x held >= router width``; for any
+smaller share the split form, ``nns_grouped_gate_up`` then
+``nns_grouped_down``), ``kda`` (``nns_kda_chunk_intra``
 / ``nns_kda_chunk_state``: the chunked gated delta rule, a state
 carried from chunk to chunk) and ``power_retention``
 (``nns_power_retention``: the chunked gated power retention of degree

@@ -255,8 +255,12 @@ def test_pipeline_gives_the_direct_calls_three_tensors(window):
     got = [[np.asarray(c.host()) for c in b.chunks] for b in p["out"].buffers]
     report = p["f"].transfer_report()
     p.stop()
-    # 4 layers (three window, one full) x 4 blocks of 16 queries
-    assert report["kernel_calls"] == {"nns_masked_attention": 16}
+    # 4 layers (three window, one full) x 4 blocks of 16 queries; 4 of
+    # 16 experts held: the split form's two kernels twice in each of the
+    # 3 expert layers (ops/grouped.py::takes_split)
+    assert report["kernel_calls"] == {"nns_masked_attention": 16,
+                                      "nns_grouped_gate_up": 6,
+                                      "nns_grouped_down": 6}
     assert report.get("prepared_leaves", 0) == 0
     # a layer's four projections reshaped and transposed a head at a
     # time, and a router bias's conversion an expert layer

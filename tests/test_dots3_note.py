@@ -277,8 +277,12 @@ def test_pipeline_gives_the_direct_calls_three_tensors():
     got = [[np.asarray(c.host()) for c in b.chunks] for b in p["out"].buffers]
     report = p["f"].transfer_report()
     p.stop()
-    # 5 layers x 4 blocks of 16 queries (the fixture's BLOCK_Q)
-    assert report["kernel_calls"] == {"nns_masked_attention": 20}
+    # 5 layers x 4 blocks of 16 queries (the fixture's BLOCK_Q); 8 of 32
+    # experts held: the split form's two kernels twice in each of the 4
+    # expert layers (ops/grouped.py::takes_split)
+    assert report["kernel_calls"] == {"nns_masked_attention": 20,
+                                      "nns_grouped_gate_up": 8,
+                                      "nns_grouped_down": 8}
     assert report.get("prepared_leaves", 0) == 0
     assert len(got) == 3
     for g, w in zip(got, want):

@@ -754,6 +754,53 @@ def test_mosaic_keeps_the_experts_sum_in_vmem(topology, monkeypatch, t, d,
     assert f"f32[{t},{d}]" in text and f"f32[{t * 8},{d}]" not in text
 
 
+@pytest.mark.parametrize("t,d,f,k,held,router,fb,dc", [
+    (8192, 5120, 1536, 8, 32, 256, 1536, 1280),
+    (4096, 6144, 2048, 8, 16, 256, 2048, 2048)], ids=["dots3", "glm5"])
+def test_mosaic_takes_the_split_form_at_the_cells_widths(
+        topology, monkeypatch, t, d, f, k, held, router, fb, dc):
+    """The expert layer under half a router at the dots3 and GLM-5
+    cells' widths (experts of ``d`` x ``f``, ``k`` a token, 32 of 256
+    and 16 of 256 held, tiles of 256, bfloat16): Mosaic lays out
+    ``nns_grouped_gate_up`` with ``w1`` and ``w3`` whole (``fb``
+    columns) twice over beside a tile of rows, and ``nns_grouped_down``
+    with a ``[t, dc]`` float32 block of the sum twice over beside two
+    blocks of ``w2``, each within the VMEM it asks for; twice each, the
+    first pass and the loop over the passes after it. The program
+    gathers no row for every pair of the router (``[t x k, d]``) and
+    holds the sum whole only as the second kernel's output."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from nnstreamer_tpu.ops import grouped
+    dev = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name=topology).devices[0])
+    assert grouped._split_blocks(t, d, f, 256, 2) == (fb, dc)
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    def experts(x, choice, weight, w1, w3, w2):
+        order, counts = grouped.group_by_expert(choice, 0, held)
+        return grouped.grouped_swiglu(x, order, counts, weight, w1, w3, w2,
+                                      tile=256, router=router)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        with jax.default_matmul_precision("default"):
+            text = jax.jit(experts).lower(
+                spec((t, d)), spec((t, k), jnp.int32),
+                spec((t, k), jnp.float32), spec((held, d, f)),
+                spec((held, d, f)), spec((held, f, d))).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert text.count("nns_grouped_gate_up") >= 2 \
+        and text.count("nns_grouped_down") >= 2
+    assert "nns_grouped_swiglu" not in text
+    assert f"bf16[{t * k},{d}]" not in text and f"f32[{t * k},{d}]" not in text
+
+
 def test_mosaic_takes_the_power_retention_at_the_cells_widths(
         topology, monkeypatch):
     """The TPU's compiler lays out ``ops/power_retention.py``'s kernel

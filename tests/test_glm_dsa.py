@@ -313,14 +313,18 @@ def test_pipeline_gives_the_direct_calls_three_tensors(window):
 
 
 @pytest.mark.parametrize("uri,dims,calls,equations", [
-    # 3 layers x 4 blocks of 16 queries (the fixture's BLOCK_Q); an
-    # attention's 2 reshapes, 2 slices, 2 pads and 3 transposes
-    # (latent.mla_weights), an expert layer's router bias to float32
+    # 3 layers x 4 blocks of 16 queries (the fixture's BLOCK_Q); a
+    # quarter of the router held: the split form's two kernels twice
+    # an expert layer (ops/grouped.py::takes_split); an attention's 2
+    # reshapes, 2 slices, 2 pads and 3 transposes (latent.mla_weights),
+    # an expert layer's router bias to float32
     ("zoo://glm_dsa?seq=64&held_first=8&held_count=8",
-     ("int32", "64"), {"nns_masked_attention": 12}, 3 * 9 + 2),
+     ("int32", "64"), {"nns_masked_attention": 12, "nns_grouped_gate_up": 4,
+                       "nns_grouped_down": 4}, 3 * 9 + 2),
     # in float32 the bias is used as it is loaded
     ("zoo://glm_dsa?seq=64&held_first=8&held_count=8&dtype=float32",
-     ("int32", "64"), {"nns_masked_attention": 12}, 3 * 9),
+     ("int32", "64"), {"nns_masked_attention": 12, "nns_grouped_gate_up": 4,
+                       "nns_grouped_down": 4}, 3 * 9),
     ("zoo://mlp", ("float32", "64:4"), {}, None),
     # every expert of the router held: the share takes the grouped kernel,
     # once an expert layer (ops/grouped.py::takes_kernel)

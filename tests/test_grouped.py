@@ -16,15 +16,17 @@ from nnstreamer_tpu.filters import prepare
 from nnstreamer_tpu.models import zoo
 from nnstreamer_tpu.ops import grouped
 from nnstreamer_tpu.ops.grouped import (group_by_expert, grouped_swiglu,
-                                        takes_kernel)
+                                        takes_kernel, takes_split)
 
 
 @pytest.mark.parametrize("tile", [1, 8, 64])
 @pytest.mark.parametrize("held_first,held", [(0, 6), (3, 2), (0, 1)])
 def test_grouped_swiglu_is_exact(tile, held_first, held):
-    """Against every expert applied to every token and masked: no pair
-    of a held expert dropped, none of another expert served, an expert
-    nobody chose costs nothing and breaks nothing."""
+    """The split form (by name, at any share: 6, 2 and 1 of a router of
+    7, the smaller two in more than one pass) against every expert
+    applied to every token and masked: no pair of a held expert
+    dropped, none of another expert served, an expert nobody chose
+    costs nothing and breaks nothing."""
     rng = np.random.default_rng(tile + held)
     t, d, f, k, router = 40, 16, 24, 3, 7          # expert 6 is never chosen
     x = rng.standard_normal((t, d)).astype(np.float32)
@@ -36,7 +38,7 @@ def test_grouped_swiglu_is_exact(tile, held_first, held):
     w2 = rng.standard_normal((router, f, d)).astype(np.float32)
     sl = slice(held_first, held_first + held)
     order, counts = group_by_expert(jnp.asarray(choice), held_first, held)
-    got = jax.jit(lambda *a: grouped._by_loops(*a, tile=tile))(
+    got = jax.jit(lambda *a: grouped._by_split(*a, tile=tile, router=router))(
         x, order, counts, weight, w1[sl], w3[sl], w2[sl])
     want = np.zeros((t, d))
     for e in range(held_first, held_first + held):
@@ -71,7 +73,7 @@ def _pairs_per_expert(routing, rng, pairs, tile, router):
     return counts
 
 
-@pytest.mark.parametrize("whole", [True, False], ids=["sorted", "loops"])
+@pytest.mark.parametrize("form", ["sorted", "split_one_pass", "split"])
 @pytest.mark.parametrize("tile,dtype,tol", [(8, jnp.float32, 1e-3),
                                             (32, jnp.float32, 1e-3),
                                             (16, jnp.bfloat16, 0.15)])
@@ -79,14 +81,16 @@ def _pairs_per_expert(routing, rng, pairs, tile, router):
     "gap_middle", "gap_first", "gap_last", "many_tiles", "off_tile",
     "one_expert", "ragged_end"])
 def test_grouped_swiglu_holds_the_whole_router(routing, tile, dtype, tol,
-                                               whole):
+                                               form):
     """``held_count`` equal to the router's width: every one of the ``T
     x K`` pairs is served here, none goes to the tail, and the result is
     the dense form's (every expert over every token, weighted by the
-    token's weight for it or 0), from the tile loops (by name) and
-    from the kernel's form (the whole router held: the rows gathered
-    once, ``nns_grouped_swiglu`` through the interpreter) alike. The
-    routings are those a walk over row tiles can get wrong: experts
+    token's weight for it or 0), from the kernel's form (the whole
+    router held: the rows gathered once, ``nns_grouped_swiglu`` through
+    the interpreter) and from the split form (by name: in one pass, and
+    with its passes sized as if the experts were a quarter of the
+    router, so that every pair held here takes two) alike.
+    The routings are those a walk over row tiles can get wrong: experts
     that serve nobody first, last and two in the middle, an
     expert over more than three tiles, every boundary inside a tile,
     all pairs on one expert, ``T x K`` no multiple of the tile."""
@@ -103,8 +107,14 @@ def test_grouped_swiglu_holds_the_whole_router(routing, tile, dtype, tol,
     order, counts = group_by_expert(jnp.asarray(choice), 0, router)
     assert counts.tolist() == counts_want.tolist()
     assert sorted(np.asarray(order).tolist()) == list(range(t * k))
-    form = functools.partial(grouped_swiglu, router=router) if whole \
-        else grouped._by_loops
+    if form == "split":
+        assert grouped._pass_pairs(t * k, router, 4 * router, tile, 8) \
+            == t * k // 2
+    form = {"sorted": functools.partial(grouped_swiglu, router=router),
+            "split_one_pass": functools.partial(grouped._by_split,
+                                                router=router),
+            "split": functools.partial(grouped._by_split,
+                                       router=4 * router)}[form]
     got = jax.jit(lambda *a: form(*a, tile=tile))(
         *(jnp.asarray(a, dtype) for a in (x,)), order, counts, weight,
         *(jnp.asarray(a, dtype) for a in (w1, w3, w2)))
@@ -256,7 +266,7 @@ def test_grouped_kernel_reads_no_row_of_a_tiles_tail():
     result is what it was, bit for bit, though the tiles hold the NaN
     row many times over; on the routings whose last tiles fall at half
     a tile, one row over, one row, and inside one half tile, the kernel
-    gives the tile loops' result bit for bit."""
+    gives the split form's result bit for bit."""
     from nnstreamer_tpu.ops.grouped import half_tiles, tiles_walked
     tile = 8
     cases = [(None, None)] + list(_HALF_TILE_ROUTINGS.values())
@@ -276,9 +286,9 @@ def test_grouped_kernel_reads_no_row_of_a_tiles_tail():
         dirty = np.asarray(run(x.at[0].set(jnp.nan), order, counts, *rest))
         assert np.isnan(dirty[0]).all() and np.isfinite(clean).all()
         np.testing.assert_array_equal(dirty[1:], clean[1:])
-        loops = jax.jit(lambda *a: grouped._by_loops(*a, tile=tile))(
-            x, order, counts, *rest)
-        np.testing.assert_array_equal(clean, np.asarray(loops))
+        split = jax.jit(lambda *a: grouped._by_split(
+            *a, tile=tile, router=router))(x, order, counts, *rest)
+        np.testing.assert_array_equal(clean, np.asarray(split))
 
 
 @pytest.mark.parametrize("counts", [
@@ -294,8 +304,8 @@ def test_grouped_kernel_fetches_the_experts_in_the_walks_order(
     ``_walk``'s steps meet them, each once and none that serves nobody,
     and the kernel run as the chip runs it (Pallas' TPU interpreter: a
     copy lands only when it is waited for, memory nobody wrote reads
-    NaN, races are looked for) gives the tile loops' result bit for bit
-    at every depth of the ring."""
+    NaN, races are looked for) gives the split form's result bit for
+    bit at every depth of the ring."""
     from jax._src.pallas.mosaic.interpret import interpret_pallas_call
     from jax.experimental.pallas import tpu as pltpu
     tile = 8
@@ -319,8 +329,9 @@ def test_grouped_kernel_fetches_the_experts_in_the_walks_order(
             *a, tile=tile, router=len(counts)))(*args)
         got = np.asarray(got)
     assert not interpret_pallas_call.races.races_found
-    loops = jax.jit(lambda *a: grouped._by_loops(*a, tile=tile))(*args)
-    np.testing.assert_array_equal(got, np.asarray(loops))
+    split = jax.jit(lambda *a: grouped._by_split(
+        *a, tile=tile, router=len(counts)))(*args)
+    np.testing.assert_array_equal(got, np.asarray(split))
 
 
 @pytest.mark.parametrize("t,d,depth,most", [
@@ -355,7 +366,7 @@ def _choice(case, rng, t, k, tile, held_first, held, router):
         return np.full((t, k), held_first + 1)
     if case == "half_full":
         # k == 1; last tiles half full to the row, one row over, whole,
-        # and a single row: both sides of where the two loops part
+        # and a single row: both sides of where a step takes half a tile
         half = tile // 2
         pairs = [2 * tile + half, tile + half + 1, tile, 1]
         ids = np.repeat(inside, pairs)
@@ -401,16 +412,16 @@ def _choice(case, rng, t, k, tile, held_first, held, router):
     ("kernel_bfloat16_half_tiles", 96, 1, 16, jnp.bfloat16, 2e-2),
 ])
 def test_grouped_swiglu_serves_every_routing(case, t, k, tile, dtype, tol):
-    """Both tile loops (whole tiles, and last tiles at half the rows:
+    """The split form (whole tiles, and last tiles at half the rows:
     4 of a router of 9 held) against each pair computed alone: an expert of
     several tiles, one that serves nobody between two that do, every
     pair on one expert, no pair of a held expert (exactly 0), last
     tiles on both sides of half full, an odd tile, bfloat16. The kernel
     (``kernel_``: 4 of a router of 8 held) on last tiles at half full,
     a row over, one row, and experts whose every row lies in one half
-    tile: against each pair alone, and the tile loops' result bit for
-    bit (a token's one pair, its products on a tile or a half tile in
-    both)."""
+    tile: against each pair alone, and the split form's result (by
+    name) bit for bit (a token's one pair, its products on a tile or a
+    half tile in both)."""
     kernel = case.startswith("kernel_")
     held_first, held, router, d, f = 2, 4, 8 if kernel else 9, 16, 24
     rng = np.random.default_rng(len(case))
@@ -437,9 +448,10 @@ def test_grouped_swiglu_serves_every_routing(case, t, k, tile, dtype, tol):
     got = jax.jit(lambda *a: grouped_swiglu(*a, tile=tile, router=router))(
         x, order, counts, weight, w1, w3, w2)
     if kernel:
-        loops = jax.jit(lambda *a: grouped._by_loops(*a, tile=tile))(
-            x, order, counts, weight, w1, w3, w2)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(loops))
+        split = jax.jit(lambda *a: grouped._by_split(
+            *a, tile=tile, router=router))(
+                x, order, counts, weight, w1, w3, w2)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(split))
     assert got.shape == (t, d) and got.dtype == jnp.float32
     want = np.zeros((t, d), np.float32)
     for tok, slot in np.argwhere((choice >= held_first)
@@ -491,7 +503,7 @@ def test_the_whole_router_adds_each_pair_once(case, dtype, tol):
     0's run ending inside a tile with expert 1's rows behind it (not
     added), the last expert's tile reaching the slack slots that hold
     pair 0 (token 0's, which the last expert serves: counted once).
-    Against the dense form and the tile loops."""
+    Against the dense form and the split form (by name)."""
     rng = np.random.default_rng(41)
     t, d, f, k, router, tile = 48, 16, 24, 4, 8, 32
     choice = _whole_router_choice(case, rng, t, k, router, tile)
@@ -506,7 +518,8 @@ def test_the_whole_router_adds_each_pair_once(case, dtype, tol):
             *(jnp.asarray(w, dtype) for w in (w1, w3, w2)))
     got = jax.jit(lambda *a: grouped_swiglu(*a, tile=tile, router=router))(
         *args)
-    loops = jax.jit(lambda *a: grouped._by_loops(*a, tile=tile))(*args)
+    split = jax.jit(lambda *a: grouped._by_split(
+        *a, tile=tile, router=router))(*args)
     dense = np.zeros((t, router), np.float32)
     np.add.at(dense, (np.arange(t)[:, None], choice), weight)
     every = jnp.einsum("tef,efd->ted", jax.nn.silu(
@@ -515,7 +528,7 @@ def test_the_whole_router_adds_each_pair_once(case, dtype, tol):
     want = jnp.einsum("ted,te->td", every, dense)
     atol = tol * (1 + 9 * (dtype == jnp.bfloat16))
     np.testing.assert_allclose(got, want, atol=atol, rtol=tol)
-    np.testing.assert_allclose(got, loops, atol=atol, rtol=tol)
+    np.testing.assert_allclose(got, split, atol=atol, rtol=tol)
 
 
 def _routing(case, rng, t, k, held_first, held, router):
@@ -557,7 +570,7 @@ def test_the_kernels_path_serves_a_share(monkeypatch, case, held_first,
     of the experts held elsewhere behind the last held expert's, where
     the kernel's walk never begins a step, and on the way out they
     count as nothing: the kernel adds each served pair, weighted, into
-    its token's row, and no other row. Against the tile loops (by
+    its token's row, and no other row. Against the split form (by
     name, on the same arguments) and against the dense form (every
     held expert over every token, weighted by the token's weight for it
     or 0). The way out's edges: a token whose pairs are all here, one
@@ -570,9 +583,6 @@ def test_the_kernels_path_serves_a_share(monkeypatch, case, held_first,
     rng = np.random.default_rng(3)
     t, d, f, k, router, held, tile = 96, 16, 24, 4, 16, 8, 32
     assert takes_kernel(held, router)
-    if case == "runs_of_tokens":
-        monkeypatch.setattr(grouped, "_VMEM", grouped._vmem_asked(
-            40, d, f, tile, jnp.dtype(dtype).itemsize))
     x = rng.standard_normal((t, d)).astype(np.float32)
     choice = _routing(case, rng, t, k, held_first, held, router
                       ).astype(np.int32)
@@ -597,12 +607,16 @@ def test_the_kernels_path_serves_a_share(monkeypatch, case, held_first,
         assert int(counts.sum()) == t * k and int(counts[-1]) % tile
     args = (jnp.asarray(x, dtype), order, counts, weight,
             *(jnp.asarray(w, dtype) for w in (w1, w3, w2)))
+    split = jax.jit(lambda *a: grouped._by_split(
+        *a, tile=tile, router=router))(*args)
+    if case == "runs_of_tokens":
+        monkeypatch.setattr(grouped, "_VMEM", grouped._vmem_asked(
+            40, d, f, tile, jnp.dtype(dtype).itemsize))
     got = jax.jit(lambda *a: grouped_swiglu(*a, tile=tile, router=router))(
         *args)
     assert _kernel_calls(lambda *a: grouped_swiglu(
         *a, tile=tile, router=router), *args) \
         == (3 if case == "runs_of_tokens" else 1)
-    loops = jax.jit(lambda *a: grouped._by_loops(*a, tile=tile))(*args)
     assert got.dtype == jnp.float32
     dense = np.zeros((t, router), np.float32)
     np.add.at(dense, (np.arange(t)[:, None], choice), weight)
@@ -613,9 +627,103 @@ def test_the_kernels_path_serves_a_share(monkeypatch, case, held_first,
     want = jnp.einsum("ted,te->td", every, dense)
     atol = tol * (1 + 9 * (dtype == jnp.bfloat16))
     np.testing.assert_allclose(got, want, atol=atol, rtol=tol)
-    np.testing.assert_allclose(got, loops, atol=atol, rtol=tol)
+    np.testing.assert_allclose(got, split, atol=atol, rtol=tol)
     if case == "a_token_none_here":
         assert not np.asarray(got[5]).any()
+
+
+def _eighth(case, rng, t, k, tile, held, router):
+    """``[t, k]`` expert ids, a token's all different, for the first
+    ``held`` of ``router`` experts held (an eighth of it), for the edges
+    of the split form (below)."""
+    inside, outside = np.arange(held), np.arange(held, router)
+    if case == "every_pair_here":
+        return np.stack([rng.permutation(inside)[:k] for _ in range(t)])
+    if case == "one_expert":
+        # every pair of a held expert on expert 1
+        return np.stack([np.r_[1, rng.permutation(outside)[:k - 1]]
+                         for _ in range(t)])
+    if case in ("tail_at_half", "tail_over_half"):
+        # k == 1; an expert of two tiles and a half, one of a tile and
+        # a half (and one row over), one whole tile, one single row
+        half = tile // 2 + (case == "tail_over_half")
+        ids = np.repeat(inside[:4], [2 * tile + half, tile + half, tile, 1])
+        ids = np.concatenate([ids, rng.choice(outside, t - len(ids))])
+        return rng.permutation(ids)[:, None]
+    bias = {"skewed": np.r_[2.0, np.zeros(router - 1)]}.get(
+        case, np.zeros(router))
+    choice = np.argsort(-(rng.gumbel(size=(t, router)) + bias), axis=1)[:, :k]
+    if case == "an_expert_nobody_chose":
+        # expert 2 chosen by nobody: a token's pick of it moved to the
+        # next free expert held elsewhere
+        for row in choice:
+            if 2 in row:
+                row[row == 2] = np.setdiff1d(outside, row)[0]
+    return choice
+
+
+@pytest.mark.parametrize("case", [
+    "even", "skewed", "an_expert_nobody_chose", "tail_at_half",
+    "tail_over_half", "one_expert", "every_pair_here"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+def test_the_split_form_serves_an_eighth(case, dtype, tol):
+    """4 of a router of 32 held, as dots3's 32 of 256: ``grouped_swiglu``
+    takes the split form (``nns_grouped_gate_up``, ``nns_grouped_down``
+    through the interpreter) and serves every pair of a held expert,
+    against each pair computed alone in float32 with ``silu(gate) * up``
+    rounded once to the operands' dtype, and against the whole-router
+    kernel's form by name on the same arguments (the same products and
+    adds in the same order: to the bit). The routings: even, one expert
+    far the busiest, an expert nobody chose, last tiles at half a tile
+    and one row over, every pair on one expert, and every pair held
+    here, four times what one pass holds: four passes, none dropped."""
+    rng = np.random.default_rng(len(case))
+    tile, held, router, d, f = 8, 4, 32, 16, 24
+    t, k = (192, 1) if case.startswith("tail") else (64, 4)
+    choice = _eighth(case, rng, t, k, tile, held, router).astype(np.int32)
+    x = jnp.asarray(rng.standard_normal((t, d)), dtype)
+    weight = jnp.asarray(rng.random((t, k)), jnp.float32)
+    w1, w3 = (jnp.asarray(rng.standard_normal((held, d, f)) * d ** -0.5,
+                          dtype) for _ in range(2))
+    w2 = jnp.asarray(rng.standard_normal((held, f, d)) * f ** -0.5, dtype)
+    order, counts = group_by_expert(jnp.asarray(choice), 0, held)
+    n = grouped._pass_pairs(t * k, held, router, tile, 8)
+    served = np.asarray(counts)
+    expected = {
+        "skewed": served[0] > 4 * served[1:].max(),
+        "an_expert_nobody_chose": served[2] == 0 and served.all(where=[
+            True, True, False, True]),
+        "tail_at_half": (served % tile == [tile // 2, tile // 2, 0, 1]).all(),
+        "tail_over_half": (served % tile
+                           == [tile // 2 + 1, tile // 2 + 1, 0, 1]).all(),
+        "one_expert": served.tolist() == [0, t, 0, 0],
+        "every_pair_here": served.sum() == t * k == 4 * n}.get(case, True)
+    assert expected, served
+    assert served.sum() <= n or case == "every_pair_here"
+    assert takes_split(held, router)
+    args = (x, order, counts, weight, w1, w3, w2)
+    run = jax.jit(lambda *a: grouped_swiglu(*a, tile=tile, router=router))
+    got = np.asarray(run(*args))
+    # the first pass's two kernels, and the loop's over the passes after
+    assert prepare.kernel_calls(jax.make_jaxpr(run)(*args)) == {
+        "nns_grouped_gate_up": 2, "nns_grouped_down": 2}
+    kernel = jax.jit(lambda *a: grouped._by_kernel(*a, tile=tile))(*args)
+    if case != "every_pair_here":
+        np.testing.assert_array_equal(got, np.asarray(kernel))
+    want = np.zeros((t, d), np.float32)
+    for tok, slot in np.argwhere(choice < held):
+        e = choice[tok, slot]
+        gate, up = (jnp.dot(x[tok], w[e], preferred_element_type=jnp.float32)
+                    for w in (w1, w3))
+        want[tok] += weight[tok, slot] * np.asarray(jnp.dot(
+            (jax.nn.silu(gate) * up).astype(dtype), w2[e],
+            preferred_element_type=jnp.float32))
+    assert np.abs(want).max() > 0.1
+    scale = max(np.abs(want).max(), 1.0)
+    np.testing.assert_allclose(got, want, atol=tol * scale)
+    np.testing.assert_allclose(got, kernel, atol=tol * scale)
 
 
 def _kernel_calls(fn, *args):
@@ -645,11 +753,59 @@ def test_runs_of_tokens_fit_the_chip(t, d, calls):
         *a, tile=256, router=256), *args) == calls
 
 
+@pytest.mark.parametrize("t,d,f,k,held,router,fb,dc,n", [
+    (8192, 5120, 1536, 8, 32, 256, 1536, 1280, 16384),
+    (16384, 5120, 1536, 8, 32, 256, 1536, 640, 32768),
+    (4096, 6144, 2048, 8, 16, 256, 2048, 2048, 4096),
+    (16384, 6144, 2048, 8, 16, 256, 2048, 768, 16384),
+    (4096, 6144, 2048, 12, 16, 768, 2048, 2048, 2048),
+    (16384, 6144, 2048, 12, 16, 768, 2048, 768, 8192)],
+    ids=["dots3", "dots3_16k", "glm5", "glm5_16k", "longcat", "longcat_16k"])
+def test_the_split_kernels_fit_the_chip(t, d, f, k, held, router, fb, dc,
+                                        n):
+    """At the three cells' widths under half a router (experts of ``d``
+    x ``f``, ``k`` a token, tiles of 256, bfloat16), on their tokens and
+    on 16,384: ``nns_grouped_gate_up`` takes ``w1`` and ``w3`` whole
+    beside a tile of rows, twice over, and ``nns_grouped_down`` the
+    widest block of the float32 sum's columns that fits twice beside two
+    blocks of ``w2``, each within a core's 128 MiB and a wider block
+    past it; one pass holds twice the pairs an even routing serves here
+    (dots3's 16,384 of its 65,536), its slots' tokens and weights and
+    the walk's scalars within SMEM."""
+    assert grouped._split_blocks(t, d, f, 256, 2) == (fb, dc)
+    assert grouped._gate_up_vmem(d, fb, 256, 2) <= grouped._VMEM
+    assert grouped._down_vmem(t, f, dc, 256, 2) <= grouped._VMEM
+    wider = [c for c in range(dc + 128, d + 1, 128) if d % c == 0]
+    assert all(grouped._down_vmem(t, f, c, 256, 2) > grouped._VMEM
+               for c in wider)
+    assert dc % 128 == 0 and d % dc == 0
+    assert grouped._pass_pairs(t * k, held, router, 256, 16) == n \
+        == 2 * t * k * held // router
+    slots, steps = n + held * 15 + 256, n // 256 + held - 1
+    assert 8 * slots + 12 * steps + (64 << 10) <= grouped._SMEM
+
+
+def test_a_pass_holds_what_smem_holds():
+    """Past what SMEM holds of its slots, a pass takes fewer rows and the
+    routing more passes: at 1,048,576 tokens of dots3's share one pass
+    holds 121,384 of the 2,097,152 pairs an even routing serves; and a
+    pass never takes more than every pair."""
+    n = grouped._pass_pairs(1 << 23, 32, 256, 256, 16)
+    assert n == 121384
+    slots, steps = n + 32 * 15 + 256, -(-n // 256) + 32 - 1
+    assert 8 * slots + 12 * steps <= grouped._SMEM - (64 << 10) \
+        < 8 * (slots + 256) + 12 * (steps + 1)
+    assert grouped._pass_pairs(40, 2, 3, 8, 8) == 40
+
+
 def test_which_shares_take_the_kernel():
     """At least half the router held: the buffer sorted by expert, sized
-    for every pair, is at most twice the rows an even routing serves."""
+    for every pair, is at most twice the rows an even routing serves.
+    Every other share takes the split form, and only those."""
     assert [takes_kernel(h, 16) for h in (16, 15, 8, 7, 4, 1)] \
         == [True, True, True, False, False, False]
+    assert all(takes_split(h, r) != takes_kernel(h, r)
+               for r in (16, 256, 768) for h in range(1, r + 1))
     assert takes_kernel(128, 256) and not takes_kernel(16, 256) \
         and not takes_kernel(16, 768) and takes_kernel(128, 128)
 
@@ -659,10 +815,12 @@ def test_which_shares_take_the_kernel():
     ("glm_dsa", {"held_first": "16", "held_count": "16"}, 2, True),
     ("glm_dsa", {"held_first": "0", "held_count": "15"}, 2, False),
     ("glm_dsa", {"held_first": "8", "held_count": "8"}, 2, False),
+    ("glm_dsa", {"held_first": "0", "held_count": "2"}, 2, False),
     ("longcat", {}, 2, True),
     ("longcat", {"held_first": "8", "held_count": "16"}, 2, True),
     ("longcat", {"held_first": "0", "held_count": "15"}, 2, False),
     ("longcat", {"held_first": "4", "held_count": "4"}, 2, False),
+    ("longcat", {"held_first": "0", "held_count": "1"}, 2, False),
     ("afmoe", {}, 3, True),
     ("afmoe", {"held_first": "0", "held_count": "16"}, 3, True),
     ("afmoe", {"held_first": "4", "held_count": "4"}, 3, False),
@@ -673,23 +831,32 @@ def test_which_shares_take_the_kernel():
     ("kimi_linear", {"held_first": "8", "held_count": "8"}, 4, True),
     ("kimi_linear", {"held_first": "0", "held_count": "7"}, 4, False),
     ("kimi_linear", {"held_first": "12", "held_count": "4"}, 4, False),
+    ("dots3_note", {}, 4, True),
+    ("dots3_note", {"held_first": "0", "held_count": "4"}, 4, False),
 ], ids=["glm_dsa-default", "glm_dsa-a_half", "glm_dsa-under_a_half",
-        "glm_dsa-a_quarter", "longcat-default", "longcat-a_half",
-        "longcat-under_a_half", "longcat-an_eighth", "afmoe-default",
-        "afmoe-all_sixteen", "afmoe-a_quarter", "afmoe-all_but_one",
-        "afmoe-a_half", "afmoe-under_a_half", "kimi_linear-default",
-        "kimi_linear-a_half", "kimi_linear-under_a_half",
-        "kimi_linear-a_quarter"])
+        "glm_dsa-a_quarter", "glm_dsa-a_sixteenth", "longcat-default",
+        "longcat-a_half", "longcat-under_a_half", "longcat-an_eighth",
+        "longcat-one_of_32", "afmoe-default", "afmoe-all_sixteen",
+        "afmoe-a_quarter", "afmoe-all_but_one", "afmoe-a_half",
+        "afmoe-under_a_half", "kimi_linear-default", "kimi_linear-a_half",
+        "kimi_linear-under_a_half", "kimi_linear-a_quarter",
+        "dots3_note-default", "dots3_note-an_eighth"])
 def test_the_share_picks_the_form(name, share, layers, kernel):
     """``nns_grouped_swiglu`` once an expert layer of the traced zoo
     program where the experts held are at least half the router (the
     router's width read off the layer's gate, LongCat's identity outputs
-    among them: 32 wide at 24 real), and not at all under a half: those
-    keep the tile loops. What the layer observes of itself, for every
+    among them: 32 wide at 24 real): Trinity's whole router and
+    Kimi-Linear's half. Under a half (GLM-5's sixteenth, LongCat's 16
+    of 768, dots3's eighth among them) the split form instead:
+    ``nns_grouped_gate_up`` and ``nns_grouped_down`` twice an expert
+    layer, the first pass's and the loop's over the passes a routing
+    past one pass takes. What the layer observes of itself, for every
     decoder alike; no option."""
     apply_fn, params, in_info, _ = zoo.build(name, seq="64", seed="3",
                                              **share)
     closed = jax.make_jaxpr(apply_fn)(
         params, jnp.zeros(in_info[0].shape, jnp.int32))
-    assert prepare.kernel_calls(closed).get("nns_grouped_swiglu", 0) \
-        == (layers if kernel else 0)
+    calls = prepare.kernel_calls(closed)
+    assert calls.get("nns_grouped_swiglu", 0) == (layers if kernel else 0)
+    assert calls.get("nns_grouped_gate_up", 0) \
+        == calls.get("nns_grouped_down", 0) == (0 if kernel else 2 * layers)

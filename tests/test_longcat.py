@@ -329,8 +329,12 @@ def test_pipeline_gives_the_direct_calls_three_tensors(window):
     got = [[np.asarray(c.host()) for c in b.chunks] for b in p["out"].buffers]
     report = p["f"].transfer_report()
     p.stop()
-    # 2 layers x 2 attentions x 4 blocks of 16 queries (the fixture's)
-    assert report["kernel_calls"] == {"nns_masked_attention": 16}
+    # 2 layers x 2 attentions x 4 blocks of 16 queries (the fixture's);
+    # 4 of a router 32 wide held: the split form's two kernels twice an
+    # expert layer (ops/grouped.py::takes_split)
+    assert report["kernel_calls"] == {"nns_masked_attention": 16,
+                                      "nns_grouped_gate_up": 4,
+                                      "nns_grouped_down": 4}
     assert report.get("prepared_leaves", 0) == 0
     # an attention's 2 reshapes, 2 slices, 2 pads and 3 transposes
     # (latent.mla_weights), and a router bias's conversion a layer
